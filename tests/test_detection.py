@@ -351,9 +351,8 @@ int tpurx_beat_abi_v2(void) { return 2; }
 
 def test_stale_v2_so_forces_rebuild(tmp_path, monkeypatch):
     """A prebuilt v2 ``.so`` (int32-ms stamps, no gen word) loads fine and
-    exports start/stop — only the required-symbol check can reject it.
-    load_beat_lib must rebuild from source and come back ABI v3 (mirror of
-    the original ``tpurx_beat_abi_v2`` forcing pattern, one ABI later)."""
+    exports start/stop — only the loader's source stamp can reject it.
+    load_beat_lib must rebuild from source and come back ABI v3."""
     from tpu_resiliency.utils import native as native_mod
 
     cc = shutil.which(os.environ.get("CC", "cc"))
@@ -383,11 +382,19 @@ def test_stale_v2_so_forces_rebuild(tmp_path, monkeypatch):
     assert hasattr(lib, "tpurx_beat_wait_stale")
     # the on-disk .so was actually replaced by the rebuild (symbol names
     # live in .dynstr as plain bytes; a re-dlopen of the same path would
-    # dedupe to the stale mapping, which is exactly why the loader loads
-    # the temp build path — see utils/native._build_and_load)
+    # dedupe to the stale mapping, which is exactly why the loader opens a
+    # fresh build under a private name — see utils/native.load_native)
     disk = stale_so.read_bytes()
     assert b"tpurx_beat_abi_v3" in disk
     assert b"tpurx_beat_abi_v2" not in disk
+
+    # same symbols, older source: only the stamp can tell.  An edit to the
+    # source must rebuild; an untouched source must not.
+    assert native_mod.ensure_built("libtpurx-beat.so")[1] is False
+    with open(tmp_path / "beat_thread.c", "a") as f:
+        f.write("\n/* edited */\n")
+    assert native_mod.ensure_built("libtpurx-beat.so")[1] is True
+    assert native_mod.ensure_built("libtpurx-beat.so")[1] is False
 
 
 # -- telemetry ---------------------------------------------------------------

@@ -56,7 +56,7 @@ def test_init_distributed_env_logic(monkeypatch):
     from tpu_resiliency.parallel.distributed import init_distributed
 
     # single process: no-op
-    monkeypatch.setenv("TPURX_NNODES", "1")
+    monkeypatch.setenv("TPURX_WORLD_SIZE", "1")
     assert init_distributed() is False
     # coordinator derivation (don't actually initialize — just check inputs
     # via a stub)
@@ -69,8 +69,11 @@ def test_init_distributed_env_logic(monkeypatch):
                 addr=coordinator_address, n=num_processes, pid=process_id
             )
 
-    monkeypatch.setenv("TPURX_NNODES", "4")
-    monkeypatch.setenv("TPURX_GROUP_RANK", "2")
+    # one JAX process per WORKER: sized by world size and rank, not by nodes
+    monkeypatch.setenv("TPURX_NNODES", "1")
+    monkeypatch.setenv("TPURX_GROUP_RANK", "0")
+    monkeypatch.setenv("TPURX_WORLD_SIZE", "4")
+    monkeypatch.setenv("TPURX_RANK", "2")
     monkeypatch.setenv("TPURX_STORE_ADDR", "10.0.0.5")
     monkeypatch.setenv("TPURX_STORE_PORT", "29400")
     monkeypatch.setattr(jax, "distributed", FakeDist)

@@ -211,7 +211,7 @@ class LogAnalyzer:
                     llm.category != FailureCategory.UNKNOWN.value
                     and llm.confidence > result.confidence
                 ):
-                    # a hallucinated (out-of-taxonomy -> unknown) category
+                    # a hallucinated (outside the known categories -> unknown) category
                     # must never displace a concrete rule verdict
                     llm.summary += f" (overrode rules' {result.category})"
                     llm.evidence = result.evidence

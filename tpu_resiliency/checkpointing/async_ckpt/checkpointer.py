@@ -254,6 +254,8 @@ class AsyncCheckpointer:
         self._stager: Optional[threading.Thread] = None
         # last staging's byte accounting (tests assert steady-state reuse)
         self.last_stage_stats: Dict[str, int] = {}
+        # "snapshot" | "sync": the mode the last async_save really took
+        self.last_stage_mode: Optional[str] = None
         # snapshot ring: {"sig", "leaves" (device arrays), "job"} slots; a
         # slot is reusable (its buffers donatable) only once its job's
         # staging has drained — job.done is the D2H-consumed fence
@@ -287,6 +289,7 @@ class AsyncCheckpointer:
         (possibly with a different world size) are never committed."""
         call_t0 = time.monotonic_ns()
         mode = stage_mode or self.stage_mode or self._resolve_stage_mode(tree)
+        self.last_stage_mode = mode
         os.makedirs(ckpt_dir, exist_ok=True)
         if save_id is None:
             save_id = str((extra_metadata or {}).get("iteration", "default"))
@@ -833,9 +836,12 @@ _default_reader = CachedMetadataReader()
 def _place_leaf(tmpl: Any, arr: np.ndarray, leaf_path: str) -> Any:
     """Hand one restored leaf to its template slot.  jax templates get the
     array device_put with the template's sharding — an async dispatch, so
-    placing leaf *i* overlaps whatever leaves are still reading.  The dtype
-    cast is skipped entirely when the checkpoint dtype already matches
-    (``astype`` copies unconditionally; ``coerce_dtype`` does not)."""
+    placing leaf *i* overlaps whatever leaves are still reading — and as
+    committed to it as the template is: a jitted step keys its cache on
+    that too, and would compile a second time for restored state that is
+    pinned where the fresh state it first saw was not.  The dtype cast is
+    skipped entirely when the checkpoint dtype already matches (``astype``
+    copies unconditionally; ``coerce_dtype`` does not)."""
     import jax
 
     if isinstance(tmpl, jax.Array):
@@ -844,7 +850,19 @@ def _place_leaf(tmpl: Any, arr: np.ndarray, leaf_path: str) -> Any:
                 f"leaf {leaf_path}: shape {arr.shape} != "
                 f"template {tmpl.shape}"
             )
-        return jax.device_put(coerce_dtype(arr, tmpl.dtype), tmpl.sharding)
+        arr = coerce_dtype(arr, tmpl.dtype)
+        if not tmpl.committed:
+            # uncommitted templates sit on one device (the default one)
+            return jax.device_put(arr)
+        if tmpl.sharding.is_fully_addressable:
+            return jax.device_put(arr, tmpl.sharding)
+        # A sharding that spans processes: device_put of host data onto it is
+        # a collective (every process checks its value against process 0's),
+        # and leaves arrive here in whatever order each process's readers
+        # finish.  Placing only this process's shards needs no agreement.
+        return jax.make_array_from_callback(
+            arr.shape, tmpl.sharding, lambda index: arr[index]
+        )
     return np.asarray(arr, dtype=getattr(tmpl, "dtype", None))
 
 

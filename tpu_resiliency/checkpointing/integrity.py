@@ -438,7 +438,7 @@ def verify_blob_file(path: str, site: str = "scrub") -> int:
     fallback ladder's validity rounds can sweep multi-GiB retained
     iterations without doubling the host's memory watermark.  Returns the
     payload length; raises :class:`CheckpointCorruptError` on any mismatch
-    (same failure taxonomy as :func:`verify_blob`)."""
+    (same failure classes as :func:`verify_blob`)."""
     t0 = time.monotonic_ns()
     _VERIFY.labels(site=site).inc()
     name = os.path.basename(path)

@@ -50,6 +50,7 @@ def pre_rendezvous_health_check(
         result = check.run()
         if not result.healthy:
             raise UnhealthyNodeError(f"device health check failed: {result.message}")
+        log.info("device health check (cycle %s): %s", current_cycle, result.message)
     if cfg.enable_storage_health_check and cfg.storage_health_check_path:
         from ..health import StoragePathHealthCheck
 

@@ -38,7 +38,7 @@ import uuid
 from typing import Dict, List, Optional
 
 from ..store import StoreClient, StoreError, StoreServer
-from ..utils import env
+from ..utils import compile_cache, env
 from ..utils.ipc import IpcConnector
 from ..utils.logging import get_logger, setup_logger
 from ..utils.profiling import ProfilingEvent, get_recorder, record_event
@@ -270,6 +270,10 @@ class ElasticAgent:
         for lr in range(self.spec.nproc_per_node):
             grank = result.rank_offset + lr
             env = dict(os.environ)
+            # every worker of every cycle compiles into, and reads from, the
+            # same persistent cache: a respawn must not start cold
+            env[compile_cache.ENV_VAR] = compile_cache.cache_dir()
+            env.update(self._chip_env(lr))
             env.update(self.spec.extra_env)
             env.update(
                 {
@@ -307,6 +311,20 @@ class ElasticAgent:
             "cycle %s: started %s workers (global ranks %s..%s)",
             cycle, len(self.workers), result.rank_offset,
             result.rank_offset + self.spec.nproc_per_node - 1,
+        )
+
+    def _chip_env(self, local_rank: int) -> Dict[str, str]:
+        """One process per chip: the env that hands worker ``local_rank``
+        its own chip (empty on a CPU host, for a single worker, or when the
+        operator pinned the job to the CPU backend).  Raises ``ValueError``
+        for a worker count the host's chips cannot carry."""
+        if os.environ.get("JAX_PLATFORMS") == "cpu":
+            return {}
+        from ..health.tpu import visible_tpu_chips
+        from ..parallel.distributed import worker_chip_env
+
+        return worker_chip_env(
+            self.spec.nproc_per_node, local_rank, visible_tpu_chips()
         )
 
     def _numa_wrap(self, cmd: List[str], local_rank: int) -> List[str]:
@@ -649,6 +667,11 @@ class ElasticAgent:
         verdict is computed once and cached so a store outage between the
         gate and ``request_restart`` can't charge the restart budget twice."""
         if self._restart_in_flight_allowed is None:
+            # the cached device verdict predates this node's fault: the gate
+            # of the next round must open the chips again
+            from ..health import DeviceHealthCheck
+
+            DeviceHealthCheck.clear_cache()
             self._restart_in_flight_allowed = self._restart_allowed()
         if not self._restart_in_flight_allowed:
             self.store.set(K_SHUTDOWN, "restart budget exhausted")
@@ -958,6 +981,10 @@ def main(argv: Optional[List[str]] = None) -> None:
     setup_logger()
     args = parse_args(argv)
     agent = build_agent(args)
+    try:
+        agent._chip_env(0)
+    except ValueError as exc:
+        raise SystemExit(f"tpurx-launch: {exc}")
     if agent.cfg.profiling_file:
         get_recorder()._path = agent.cfg.profiling_file
     agent.setup_rank_monitors_early()

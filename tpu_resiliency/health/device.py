@@ -7,9 +7,20 @@ op".  Crucially the probe must run in a **subprocess**: initializing JAX in
 the launcher would claim the TPU chips and starve the workers.
 
 The subprocess runs a trivial computation with a wall-clock timeout and
-prints a sentinel; hang, crash, or missing devices all fail the check.
-Results are cached for ``cache_ttl`` seconds because a full probe costs a
-runtime init (~seconds).
+prints a sentinel; hang, crash, or missing devices all fail the check, and so
+does a JAX that came up on the CPU backend on a host that exposes TPU chips
+(without ``JAX_PLATFORMS`` a failed TPU init only warns and falls back).  The
+verdict names the platform the probe ran on.  Results are cached for
+``cache_ttl`` seconds because a full probe costs a runtime init (about 10 s
+on a v5e host).
+
+A chip belongs to one process at a time (libtpu takes ``/tmp/libtpu_lockfile``;
+a second opener fails within seconds with "accessing libtpu multi-process
+lockfile"), so the probe is for the gap between cycles, when the workers are
+gone.  A SIGKILLed holder leaves the lock *file* behind but not the lock, and
+its HBM is reclaimed with the process: on a v5e the next opener, started
+under 3 s after the kill of a process holding 8 GiB, came up in the usual
+time with ``bytes_in_use`` back at idle (PERF.md, PR 21).
 """
 
 from __future__ import annotations
@@ -24,8 +35,6 @@ from .base import HealthCheck, HealthCheckResult
 
 _PROBE_CODE = r"""
 import json
-import os
-os.environ.setdefault("TPU_PROCESS_BOUNDS", "")
 import jax
 devs = jax.devices()
 assert devs, "no devices"
@@ -124,7 +133,23 @@ class DeviceHealthCheck(HealthCheck):
                         f"(leaked grants?)",
                     )
         kinds = {d.get("kind") for d in stats} or {"?"}
-        return HealthCheckResult(True, f"{n} device(s) healthy ({', '.join(map(str, kinds))})")
+        platforms = {d.get("platform") for d in stats} or {"?"}
+        on = ", ".join(sorted(map(str, platforms)))
+        if platforms != {"tpu"}:
+            from .tpu import visible_tpu_chips
+
+            chips = visible_tpu_chips()
+            if chips:
+                return HealthCheckResult(
+                    False,
+                    f"host exposes {len(chips)} TPU chip(s) but JAX came up "
+                    f"on platform {on}",
+                )
+        return HealthCheckResult(
+            True,
+            f"{n} device(s) healthy ({', '.join(map(str, kinds))}) "
+            f"on platform {on}",
+        )
 
     @classmethod
     def clear_cache(cls) -> None:

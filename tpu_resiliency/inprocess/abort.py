@@ -382,15 +382,12 @@ class ShrinkMeshStage(AbortStage):
 
     def release(self, state=None) -> Optional[str]:
         import jax
-        from jax._src import distributed as jax_dist
+        import jax.extend.backend as jeb  # lazy submodule
+
+        from ..parallel import distributed as dist_mod
 
         detail = []
-        state_obj = getattr(jax_dist, "global_state", None)
-        initialized = (
-            state_obj is not None
-            and getattr(state_obj, "client", None) is not None
-        )
-        if initialized:
+        if jax.distributed.is_initialized():
             jax.distributed.shutdown()
             detail.append("distributed client shut down")
         else:
@@ -399,21 +396,11 @@ class ShrinkMeshStage(AbortStage):
         # the full reset (measured by benchmarks/mesh_shrink_experiment.py):
         # clearing compiled caches is NOT enough — jax.distributed refuses
         # re-init while backends are live, so the backends must go too
-        try:
-            import jax.extend.backend as jeb  # lazy submodule
-
-            jeb.clear_backends()
-            detail.append("caches+backends cleared")
-        except Exception as exc:  # noqa: BLE001 - version-dependent API
-            detail.append(f"caches cleared (clear_backends: {exc!r})")
+        jeb.clear_backends()
+        detail.append("caches+backends cleared")
         # reset the bootstrap helper so the next iteration's initialize
         # plugin may re-init at the surviving world size
-        try:
-            from ..parallel import distributed as dist_mod
-
-            dist_mod._initialized = False
-        except (ImportError, AttributeError):
-            pass  # helper is optional
+        dist_mod._initialized = False
         return "; ".join(detail)
 
 

@@ -43,12 +43,11 @@ _PINNED: list = []  # shared slots a queued C pending call may still touch
 
 def _load_native_stamper():
     """Load the pure-C pending-call stamper via the shared build-on-demand
-    loader (load-first, atomic temp build — utils/native.py); None if the
-    toolchain or loader can't deliver it (fallback: ctypes callback)."""
+    loader (utils/native.py); None if the toolchain or loader can't deliver
+    it (fallback: ctypes callback)."""
     from ..utils.native import load_native
 
-    lib = load_native("libtpurx-pending.so", "pending_stamp.c",
-                      required_symbols=("tpurx_schedule_stamp",))
+    lib = load_native("libtpurx-pending.so")
     if lib is not None:
         # idempotent re-assignment: load_native caches the CDLL per process
         lib.tpurx_schedule_stamp.argtypes = [ctypes.c_void_p]

@@ -88,6 +88,7 @@ class QuorumTripwire:
         self._iteration = 0
         self._fired_iteration: Optional[int] = None
         self._lock = threading.Lock()
+        self._suspended_budget: Optional[float] = None
         self.trip_time: Optional[float] = None
         self.monitor = QuorumMonitor(
             mesh,
@@ -128,6 +129,16 @@ class QuorumTripwire:
         self.monitor.start()
         return self
 
+    def suspend(self) -> None:
+        """No trips until the next :meth:`set_iteration`.  The restart path
+        stops pinging by design — last-call wait, abort ladder, health
+        check, barrier — and with manual beats it outlasts any step-sized
+        budget; a wedged restart is the monitor process's to catch."""
+        with self._lock:
+            if self._suspended_budget is None:
+                self._suspended_budget = self.monitor.budget_ms
+                self.monitor.budget_ms = float("inf")
+
     def set_iteration(self, iteration: int) -> None:
         with self._lock:
             self._iteration = iteration
@@ -136,6 +147,12 @@ class QuorumTripwire:
         # re-arm the liveness beater so the OLD hang's silence doesn't trip
         # the NEW iteration
         self.monitor.resume_auto_beat()
+        with self._lock:
+            if self._suspended_budget is not None:
+                # after the fence above: ticks dispatched while suspended
+                # carry restart-path ages and must not fire on the way out
+                self.monitor.budget_ms = self._suspended_budget
+                self._suspended_budget = None
 
     def stop(self) -> None:
         self.monitor.stop()

@@ -273,6 +273,22 @@ class CallWrapper:
                 self.quorum.monitor.resume_auto_beat()
                 self.quorum.monitor.budget_ms = saved_budget
 
+    def calibrate_quorum(self, load_fn: Callable[[], Any],
+                         n_ticks: int = 20) -> Optional[float]:
+        """Derive the quorum budget from healthy tick ages sampled while
+        ``load_fn`` runs — one real training step with its :meth:`ping`, so
+        the ages embed the step time and the host contention of THIS model
+        on THIS device.  The constructor budget is tuned for nothing: with
+        manual beats it must exceed the step time, which only the workload
+        knows.  Call once the step is compiled and warm.  Returns the new
+        budget (ms), or None without a quorum tripwire."""
+        if not self.quorum:
+            return None
+        return self.quorum.monitor.calibrate(
+            n_ticks=n_ticks, min_budget_ms=self.w.quorum_min_budget_ms,
+            load_fn=load_fn,
+        )
+
     @property
     def iteration(self) -> int:
         return self.state.iteration
@@ -484,6 +500,10 @@ class CallWrapper:
                         if self._accepts_cw:
                             kwargs = {**kwargs, "call_wrapper": self}
                         ret = self.fn(*args, **kwargs)
+                        if self.quorum:
+                            # fn returned: its pings are over, and so is
+                            # what the tripwire was watching
+                            self.quorum.suspend()
                         if w.completion:
                             # Completion plugin (reference `completion.py`
                             # ABC): may transform/validate the return value
@@ -534,6 +554,8 @@ class CallWrapper:
 
             # ---- restart path ---- (async-exc slot empty from here on)
             phase_t0 = self._restart_started_ns = time.monotonic_ns()
+            if self.quorum:
+                self.quorum.suspend()  # re-armed by set_iteration at loop top
             _RESTARTS.inc()
             _INTERRUPTIONS.labels(
                 "exception" if fault_exc is not None else "peer_signal"

@@ -43,20 +43,23 @@ class TransformerConfig:
 
 
 def _specs(cfg: TransformerConfig):
-    """PartitionSpecs per parameter (heads/ffn on 'model')."""
+    """PartitionSpecs per parameter (heads/ffn on 'model').  Replicated
+    leaves say ``P()``, the spelling jit gives its outputs: ``P(None)`` is
+    the same placement but another cache key, and the step would compile a
+    second time when its own outputs come back in."""
     from jax.sharding import PartitionSpec as P
 
     layer = {
         "wq": P(None, "model"), "wk": P(None, "model"), "wv": P(None, "model"),
         "wo": P("model", None),
         "w1": P(None, "model"), "w2": P("model", None),
-        "ln1_scale": P(None), "ln2_scale": P(None),
+        "ln1_scale": P(), "ln2_scale": P(),
     }
     return {
         "embed": P("model", None),        # vocab sharded over model axis
-        "pos": P(None, None),
+        "pos": P(),
         "layers": [dict(layer) for _ in range(cfg.n_layers)],
-        "ln_f_scale": P(None),
+        "ln_f_scale": P(),
     }
 
 
@@ -171,14 +174,28 @@ def init_opt_state(params):
     import jax
     import jax.numpy as jnp
 
-    f32 = lambda p: jnp.zeros(p.shape, dtype=jnp.float32)
-    any_low = any(
-        leaf.dtype != jnp.float32 for leaf in jax.tree_util.tree_leaves(params)
-    )
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    # every moment lives where its parameter lives (and is committed there
+    # only if the parameter is): zeros made without a sharding would all
+    # land on device 0, and the step would compile twice — once for these
+    # inputs, once for its own outputs fed back in
+    def f32(p):
+        z = jnp.zeros(p.shape, dtype=jnp.float32)
+        return jax.device_put(z, p.sharding) if p.committed else z
+
+    leaves = jax.tree_util.tree_leaves(params)
+    any_low = any(leaf.dtype != jnp.float32 for leaf in leaves)
+    count = jnp.zeros((), dtype=jnp.int32)
+    if leaves and leaves[0].committed:
+        sh = leaves[0].sharding
+        if isinstance(sh, NamedSharding):
+            sh = NamedSharding(sh.mesh, P())  # replicated over the mesh
+        count = jax.device_put(count, sh)
     state = {
         "mu": jax.tree_util.tree_map(f32, params),
         "nu": jax.tree_util.tree_map(f32, params),
-        "count": jnp.zeros((), dtype=jnp.int32),
+        "count": count,
     }
     if any_low:
         # fp32 master copy: bf16 params would silently drop sub-ulp updates

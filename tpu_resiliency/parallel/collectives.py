@@ -386,10 +386,8 @@ def build_shift_permute(mesh, axis: str, shift: int):
 
         return _jax.lax.ppermute(x, axis, perm)
 
-    from ..utils.jax_compat import shard_map as shard_map_compat
-
-    smapped = shard_map_compat(
-        body, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check=False
+    smapped = jax.shard_map(
+        body, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False
     )
     return jax.jit(smapped), NamedSharding(mesh, P(axis))
 

@@ -82,13 +82,10 @@ def default_relayout() -> str:
     the re-run re-traces against the current (possibly changed) topology.
     The full teardown — distributed client + backends — is the *shrink*
     rung's job via the abort ladder."""
-    try:
-        import jax
+    import jax
 
-        jax.clear_caches()
-        return "caches cleared"
-    except Exception as exc:  # noqa: BLE001 — relayout is best-effort prep
-        return f"clear_caches unavailable: {exc!r}"
+    jax.clear_caches()
+    return "caches cleared"
 
 
 def trip_shrink(op: str, axis: str, culprits: Tuple[int, ...] = ()) -> str:

@@ -1,9 +1,10 @@
 """Native (C++) store server loader.
 
-Builds ``native/store_server.cpp`` on first use (cached binary) and runs it
-as a subprocess.  Same wire protocol, same client — the native server is a
-drop-in for the asyncio one where control-plane latency/fan-in matters
-(rendezvous CAS storms at pod scale).
+Builds ``native/store_server.cpp`` on first use (``utils/native.py``: cached
+binary, rebuilt when the source changed) and runs it as a subprocess.  Same
+wire protocol, same client — the native server is a drop-in for the asyncio
+one where control-plane latency/fan-in matters (rendezvous CAS storms at pod
+scale).
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import time
 from typing import Optional
 
 from ..utils.logging import get_logger
+from ..utils.native import ensure_built
 from ..utils.retry import Retrier, RetryExhausted, RetryPolicy
 
 log = get_logger("store.native")
@@ -23,43 +25,11 @@ log = get_logger("store.native")
 _STARTUP_POLL = RetryPolicy(max_attempts=None, base_delay=0.05, max_delay=0.05,
                             min_delay_fraction=1.0)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 
-
-def native_binary_path() -> str:
-    return os.path.abspath(os.path.join(_NATIVE_DIR, "tpurx-store-server"))
-
-
-def build_native_server(force: bool = False) -> str:
-    """Compile the native server if needed; returns the binary path.
-
-    Builds to a process-unique temp file and atomically ``os.replace``s it:
-    concurrent processes (parallel test runs, multiple agents on one host)
-    may build simultaneously, and a torn half-written binary must never be
-    exec'd."""
-    binary = native_binary_path()
-    src = os.path.abspath(os.path.join(_NATIVE_DIR, "store_server.cpp"))
-    if (
-        not force
-        and os.path.exists(binary)
-        and os.path.getmtime(binary) >= os.path.getmtime(src)
-    ):
-        return binary
-    log.info("building native store server...")
-    tmp = f"{binary}.build.{os.getpid()}"
-    cxx = os.environ.get("CXX", "g++")
-    try:
-        subprocess.run(
-            [cxx, "-O2", "-std=c++17", "-Wall", "-o", tmp, src],
-            check=True, capture_output=True, text=True, timeout=120,
-        )
-        os.replace(tmp, binary)
-    finally:
-        try:
-            os.unlink(tmp)
-        except OSError:
-            pass
-    return binary
+def build_native_server() -> str:
+    """Compile the native server unless the binary on disk was built from
+    the current source (utils/native.py stamps it); returns its path."""
+    return ensure_built("tpurx-store-server")[0]
 
 
 class NativeStoreServer:

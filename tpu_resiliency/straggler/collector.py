@@ -90,14 +90,7 @@ class _Stats(ctypes.Structure):
 
 
 def _load_ring_lib():
-    lib = load_native(
-        "libtpurx-opring.so", "op_ring.c", extra_args=("-lm",),
-        required_symbols=(
-            "tpurx_ring_arena_size", "tpurx_ring_init", "tpurx_ring_intern",
-            "tpurx_ring_push", "tpurx_ring_add_drop", "tpurx_ring_n_ops",
-            "tpurx_ring_name", "tpurx_ring_stats",
-        ),
-    )
+    lib = load_native("libtpurx-opring.so")
     if lib is None:
         return None
     lib.tpurx_ring_arena_size.argtypes = [ctypes.c_uint32, ctypes.c_uint32]

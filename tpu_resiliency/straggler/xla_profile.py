@@ -29,7 +29,7 @@ import json
 import os
 import shutil
 import tempfile
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 from ..utils.logging import get_logger
 from .timers import DurationStore
@@ -37,10 +37,20 @@ from .timers import DurationStore
 log = get_logger("straggler.xla")
 
 
-# Runtime bookkeeping spans sharing the op lanes: not op time.  "end: <op>"
-# markers would double-count ops; executor/listener spans cover whole
-# executions and would dilute per-op weighting; "XLA Modules"/"Steps" lane
-# aggregates likewise.
+# A trace taken on an accelerator has one process per device
+# ("/device:TPU:0") whose "XLA Ops" lane carries the ops; its other lanes
+# ("XLA Modules", "Async XLA Ops", "TC Overlay") aggregate or overlap them.
+# The host process of such a trace ("/host:CPU": the PJRT execute thread, the
+# sync-flag poller, python) is runtime time, never op time (looked at on a
+# v5e, jax 0.9.0: PERF.md, PR 21).
+_DEVICE_PROCESS_PREFIX = "/device:"
+_DEVICE_OP_LANE = "XLA Ops"
+
+# On the CPU backend there is no device process and the ops run on the
+# client's execution threads, next to runtime bookkeeping spans that are not
+# op time.  "end: <op>" markers would double-count ops; executor/listener
+# spans cover whole executions and would dilute per-op weighting;
+# "XLA Modules"/"Steps" lane aggregates likewise.
 _NON_OP_PREFIXES = ("end: ", "$")
 _NON_OP_SUBSTRINGS = (
     "ThunkExecutor", "ThreadpoolListener", "ExecuteThunks", "BufferAllocations",
@@ -59,12 +69,20 @@ def _is_op_event(name: str, lane: str) -> bool:
 
 
 def parse_trace_dir(trace_dir: str) -> Dict[str, List[float]]:
-    """Aggregate op durations (seconds) from a profiler dump directory.
+    """Aggregate op durations (seconds) from a profiler dump directory."""
+    return parse_trace_events(trace_dir)[0]
 
-    Takes complete ('X') events from the op lanes — on TPU the device
-    "XLA Ops" lanes; on CPU the PjRt client execution threads — keyed by op
-    name, with runtime bookkeeping spans filtered (see ``_is_op_event``)."""
+
+def parse_trace_events(trace_dir: str) -> Tuple[Dict[str, List[float]], str]:
+    """``(per_op_durations_s, source)`` from a profiler dump directory.
+
+    Takes complete ('X') events keyed by op name.  ``source`` is "device"
+    when the trace has accelerator processes — then only their "XLA Ops"
+    lanes count — and "host" on the CPU backend, where the ops sit on the
+    PjRt client's execution threads with runtime bookkeeping spans filtered
+    (see ``_is_op_event``)."""
     out: Dict[str, List[float]] = {}
+    source = "host"
     for path in glob.glob(
         os.path.join(trace_dir, "**", "*.trace.json.gz"), recursive=True
     ):
@@ -76,18 +94,30 @@ def parse_trace_dir(trace_dir: str) -> Dict[str, List[float]]:
             continue
         events = data.get("traceEvents", [])
         lanes: Dict[tuple, str] = {}
+        device_pids = set()
         for e in events:
-            if e.get("ph") == "M" and e.get("name") == "thread_name":
-                lanes[(e.get("pid"), e.get("tid"))] = e.get("args", {}).get("name", "")
+            if e.get("ph") != "M":
+                continue
+            label = e.get("args", {}).get("name", "")
+            if e.get("name") == "thread_name":
+                lanes[(e.get("pid"), e.get("tid"))] = label
+            elif (e.get("name") == "process_name"
+                  and label.startswith(_DEVICE_PROCESS_PREFIX)):
+                device_pids.add(e.get("pid"))
+        if device_pids:
+            source = "device"
         for e in events:
             if e.get("ph") != "X" or not e.get("dur"):
                 continue
             lane = lanes.get((e.get("pid"), e.get("tid")), "")
             name = e.get("name", "?")
-            if not _is_op_event(name, lane):
+            if device_pids:
+                if e.get("pid") not in device_pids or lane != _DEVICE_OP_LANE:
+                    continue
+            elif not _is_op_event(name, lane):
                 continue
             out.setdefault(name, []).append(float(e["dur"]) / 1e6)  # µs → s
-    return out
+    return out, source
 
 
 class XlaProfileCollector:
@@ -96,6 +126,7 @@ class XlaProfileCollector:
         self.prefix = prefix
         self.top_k = top_k
         self.last_capture: Dict[str, List[float]] = {}
+        self.last_source = ""  # "device" | "host" lanes of the last capture
 
     @contextlib.contextmanager
     def capture(self):
@@ -106,7 +137,7 @@ class XlaProfileCollector:
         try:
             with jax.profiler.trace(trace_dir):
                 yield
-            per_op = parse_trace_dir(trace_dir)
+            per_op, self.last_source = parse_trace_events(trace_dir)
             # keep the top_k ops by total time: straggler scores weight by
             # total anyway, and unbounded op-name cardinality would bloat
             # every report

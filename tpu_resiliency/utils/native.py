@@ -1,19 +1,27 @@
-"""Loader for the repo's native helper libraries (build-on-demand).
+"""Builder and loader for the repo's native helpers (build-on-demand).
 
-Load-first, build-on-failure: shipped binaries in git are unreviewable and
-mtime-based rebuild checks are checkout-order-dependent, so the .so files
-are NOT committed — a missing or unloadable library is compiled from its .c
-source to a process-unique temp file and atomically ``os.replace``d into
-place (concurrent ranks on one host may build simultaneously; a torn
-half-written .so must never be dlopen'd).  Callers must tolerate ``None``
-(no toolchain, no prebuilt) with a pure-Python fallback.
+The binaries under ``native/`` are build products, never committed: shipped
+binaries are unreviewable.  A binary is used only when the stamp beside it
+(``<binary>.src``) names the sha256 of the source and flags it would be built
+from now; otherwise it is compiled to a process-unique temp file and
+atomically ``os.replace``d into place (concurrent ranks on one host may build
+simultaneously; a torn half-written file must never be dlopen'd or exec'd).
+A binary left behind by older source therefore never runs — an mtime or a
+symbol probe cannot promise that.
+
+:func:`load_native` returns ``None`` when there is no toolchain; callers then
+run their pure-Python stand-in.  Entry points that must not (the chip smoke)
+call :func:`build_all` first, which raises, and check :func:`loaded` after.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import threading
+from typing import Dict, Optional, Sequence
 
 from .logging import get_logger
 
@@ -23,64 +31,102 @@ NATIVE_DIR = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", "native")
 )
 
+# name -> (source, compiler env var, default compiler, flags before -o,
+# flags after the source); mirrors native/Makefile
+TARGETS: Dict[str, tuple] = {
+    "libtpurx-pending.so": (
+        "pending_stamp.c", "CC", "cc", ("-O2", "-Wall", "-shared", "-fPIC"), ()),
+    "libtpurx-opring.so": (
+        "op_ring.c", "CC", "cc", ("-O2", "-Wall", "-shared", "-fPIC"), ("-lm",)),
+    "libtpurx-beat.so": (
+        "beat_thread.c", "CC", "cc",
+        ("-O2", "-Wall", "-shared", "-fPIC", "-D_GNU_SOURCE"), ("-lpthread",)),
+    "tpurx-store-server": (
+        "store_server.cpp", "CXX", "g++", ("-O2", "-std=c++17", "-Wall"), ()),
+}
+
 _cache: dict = {}
+_cache_lock = threading.Lock()
 
 
-def _build_and_load(src: str, path: str, extra_args: tuple,
-                    try_load) -> "ctypes.CDLL":
-    """Compile to a process-unique temp path, dlopen THAT path, then
-    atomically publish to ``path`` for future processes.
+def _source_stamp(src: str, flags: Sequence[str]) -> str:
+    h = hashlib.sha256(" ".join(flags).encode())
+    with open(src, "rb") as f:
+        h.update(f.read())
+    return h.hexdigest()
 
-    Loading the temp path (not the final one) is load-bearing: glibc
-    dedupes dlopen by pathname, so once a stale .so has been opened at
-    ``path`` in this process, re-opening ``path`` returns the OLD mapping
-    even after an os.replace — the rebuilt library would be unreachable
-    and the required-symbol staleness forcing would silently fail."""
-    tmp = f"{path}.build.{os.getpid()}"
-    cc = os.environ.get("CC", "cc")
+
+def ensure_built(name: str) -> tuple:
+    """Build ``native/<name>`` unless its stamp matches the current source.
+    Returns ``(path, built)``; raises ``OSError``/``SubprocessError`` when
+    the source cannot be compiled."""
+    src_name, cc_var, cc_default, pre, post = TARGETS[name]
+    path = os.path.join(NATIVE_DIR, name)
+    src = os.path.join(NATIVE_DIR, src_name)
+    want = _source_stamp(src, (*pre, *post))
+    try:
+        with open(path + ".src") as f:
+            if f.read().strip() == want and os.path.exists(path):
+                return path, False
+    except OSError:
+        pass
+    tmp = f"{path}.build.{os.getpid()}.{threading.get_ident()}"
     try:
         subprocess.run(
-            [cc, "-O2", "-Wall", "-shared", "-fPIC", "-o", tmp, src,
-             *extra_args],
-            check=True, capture_output=True, text=True, timeout=60,
+            [os.environ.get(cc_var, cc_default), *pre, "-o", tmp, src, *post],
+            check=True, capture_output=True, text=True, timeout=120,
         )
-        lib = try_load(tmp)
         os.replace(tmp, path)
-        return lib
+        with open(tmp, "w") as f:
+            f.write(want)
+        os.replace(tmp, path + ".src")
     finally:
         try:
             os.unlink(tmp)
         except OSError:
             pass
+    log.info("built native/%s from %s", name, src_name)
+    return path, True
 
 
-def load_native(lib_name: str, src_name: str, extra_args: tuple = (),
-                required_symbols: tuple = ()):
-    """Load ``native/<lib_name>``, building from ``native/<src_name>`` when
-    absent, unloadable, or missing ``required_symbols`` (a prebuilt .so from
-    an older source revision loads fine but lacks newly added exports — the
-    symbol check forces a rebuild instead of an AttributeError later).
-    Returns a ``ctypes.CDLL`` or None."""
-    if lib_name in _cache:
+def build_all() -> Dict[str, bool]:
+    """Build every native target now; ``{name: built_in_this_call}``."""
+    return {name: ensure_built(name)[1] for name in TARGETS}
+
+
+def load_native(lib_name: str) -> Optional[ctypes.CDLL]:
+    """``ctypes.CDLL`` of ``native/<lib_name>``, built first when missing or
+    stale; ``None`` when it cannot be built or loaded."""
+    with _cache_lock:
+        if lib_name not in _cache:
+            _cache[lib_name] = _build_and_open(lib_name)
         return _cache[lib_name]
-    path = os.path.join(NATIVE_DIR, lib_name)
-    src = os.path.join(NATIVE_DIR, src_name)
 
-    def _try_load(at_path):
-        loaded = ctypes.CDLL(at_path)
-        for sym in required_symbols:
-            if not hasattr(loaded, sym):
-                raise OSError(f"{lib_name} is stale: missing symbol {sym}")
-        return loaded
 
-    lib = None
+def _build_and_open(lib_name: str) -> Optional[ctypes.CDLL]:
     try:
-        lib = _try_load(path)
-    except OSError:
+        path, built = ensure_built(lib_name)
+        if not built:
+            return ctypes.CDLL(path)
+        # glibc dedupes dlopen by pathname: had this process already opened
+        # an older file at ``path``, re-opening ``path`` would return the OLD
+        # mapping.  A private link has a fresh name.
+        private = f"{path}.load.{os.getpid()}"
         try:
-            lib = _build_and_load(src, path, extra_args, _try_load)
-        except (OSError, subprocess.SubprocessError) as exc:
-            log.info("native %s unavailable (%s); callers fall back to "
-                     "pure Python", lib_name, exc)
-    _cache[lib_name] = lib
-    return lib
+            os.link(path, private)
+            return ctypes.CDLL(private)
+        finally:
+            try:
+                os.unlink(private)
+            except OSError:
+                pass
+    except (OSError, subprocess.SubprocessError) as exc:
+        log.warning("native %s unavailable (%s); callers fall back to "
+                    "pure Python", lib_name, exc)
+        return None
+
+
+def loaded() -> Dict[str, bool]:
+    """Which shared libraries this process asked for, and whether each is
+    really loaded (False = its caller runs the pure-Python stand-in)."""
+    return {name: lib is not None for name, lib in _cache.items()}

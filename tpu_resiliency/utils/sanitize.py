@@ -248,6 +248,11 @@ class _TrackedRLock(_TrackedLock):
     def _is_owned(self):
         return self._inner._is_owned()
 
+    def _recursion_count(self):
+        # multiprocessing.resource_tracker (3.12.4+) asks its RLock this to
+        # refuse re-entrant calls from a GC'd finalizer
+        return self._inner._recursion_count()
+
 
 def _make_factory(kind: str):
     orig = _ORIG[kind]
