@@ -197,7 +197,9 @@ def test_declared_metrics_register_on_import():
 def _declared_flight_events():
     """(name, fields, rel, lineno) for every ``declare_event`` call with a
     literal first argument anywhere in the package — the flight-recorder
-    analog of :func:`_declared_metric_names`."""
+    analog of :func:`_declared_metric_names`.  ``declare_interval`` declares
+    two: its first two arguments, each with ``ident`` and ``parent`` before
+    the listed fields."""
     out = []
     for rel, path in _library_sources():
         with open(path) as f:
@@ -212,15 +214,22 @@ def _declared_flight_events():
                 ctor = func.attr
             else:
                 continue
-            if ctor != "declare_event" or not node.args:
+            n_names = {"declare_event": 1, "declare_interval": 2}.get(ctor)
+            if n_names is None or len(node.args) < n_names:
                 continue
-            first = node.args[0]
-            if isinstance(first, ast.Constant) and isinstance(first.value, str):
-                fields = tuple(
-                    a.value for a in node.args[1:]
-                    if isinstance(a, ast.Constant) and isinstance(a.value, str)
-                )
-                out.append((first.value, fields, rel, node.lineno))
+            literal = [
+                a.value for a in node.args
+                if isinstance(a, ast.Constant) and isinstance(a.value, str)
+            ]
+            if len(literal) < n_names or not all(
+                isinstance(a, ast.Constant) for a in node.args[:n_names]
+            ):
+                continue
+            fields = tuple(literal[n_names:])
+            if ctor == "declare_interval":
+                fields = ("ident", "parent") + fields
+            for name in literal[:n_names]:
+                out.append((name, fields, rel, node.lineno))
     return out
 
 

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import glob
+import itertools
 import json
 import os
 import queue as queue_mod
@@ -47,7 +48,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ...telemetry import counter, gauge, histogram
+from ...telemetry import counter, flight, gauge, histogram
 from ...utils import env
 from ...utils.logging import get_logger
 from .core import (  # noqa: F401 - CheckpointSaveError re-exported for callers
@@ -58,7 +59,13 @@ from .core import (  # noqa: F401 - CheckpointSaveError re-exported for callers
 )
 from ...utils.dtypes import coerce_dtype
 from . import resident as resident_mod
-from .staging import StagedTree, plan_signature, shard_payload, stage_pytree
+from .staging import (
+    IV_STAGE,
+    StagedTree,
+    plan_signature,
+    shard_payload,
+    stage_pytree,
+)
 from .writer import (
     _RestoreEngine,
     is_committed,
@@ -92,6 +99,43 @@ _DRAIN_PROGRESS = gauge(
     "tpurx_ckpt_drain_progress",
     "Fraction (0-1) of in-flight save bytes the worker has written",
 )
+_SNAP_RING_BYTES = gauge(
+    "tpurx_ckpt_snap_ring_bytes",
+    "Device bytes held by the live slots of the snapshot ring",
+)
+
+# Flight-recorder intervals of one save (ident = the save ticket).  The call
+# itself is prepare + snapshot + handoff; staging runs on the stager thread
+# and the drain (core.py) ends at the commit, so those are intervals of
+# their own that share the ticket.
+IV_SAVE = flight.declare_interval("ckpt.save_begin", "ckpt.save_end")
+IV_SAVE_PREPARE = flight.declare_interval(
+    "ckpt.save.prepare_begin", "ckpt.save.prepare_end"
+)
+IV_SAVE_SNAPSHOT = flight.declare_interval(
+    "ckpt.save.snapshot_begin", "ckpt.save.snapshot_end"
+)
+IV_SAVE_HANDOFF = flight.declare_interval(
+    "ckpt.save.handoff_begin", "ckpt.save.handoff_end"
+)
+# ... and of one restore (ident = a process-local load number)
+IV_LOAD = flight.declare_interval("ckpt.load_begin", "ckpt.load_end")
+IV_LOAD_PLAN = flight.declare_interval(
+    "ckpt.load.plan_begin", "ckpt.load.plan_end"
+)
+IV_LOAD_START = flight.declare_interval(
+    "ckpt.load.start_begin", "ckpt.load.start_end"
+)
+IV_LOAD_WAIT = flight.declare_interval(
+    "ckpt.load.wait_begin", "ckpt.load.wait_end"
+)
+IV_LOAD_PLACE = flight.declare_interval(
+    "ckpt.load.place_begin", "ckpt.load.place_end"
+)
+IV_LOAD_RELEASE = flight.declare_interval(
+    "ckpt.load.release_begin", "ckpt.load.release_end"
+)
+_LOAD_SEQ = itertools.count(1)
 
 
 _SNAP_FN = None
@@ -288,82 +332,104 @@ class AsyncCheckpointer:
         id, so stale index files from a previous run into the same directory
         (possibly with a different world size) are never committed."""
         call_t0 = time.monotonic_ns()
-        mode = stage_mode or self.stage_mode or self._resolve_stage_mode(tree)
-        self.last_stage_mode = mode
-        os.makedirs(ckpt_dir, exist_ok=True)
-        if save_id is None:
-            save_id = str((extra_metadata or {}).get("iteration", "default"))
-        # drop our own leftovers from any previous save into this directory
-        for stale in (
-            os.path.join(ckpt_dir, f"process_{self.process_index}.json"),
-            os.path.join(ckpt_dir, "metadata.json") if self.rank == 0 else None,
-        ):
-            if stale and os.path.exists(stale):
-                os.unlink(stale)
-        sig = plan_signature(tree, self.process_index)
         self._save_seq += 1
-        snap_slot = None
-        if mode == "snapshot":
-            # also copies host-only trees: the stager must never hold raw
-            # references the trainer can mutate in place after we return
-            tree, snap_slot = self._ring_snapshot(tree, sig)  # async; no D2H yet
-        job = _StagingJob(tree=tree, plan_sig=sig, ticket=self._save_seq,
-                          save_id=save_id)
-        if snap_slot is not None:
-            snap_slot["job"] = job
-            with self._snap_lock:
-                self._snap_ring.append(snap_slot)
-                while len(self._snap_ring) > self._ring_cap():
-                    self._snap_ring.pop(0)  # evicted slot's buffers just drop
-        if digest is None:
-            digest = self.digest
-        effective_digest = (
-            digest if digest is not None else env.CKPT_DIGEST.get()
-        )
-        if delta is None:
-            delta = self.delta if self.delta is not None else env.CKPT_DELTA.get()
-        from . import device_digest as device_digest_mod
+        ticket = self._save_seq
+        with flight.span(IV_SAVE, ticket):
+            with flight.span(IV_SAVE_PREPARE, ticket, IV_SAVE):
+                mode = (
+                    stage_mode or self.stage_mode
+                    or self._resolve_stage_mode(tree)
+                )
+                self.last_stage_mode = mode
+                os.makedirs(ckpt_dir, exist_ok=True)
+                if save_id is None:
+                    save_id = str(
+                        (extra_metadata or {}).get("iteration", "default")
+                    )
+                # drop our own leftovers from any previous save into this
+                # directory
+                for stale in (
+                    os.path.join(ckpt_dir, f"process_{self.process_index}.json"),
+                    os.path.join(ckpt_dir, "metadata.json")
+                    if self.rank == 0 else None,
+                ):
+                    if stale and os.path.exists(stale):
+                        os.unlink(stale)
+                sig = plan_signature(tree, self.process_index)
+            snap_slot = None
+            if mode == "snapshot":
+                # also copies host-only trees: the stager must never hold raw
+                # references the trainer can mutate in place after we return
+                with flight.span(IV_SAVE_SNAPSHOT, ticket, IV_SAVE):
+                    # async; no D2H yet
+                    tree, snap_slot = self._ring_snapshot(tree, sig)
+            with flight.span(IV_SAVE_HANDOFF, ticket, IV_SAVE):
+                job = _StagingJob(tree=tree, plan_sig=sig, ticket=ticket,
+                                  save_id=save_id)
+                if snap_slot is not None:
+                    snap_slot["job"] = job
+                    with self._snap_lock:
+                        self._snap_ring.append(snap_slot)
+                        while len(self._snap_ring) > self._ring_cap():
+                            # the evicted slot's buffers just drop
+                            self._snap_ring.pop(0)
+                        self._note_ring_bytes()
+                if digest is None:
+                    digest = self.digest
+                effective_digest = (
+                    digest if digest is not None else env.CKPT_DIGEST.get()
+                )
+                if delta is None:
+                    delta = (
+                        self.delta if self.delta is not None
+                        else env.CKPT_DELTA.get()
+                    )
+                from . import device_digest as device_digest_mod
 
-        job.device_digest = bool(effective_digest) and (
-            self.device_digest if self.device_digest is not None
-            else device_digest_mod.enabled()
-        )
-        base = self._delta_baseline
-        if (delta and effective_digest and base is not None
-                and base["sig"] == sig):
-            job.delta_base = base["chunks"]
-            job.delta_fps = base.get("device_fps")
-            job.delta_save_id = str(base.get("save_id") or "")
-        finalize_fns: List[Callable] = []
-        if self.rank == 0:
-            extra = extra_metadata
-            finalize_fns.append(
-                lambda: self._merger.finalize(ckpt_dir, job.staged, extra, save_id)
-            )
-        # every rank: fold the committed index back into the trainer — the
-        # delta baseline for the next save, and (when enabled) the resident
-        # publish binding index digests to the staged shm buffers
-        finalize_fns.append(
-            lambda: self._after_commit(ckpt_dir, job, save_id, sig)
-        )
-        req = AsyncRequest(
-            async_fn=write_process_shards_streamed,
-            async_fn_args=(
-                ckpt_dir, self.process_index, self.write_threads, save_id, sig,
-                digest,
-            ),
-            finalize_fns=finalize_fns,
-            cleanup_fns=[lambda: self._release_job(job)],
-        )
-        job.stream = self.queue.schedule_streamed_request(req)
-        if mode == "sync":
-            self._run_staging(job)
-        else:
-            self._ensure_stager()
-            self._stage_q.put(job)
+                job.device_digest = bool(effective_digest) and (
+                    self.device_digest if self.device_digest is not None
+                    else device_digest_mod.enabled()
+                )
+                base = self._delta_baseline
+                if (delta and effective_digest and base is not None
+                        and base["sig"] == sig):
+                    job.delta_base = base["chunks"]
+                    job.delta_fps = base.get("device_fps")
+                    job.delta_save_id = str(base.get("save_id") or "")
+                finalize_fns: List[Callable] = []
+                if self.rank == 0:
+                    extra = extra_metadata
+                    finalize_fns.append(
+                        lambda: self._merger.finalize(
+                            ckpt_dir, job.staged, extra, save_id
+                        )
+                    )
+                # every rank: fold the committed index back into the trainer
+                # — the delta baseline for the next save, and (when enabled)
+                # the resident publish binding index digests to the staged
+                # shm buffers
+                finalize_fns.append(
+                    lambda: self._after_commit(ckpt_dir, job, save_id, sig)
+                )
+                req = AsyncRequest(
+                    async_fn=write_process_shards_streamed,
+                    async_fn_args=(
+                        ckpt_dir, self.process_index, self.write_threads,
+                        save_id, sig, digest,
+                    ),
+                    finalize_fns=finalize_fns,
+                    cleanup_fns=[lambda: self._release_job(job)],
+                    ticket=ticket,
+                )
+                job.stream = self.queue.schedule_streamed_request(req)
+                if mode == "sync":
+                    self._run_staging(job)
+                else:
+                    self._ensure_stager()
+                    self._stage_q.put(job)
         _SAVES.inc()
         _SAVE_CALL_NS.observe(time.monotonic_ns() - call_t0)
-        return self._save_seq
+        return ticket
 
     def save(self, tree: Any, ckpt_dir: str, extra_metadata: Optional[Dict] = None) -> None:
         """Synchronous save (stage + write + commit before returning)."""
@@ -402,6 +468,14 @@ class AsyncCheckpointer:
         )
         return max(1, int(cap))
 
+    def _note_ring_bytes(self) -> None:
+        """Publish the ring's live device bytes; call with ``_snap_lock``
+        held, wherever ``_snap_ring`` changed."""
+        _SNAP_RING_BYTES.set(sum(
+            int(leaf.nbytes)
+            for slot in self._snap_ring for leaf in slot["leaves"]
+        ))
+
     def _ring_snapshot(self, tree: Any, sig: str) -> Tuple[Any, Optional[Dict]]:
         """Device snapshot through the double-buffered ring: with
         ``stage_buffers >= 2``, the copy DONATES a previous slot's device
@@ -429,6 +503,7 @@ class AsyncCheckpointer:
                     if (s["sig"] == sig and len(s["leaves"]) == len(dev_idx)
                             and (s["job"] is None or s["job"].done.is_set())):
                         slot = self._snap_ring.pop(i)
+                        self._note_ring_bytes()
                         break
         copies: List[Any] = []
         if dev_idx:
@@ -493,6 +568,7 @@ class AsyncCheckpointer:
         """Stage ``job.tree`` into shm, streaming the plan then each shard to
         the worker the moment its bytes land — the drain overlaps staging."""
         stream = job.stream
+        flight.begin(IV_STAGE, job.ticket)
 
         def _payload(info):
             p = shard_payload(info)
@@ -538,6 +614,7 @@ class AsyncCheckpointer:
                         ("shards", [_payload(info)])
                     ),
                     digest_ctx=digest_ctx,
+                    ident=job.ticket,
                 )
             except BaseException:
                 if pooled is not None:
@@ -570,6 +647,7 @@ class AsyncCheckpointer:
         finally:
             job.tree = None  # free the device snapshot
             job.done.set()
+            flight.end(IV_STAGE, job.ticket)
 
     def _release_job(self, job: _StagingJob) -> None:
         with job.lock:
@@ -737,6 +815,7 @@ class AsyncCheckpointer:
                 self._stager.join(timeout=10)
             with self._snap_lock:
                 self._snap_ring.clear()  # drop device snapshot references
+                self._note_ring_bytes()
             self._drain_pool()
             self.queue.close()
 
@@ -915,67 +994,80 @@ def load_checkpoint(
     in flight and every chunk re-verified against the committed index here.
     ``stats["bytes_peer"]`` reports how much came over the wire.
     """
-    use_res = env.CKPT_RESIDENT.get() if resident is None else resident
-    rc = resident_mod.lookup(ckpt_dir) if (use_res and not serial) else None
-    res_bufs: Optional[Dict[Tuple[int, int, int], memoryview]] = None
-    if rc is not None:
-        res_bufs = {
-            (rc.process_index, l, s): buf
-            for (l, s), buf in rc.buffers().items()
-        }
-    if rc is not None and rc.complete and res_bufs:
-        meta = rc.as_meta()  # committed index from memory: zero file opens
-    else:
-        if not is_committed(ckpt_dir):
-            raise FileNotFoundError(f"no committed checkpoint at {ckpt_dir}")
-        meta = (reader or _default_reader).read(ckpt_dir)
-
-    if peers is not None and not serial:
-        # peer-memory rung: pull shards whose local bytes are missing from
-        # other ranks' resident generations, then hand them to the engine as
-        # additional in-memory sources (chunk crcs re-verified on copy)
-        res_bufs = dict(res_bufs or {})
-        peer_bytes = peers.fetch_missing(ckpt_dir, meta, res_bufs)
-        if stats is not None:
-            stats["bytes_peer"] = peer_bytes
-        if not res_bufs:
-            res_bufs = None
-
     import jax.tree_util as jtu
 
-    leaves, treedef = jtu.tree_flatten(template)
-    if len(leaves) != len(meta["leaf_paths"]):
-        raise ValueError(
-            f"template has {len(leaves)} leaves, checkpoint has "
-            f"{len(meta['leaf_paths'])}"
-        )
-    t0 = time.monotonic_ns()
-    out_leaves: List[Any] = [None] * len(leaves)
-    if serial:
-        for i, tmpl in enumerate(leaves):
-            arr = read_leaf(ckpt_dir, meta, i)
-            out_leaves[i] = _place_leaf(tmpl, arr, meta["leaf_paths"][i])
+    load_id = next(_LOAD_SEQ)
+    with flight.span(IV_LOAD, load_id):
+        with flight.span(IV_LOAD_PLAN, load_id, IV_LOAD):
+            use_res = env.CKPT_RESIDENT.get() if resident is None else resident
+            rc = resident_mod.lookup(ckpt_dir) if (use_res and not serial) else None
+            res_bufs: Optional[Dict[Tuple[int, int, int], memoryview]] = None
+            if rc is not None:
+                res_bufs = {
+                    (rc.process_index, l, s): buf
+                    for (l, s), buf in rc.buffers().items()
+                }
+            if rc is not None and rc.complete and res_bufs:
+                meta = rc.as_meta()  # committed index from memory: zero file opens
+            else:
+                if not is_committed(ckpt_dir):
+                    raise FileNotFoundError(
+                        f"no committed checkpoint at {ckpt_dir}"
+                    )
+                meta = (reader or _default_reader).read(ckpt_dir)
+
+            if peers is not None and not serial:
+                # peer-memory rung: pull shards whose local bytes are missing
+                # from other ranks' resident generations, then hand them to the
+                # engine as additional in-memory sources (chunk crcs re-verified
+                # on copy)
+                res_bufs = dict(res_bufs or {})
+                peer_bytes = peers.fetch_missing(ckpt_dir, meta, res_bufs)
+                if stats is not None:
+                    stats["bytes_peer"] = peer_bytes
+                if not res_bufs:
+                    res_bufs = None
+
+            leaves, treedef = jtu.tree_flatten(template)
+            if len(leaves) != len(meta["leaf_paths"]):
+                raise ValueError(
+                    f"template has {len(leaves)} leaves, checkpoint has "
+                    f"{len(meta['leaf_paths'])}"
+                )
+        t0 = time.monotonic_ns()
+        out_leaves: List[Any] = [None] * len(leaves)
+
+        def place(idx: int, arr: np.ndarray) -> None:
+            with flight.span(IV_LOAD_PLACE, load_id, IV_LOAD):
+                out_leaves[idx] = _place_leaf(
+                    leaves[idx], arr, meta["leaf_paths"][idx]
+                )
+
+        if serial:
+            for i in range(len(leaves)):
+                place(i, read_leaf(ckpt_dir, meta, i))
+            if stats is not None:
+                stats.update(
+                    {"threads": 1, "restore_ns": time.monotonic_ns() - t0}
+                )
+            return jtu.tree_unflatten(treedef, out_leaves)
+        with flight.span(IV_LOAD_START, load_id, IV_LOAD):
+            engine = _RestoreEngine(
+                ckpt_dir, meta, num_threads=resolve_restore_threads(threads),
+                leaf_indices=range(len(leaves)), resident=res_bufs,
+            )
+        try:
+            while True:
+                with flight.span(IV_LOAD_WAIT, load_id, IV_LOAD):
+                    idx, payload = engine.ready.get()
+                if idx is None:
+                    if payload is not None:
+                        raise payload
+                    break
+                place(idx, payload)
+        finally:
+            with flight.span(IV_LOAD_RELEASE, load_id, IV_LOAD):
+                engine.close()
         if stats is not None:
-            stats.update(
-                {"threads": 1, "restore_ns": time.monotonic_ns() - t0}
-            )
+            stats.update(engine.stats())
         return jtu.tree_unflatten(treedef, out_leaves)
-    engine = _RestoreEngine(
-        ckpt_dir, meta, num_threads=resolve_restore_threads(threads),
-        leaf_indices=range(len(leaves)), resident=res_bufs,
-    )
-    try:
-        while True:
-            idx, payload = engine.ready.get()
-            if idx is None:
-                if payload is not None:
-                    raise payload
-                break
-            out_leaves[idx] = _place_leaf(
-                leaves[idx], payload, meta["leaf_paths"][idx]
-            )
-    finally:
-        engine.close()
-    if stats is not None:
-        stats.update(engine.stats())
-    return jtu.tree_unflatten(treedef, out_leaves)

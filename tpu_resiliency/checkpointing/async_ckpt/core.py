@@ -44,8 +44,9 @@ log = get_logger("async_ckpt")
 
 # flight-recorder span pair: one drain from schedule to finalize (the
 # black-box answer to "was a checkpoint in flight when the fault hit")
-EV_DRAIN_BEGIN = flight.declare_event("ckpt.drain_begin", "call_idx")
-EV_DRAIN_END = flight.declare_event("ckpt.drain_end", "call_idx")
+IV_DRAIN = flight.declare_interval(
+    "ckpt.drain_begin", "ckpt.drain_end", "call_idx"
+)
 
 
 @dataclasses.dataclass
@@ -67,6 +68,13 @@ class AsyncRequest:
     finalize_fns: Sequence[Callable] = ()
     cleanup_fns: Sequence[Callable] = ()
     call_idx: int = 0
+    # the checkpointer's save ticket: the ident the drain's flight interval
+    # shares with the save's other intervals (None: the call index serves)
+    ticket: Optional[int] = None
+
+    @property
+    def flight_ident(self) -> int:
+        return self.call_idx if self.ticket is None else self.ticket
 
     def execute_sync(self) -> None:
         if self.preload_fn is not None:
@@ -394,7 +402,7 @@ class AsyncCallsQueue:
         self._call_idx += 1
         req = dataclasses.replace(req, call_idx=self._call_idx)
         record_event(ProfilingEvent.CHECKPOINT_SAVE_STARTED, call_idx=req.call_idx)
-        flight.record(EV_DRAIN_BEGIN, req.call_idx)
+        flight.begin(IV_DRAIN, req.flight_ident, None, req.call_idx)
         try:
             if req.preload_fn is not None:
                 req.preload_fn()
@@ -415,7 +423,7 @@ class AsyncCallsQueue:
         self._call_idx += 1
         req = dataclasses.replace(req, call_idx=self._call_idx)
         record_event(ProfilingEvent.CHECKPOINT_SAVE_STARTED, call_idx=req.call_idx)
-        flight.record(EV_DRAIN_BEGIN, req.call_idx)
+        flight.begin(IV_DRAIN, req.flight_ident, None, req.call_idx)
         try:
             if req.preload_fn is not None:
                 req.preload_fn()
@@ -487,7 +495,7 @@ class AsyncCallsQueue:
             if stats is not None:
                 self.last_call_stats = stats
             record_event(ProfilingEvent.CHECKPOINT_SAVE_FINALIZED, call_idx=req.call_idx)
-            flight.record(EV_DRAIN_END, req.call_idx)
+            flight.end(IV_DRAIN, req.flight_ident, None, req.call_idx)
             self._pending.pop(0)
             finalized.append(req.call_idx)
         return finalized

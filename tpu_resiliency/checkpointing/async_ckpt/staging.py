@@ -70,8 +70,17 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from ...telemetry import flight
 from ...utils.logging import get_logger
 from ..coverage import covers
+
+# Flight-recorder intervals of the stager's side of one save (ident = the
+# save ticket): the whole staging job, and inside it the device-to-host
+# transfer — what a dispatch issued right after ``async_save`` queues behind.
+IV_STAGE = flight.declare_interval("ckpt.stage_begin", "ckpt.stage_end")
+IV_STAGE_D2H = flight.declare_interval(
+    "ckpt.stage.d2h_begin", "ckpt.stage.d2h_end"
+)
 
 log = get_logger("ckpt.staging")
 
@@ -317,6 +326,7 @@ def stage_pytree(
     on_plan: Optional[Callable[[int], None]] = None,
     on_shard_staged: Optional[Callable[[ShardInfo], None]] = None,
     digest_ctx: Optional[Any] = None,
+    ident: Optional[int] = None,
 ) -> StagedTree:
     """Stage all array leaves into shared memory.  Scalars / numpy leaves are
     staged too (uniform handling keeps the writer simple).
@@ -338,7 +348,10 @@ def stage_pytree(
     memcpy; their ``on_shard_staged`` fires immediately with provenance-only
     info (``skip_spans`` set).  Skipping additionally requires ``reuse``
     (the pooled segment must keep holding the shard's — identical —
-    bytes for the resident publish)."""
+    bytes for the resident publish).
+
+    ``ident`` (the save ticket) tags the ``ckpt.stage.d2h`` flight
+    interval: first transfer issued to last byte landed in shm."""
     treedef, paths, leaves = _leaf_paths(tree)
     pidx = process_index
     if pidx is None:
@@ -353,7 +366,7 @@ def stage_pytree(
         )
     try:
         return _stage_pipelined(staged, leaves, pidx, reusing,
-                                on_plan, on_shard_staged, digest_ctx)
+                                on_plan, on_shard_staged, digest_ctx, ident)
     except BaseException:
         if not reusing:
             staged.close(unlink=True)  # partial staging must not leak shm
@@ -424,6 +437,7 @@ def _stage_pipelined(
     on_plan: Optional[Callable[[int], None]],
     on_shard_staged: Optional[Callable[[ShardInfo], None]],
     digest_ctx: Optional[Any] = None,
+    ident: Optional[int] = None,
 ) -> StagedTree:
     work = _build_plan(staged, leaves, pidx, reusing)
     total = sum(w.info.nbytes for w in work)
@@ -470,55 +484,56 @@ def _stage_pipelined(
             elif unchanged is not None:
                 w.info.dev_unchanged = unchanged
 
-    # Kick off async D2H for every owned jax shard that transfers, before
-    # copying anything: all DMAs are in flight while shard-by-shard memcpys
-    # land below.  Skipped shards never transfer.
-    jax_pending = 0
-    for w in work:
-        if w.is_jax and not w.info.d2h_skipped:
-            w.source.data.copy_to_host_async()
-            jax_pending += 1
-
-    # skipped shards complete instantly: stream their provenance-only
-    # payloads first so the drain credits their bytes before any wait
-    if on_shard_staged is not None:
+    with flight.span(IV_STAGE_D2H, ident, IV_STAGE):
+        # Kick off async D2H for every owned jax shard that transfers, before
+        # copying anything: all DMAs are in flight while shard-by-shard memcpys
+        # land below.  Skipped shards never transfer.
+        jax_pending = 0
         for w in work:
-            if w.info.d2h_skipped:
-                on_shard_staged(w.info)
+            if w.is_jax and not w.info.d2h_skipped:
+                w.source.data.copy_to_host_async()
+                jax_pending += 1
 
-    shms = staged._shms if reusing else []
-    wait_s = copy_s = hidden_copy_s = 0.0
-    for k, w in enumerate(work):
-        if w.info.d2h_skipped:
-            continue  # slot k's shm keeps the (identical) baseline bytes
-        t0 = time.perf_counter()
-        if w.is_jax:
-            arr = np.asarray(w.source.data)  # completes THIS shard's D2H only
-            jax_pending -= 1
-        else:
-            arr = np.asarray(w.source)
-        t1 = time.perf_counter()
-        if reusing:
-            shm = shms[k]
-            if arr.nbytes != w.info.nbytes:
-                raise ValueError(
-                    f"restage size mismatch on leaf {w.info.leaf_idx}: "
-                    f"{arr.nbytes} != {w.info.nbytes} (stale plan signature?)"
-                )
-        else:
-            shm = create_shm(max(1, arr.nbytes))
-            staged._shms.append(shm)
-            w.info.shm_name = shm.name
-            w.info.nbytes = arr.nbytes
-        dst = np.ndarray(arr.shape, dtype=arr.dtype, buffer=shm.buf)
-        np.copyto(dst, arr, casting="no")
-        t2 = time.perf_counter()
-        wait_s += t1 - t0
-        copy_s += t2 - t1
-        if jax_pending > 0:  # this memcpy ran under at least one live DMA
-            hidden_copy_s += t2 - t1
+        # skipped shards complete instantly: stream their provenance-only
+        # payloads first so the drain credits their bytes before any wait
         if on_shard_staged is not None:
-            on_shard_staged(w.info)
+            for w in work:
+                if w.info.d2h_skipped:
+                    on_shard_staged(w.info)
+
+        shms = staged._shms if reusing else []
+        wait_s = copy_s = hidden_copy_s = 0.0
+        for k, w in enumerate(work):
+            if w.info.d2h_skipped:
+                continue  # slot k's shm keeps the (identical) baseline bytes
+            t0 = time.perf_counter()
+            if w.is_jax:
+                arr = np.asarray(w.source.data)  # completes THIS shard's D2H only
+                jax_pending -= 1
+            else:
+                arr = np.asarray(w.source)
+            t1 = time.perf_counter()
+            if reusing:
+                shm = shms[k]
+                if arr.nbytes != w.info.nbytes:
+                    raise ValueError(
+                        f"restage size mismatch on leaf {w.info.leaf_idx}: "
+                        f"{arr.nbytes} != {w.info.nbytes} (stale plan signature?)"
+                    )
+            else:
+                shm = create_shm(max(1, arr.nbytes))
+                staged._shms.append(shm)
+                w.info.shm_name = shm.name
+                w.info.nbytes = arr.nbytes
+            dst = np.ndarray(arr.shape, dtype=arr.dtype, buffer=shm.buf)
+            np.copyto(dst, arr, casting="no")
+            t2 = time.perf_counter()
+            wait_s += t1 - t0
+            copy_s += t2 - t1
+            if jax_pending > 0:  # this memcpy ran under at least one live DMA
+                hidden_copy_s += t2 - t1
+            if on_shard_staged is not None:
+                on_shard_staged(w.info)
 
     owned_bytes = sum(w.info.nbytes for w in work)
     staged.bytes_allocated = 0 if reusing else owned_bytes

@@ -29,23 +29,34 @@ Design:
   hooks — the in-process wrapper installs one that runs the attribution
   engine's ``trace_analyzer`` over the dump.
 
+- **Intervals are event pairs.**  :func:`declare_interval` declares a
+  ``<name>_begin`` / ``<name>_end`` pair; :func:`span` (one thread) or
+  :func:`begin` / :func:`end` (begin on one thread, end on another) record
+  them with the ``ident`` every interval of one operation shares (a save
+  ticket, a load number) and the ``parent`` interval's name.  A begin with
+  no end in a fault dump says where the process was stuck.
+
 Dump triggers wired across the repo: monitor trip, abort-ladder entry,
 ``CollectiveTimeout``, unhandled wrapper exceptions, ``GET /flight`` on
-the metrics exporter, and SIGUSR2.
+the metrics exporter, SIGUSR2, and — only where ``TPURX_FLIGHT_DIR`` names
+a directory — process exit (reason ``exit``).
 """
 
 from __future__ import annotations
 
+import atexit
+import contextlib
 import itertools
 import json
 import os
 import re
 import signal
 import socket
+import sys
 import tempfile
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..utils import env
 from ..utils.logging import get_logger
@@ -80,6 +91,38 @@ def event_names() -> List[str]:
 
 def event_fields(name: str) -> Tuple[str, ...]:
     return _EVENT_FIELDS[name]
+
+
+class Interval(NamedTuple):
+    """A declared begin/end event pair; ``name`` is what the two share
+    (``ckpt.save`` of ``ckpt.save_begin`` / ``ckpt.save_end``)."""
+
+    name: str
+    begin_event: str
+    end_event: str
+
+
+_INTERVALS: Dict[str, Interval] = {}
+
+
+def declare_interval(begin_event: str, end_event: str, *fields: str) -> Interval:
+    """Declare ``<name>_begin`` and ``<name>_end`` (literal names, once, at
+    module scope, like :func:`declare_event`) as the interval :func:`span`,
+    :func:`begin` and :func:`end` record.  Both events carry ``ident`` and
+    ``parent`` first, then ``fields``."""
+    name = begin_event[: -len("_begin")]
+    if not begin_event.endswith("_begin") or end_event != f"{name}_end":
+        raise ValueError(
+            f"not a <name>_begin/<name>_end pair: {begin_event!r}, {end_event!r}"
+        )
+    declare_event(begin_event, "ident", "parent", *fields)
+    declare_event(end_event, "ident", "parent", *fields)
+    _INTERVALS[name] = Interval(name, begin_event, end_event)
+    return _INTERVALS[name]
+
+
+def intervals() -> List[Interval]:
+    return [_INTERVALS[name] for name in sorted(_INTERVALS)]
 
 
 EV_DUMP = declare_event("flight.dump", "reason")
@@ -168,17 +211,77 @@ def flight_enabled() -> bool:
         return True
 
 
+def _parent_name(parent: Optional[Interval]) -> Optional[str]:
+    return None if parent is None else parent.name
+
+
+def _begin(
+    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any
+) -> None:
+    record(iv.begin_event, ident, _parent_name(parent), *extra)
+
+
+def _end(
+    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any
+) -> None:
+    record(iv.end_event, ident, _parent_name(parent), *extra)
+
+
+class _Span:
+    """One interval on one thread: the begin/end records and, where jax is
+    already loaded, a ``TraceAnnotation`` of the same name, so an operator's
+    own profiler capture shows the interval above the device's timeline."""
+
+    __slots__ = ("_iv", "_ident", "_parent", "_annotation")
+
+    def __init__(
+        self, iv: Interval, ident: Any, parent: Optional[Interval] = None
+    ):
+        self._iv, self._ident, self._parent = iv, ident, _parent_name(parent)
+        self._annotation = None
+
+    def __enter__(self) -> "_Span":
+        record(self._iv.begin_event, self._ident, self._parent)
+        profiler = sys.modules.get("jax.profiler")  # never imported here
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self._iv.name)
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        record(self._iv.end_event, self._ident, self._parent)
+
+
+_NOOP_SPAN = contextlib.nullcontext()
+
+
+def _noop_span(*_args: Any, **_kwargs: Any) -> Any:
+    return _NOOP_SPAN
+
+
+def _noop(*_args: Any, **_kwargs: Any) -> None:
+    return None
+
+
 def configure(
     enabled: Optional[bool] = None, capacity: Optional[int] = None
 ) -> None:
-    """(Re)build the process recorder and rebind :func:`record`."""
-    global _recorder, record
+    """(Re)build the process recorder and rebind :func:`record`,
+    :func:`span`, :func:`begin` and :func:`end`."""
+    global _recorder, record, span, begin, end
     if enabled is None:
         enabled = flight_enabled()
     if capacity is None:
         capacity = env.FLIGHT_RING.get()
     _recorder = FlightRecorder(capacity) if enabled else NOOP
     record = _recorder.record
+    # span(iv, ident, parent=None): context manager around an interval of
+    # one thread; begin/end(iv, ident, parent=None, *extra): the same pair
+    # for an interval that starts on one thread and ends on another
+    span = _Span if enabled else _noop_span
+    begin, end = (_begin, _end) if enabled else (_noop, _noop)
 
 
 def get_flight() -> Any:
@@ -317,6 +420,15 @@ def last_dump_path() -> Optional[str]:
     with _dump_lock:
         return _dump_paths[-1] if _dump_paths else None
 
+
+def _dump_at_exit() -> None:
+    """One last black box with the whole ring — only where the operator
+    named a directory for dumps: a job never sprays the temp directory."""
+    if env.FLIGHT_DIR.get():
+        dump("exit", min_interval_s=0.0)
+
+
+atexit.register(_dump_at_exit)
 
 _signal_installed = False
 

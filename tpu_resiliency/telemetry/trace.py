@@ -55,6 +55,40 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     "collective.dispatch": ("collective.settle", "collective", "collective"),
     "ckpt.drain_begin": ("ckpt.drain_end", "ckpt_drain", "checkpointing"),
     "ckpt.restore_begin": ("ckpt.restore_end", "ckpt_restore", "checkpointing"),
+    # flight intervals (flight.interval): one save is ckpt.save (prepare,
+    # snapshot, handoff) + ckpt.stage (d2h) + ckpt.drain, sharing the save
+    # ticket as ``ident``; one restore is ckpt.load and its children,
+    # sharing a load number
+    "ckpt.save_begin": ("ckpt.save_end", "ckpt.save", "checkpointing"),
+    "ckpt.save.prepare_begin": (
+        "ckpt.save.prepare_end", "ckpt.save.prepare", "checkpointing",
+    ),
+    "ckpt.save.snapshot_begin": (
+        "ckpt.save.snapshot_end", "ckpt.save.snapshot", "checkpointing",
+    ),
+    "ckpt.save.handoff_begin": (
+        "ckpt.save.handoff_end", "ckpt.save.handoff", "checkpointing",
+    ),
+    "ckpt.stage_begin": ("ckpt.stage_end", "ckpt.stage", "checkpointing"),
+    "ckpt.stage.d2h_begin": (
+        "ckpt.stage.d2h_end", "ckpt.stage.d2h", "checkpointing",
+    ),
+    "ckpt.load_begin": ("ckpt.load_end", "ckpt.load", "checkpointing"),
+    "ckpt.load.plan_begin": (
+        "ckpt.load.plan_end", "ckpt.load.plan", "checkpointing",
+    ),
+    "ckpt.load.start_begin": (
+        "ckpt.load.start_end", "ckpt.load.start", "checkpointing",
+    ),
+    "ckpt.load.wait_begin": (
+        "ckpt.load.wait_end", "ckpt.load.wait", "checkpointing",
+    ),
+    "ckpt.load.place_begin": (
+        "ckpt.load.place_end", "ckpt.load.place", "checkpointing",
+    ),
+    "ckpt.load.release_begin": (
+        "ckpt.load.release_end", "ckpt.load.release", "checkpointing",
+    ),
     # predict-and-evacuate: risk crossing → replacement's warm join is
     # the planned-handoff MTTR span (evac.ckpt_ahead / evac.promote
     # render as instants inside it)
@@ -156,6 +190,10 @@ def _span_key(rec: Dict[str, Any], start_event: str) -> Tuple:
         return (start_event, rec.get("section", ""))
     if start_event == "collective.dispatch":
         return (start_event, rec.get("op", ""), rec.get("axis", ""))
+    if "ident" in rec:
+        # flight intervals: two saves' drains overlap, and a begin on one
+        # thread ends on another — the shared ident pairs them, not LIFO
+        return (start_event, rec["ident"])
     return (start_event,)
 
 

@@ -423,7 +423,8 @@ FLIGHT_RING = Knob(
 FLIGHT_DIR = Knob(
     "TPURX_FLIGHT_DIR", str, None,
     "Directory for flight-recorder black-box dumps (default: the "
-    "system temp dir).", group="telemetry")
+    "system temp dir).  Only where it is set does a process also dump "
+    "its ring once when it ends (reason `exit`).", group="telemetry")
 FLIGHT_DUMP_KEEP = Knob(
     "TPURX_FLIGHT_DUMP_KEEP", int, 32,
     "Dump files retained per process; older dumps this process wrote "
