@@ -305,30 +305,43 @@ def test_snapshot_round_trip_leaves_every_interval_paired(tmp_path):
 
 def test_a_blocked_restore_records_its_wait(tmp_path, monkeypatch):
     """``ckpt.load.wait`` is the placing thread in ``engine.ready.get()``:
-    with the readers held back it has to wait for its first leaf."""
-    import time
-
+    with the readers held at a gate until that thread is seen waiting, one
+    wait begins before the gate opens and ends after it."""
     from tpu_resiliency.checkpointing import AsyncCheckpointer, load_checkpoint
     from tpu_resiliency.checkpointing.async_ckpt import writer
+    from tpu_resiliency.telemetry.clock import mono_ns
+
+    gate, opened_ns = threading.Event(), []
+    real = writer._RestoreEngine._worker
+
+    def held(self):
+        gate.wait(timeout=60)
+        return real(self)
+
+    def open_once_the_caller_waits():
+        deadline = mono_ns() + 60e9
+        while mono_ns() < deadline and not any(
+                r["event"] == "ckpt.load.wait_begin" for r in _records()):
+            gate.wait(timeout=0.002)
+        opened_ns.append(mono_ns())
+        gate.set()
 
     ckpt = AsyncCheckpointer()
+    opener = threading.Thread(target=open_once_the_caller_waits, daemon=True)
     try:
         ckpt.async_save(_tree(), str(tmp_path / "s"), stage_mode="snapshot")
         ckpt.finalize_all()
-        real = writer._ShardSource.read_span
-
-        def slow(self, *args):
-            time.sleep(0.05)
-            return real(self, *args)
-
-        monkeypatch.setattr(writer._ShardSource, "read_span", slow)
+        monkeypatch.setattr(writer._RestoreEngine, "_worker", held)
+        opener.start()
         load_checkpoint(str(tmp_path / "s"), _tree())
     finally:
+        gate.set()
         ckpt.close()
-    paired = _paired(_records())
-    waits = [iv for (name, _), found in paired.items()
+    opener.join(timeout=60)
+    (opened,) = opened_ns
+    waits = [iv for (name, _), found in _paired(_records()).items()
              if name == "ckpt.load.wait" for iv in found]
-    assert waits and max(end - begin for begin, end, _ in waits) >= 20e6
+    assert [1 for begin, end, _ in waits if begin <= opened <= end] == [1]
 
 
 def test_wrapped_steps_with_no_save_record_no_interval(store_server):

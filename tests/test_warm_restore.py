@@ -10,6 +10,7 @@ still covers every byte.  The local manager's ladder tries its own resident
 blob, then clique peers' resident copies over the TCP exchange, then disk.
 """
 
+import gc
 import json
 import os
 import shutil
@@ -27,6 +28,7 @@ from tpu_resiliency.checkpointing.async_ckpt.checkpointer import (
     AsyncCheckpointer,
     load_checkpoint,
 )
+from tpu_resiliency.checkpointing.integrity import CheckpointCorruptError
 from tpu_resiliency.checkpointing.local.manager import LocalCheckpointManager
 from tpu_resiliency.checkpointing.local.replication import (
     CliqueReplication,
@@ -169,6 +171,188 @@ class TestResidentRestore:
             cp.close()
         assert resident_mod.lookup(d1) is None
         assert resident_mod.lookup(d2) is not None
+
+
+# -- in place: verified where the bytes lie, placed from that view -------------
+
+
+def _save_resident(tmp_path, tree):
+    d = str(tmp_path / "ck")
+    cp = AsyncCheckpointer(digest=True, resident=True)
+    try:
+        cp.save(tree, d, extra_metadata={"iteration": 1})
+    finally:
+        cp.close()
+    rc = resident_mod.lookup(d)
+    assert rc is not None and rc.complete
+    return d, rc
+
+
+def _shard_of(rc, leaf_path):
+    """((leaf_idx, shard_idx), index entry) of ``leaf_path``'s lone shard."""
+    leaf_idx = rc.leaf_paths.index(leaf_path)
+    (found,) = [(k, s) for k, s in rc.shards.items() if k[0] == leaf_idx]
+    return found
+
+
+def _overwrite_segments(rc):
+    """What the next save's reuse of the staging tree does to them."""
+    for s in rc.shards.values():
+        s["buf"][: int(s["nbytes"])] = b"\xa5" * int(s["nbytes"])
+
+
+def _whole_leaf_shards_verified_where_they_lie(tmp_path, monkeypatch):
+    tree = make_tree(11)
+    d, _rc = _save_resident(tmp_path, tree)
+    _forbid_file_reads(monkeypatch)
+
+    def _no_second_buffer(nbytes):
+        raise AssertionError(f"a {nbytes}-byte leaf buffer beside the segment")
+
+    monkeypatch.setattr(writer_mod, "_alloc_aligned", _no_second_buffer)
+    before = get_registry().value_of("tpurx_ckpt_restore_in_place_bytes_total")
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert_trees_equal(tree, restored)
+    assert stats["bytes_read"] > 0
+    assert stats["bytes_in_place"] == stats["bytes_shm"] == stats["bytes_read"]
+    assert get_registry().value_of(
+        "tpurx_ckpt_restore_in_place_bytes_total"
+    ) - before == stats["bytes_in_place"]
+
+
+def _a_flipped_resident_byte_names_shard_and_offset(tmp_path, monkeypatch):
+    tree = {
+        "big": jax.device_put(np.arange(3 * 4096, dtype=np.float32)),
+        "ok": jax.device_put(np.ones(64, dtype=np.float32)),
+    }
+    monkeypatch.setenv("TPURX_CKPT_CHUNK_BYTES", "16384")  # three spans
+    d, rc = _save_resident(tmp_path, tree)
+    (leaf_idx, shard_idx), s = _shard_of(rc, "['big']")
+    assert len(s["chunks"]) == 3
+    s["buf"][16384 + 5] ^= 0xFF  # in the second span
+    placed = []
+    real = ckpt_mod._place_leaf
+    monkeypatch.setattr(
+        ckpt_mod, "_place_leaf",
+        lambda tmpl, arr, path: placed.append(path) or real(tmpl, arr, path),
+    )
+    with pytest.raises(CheckpointCorruptError) as err:
+        load_checkpoint(d, tree, threads=2)
+    assert writer_mod.shard_filename(leaf_idx, shard_idx) in str(err.value)
+    assert "offset 16384" in str(err.value)
+    assert "['big']" not in placed
+    # the error's traceback holds the readers' frames, and those views of
+    # the segment: gone before the fixture closes the segment under them
+    del err, s
+    gc.collect()
+
+
+def _restored_values_outlive_the_segments(tmp_path, monkeypatch):
+    """On the CPU backend ``device_put`` of a page-aligned host buffer IS
+    that buffer: handing it the resident view would make the restored
+    state follow the next save's bytes."""
+    tree = make_tree(13)
+    expect = jax.tree_util.tree_map(lambda x: np.array(x), tree)
+    d, rc = _save_resident(tmp_path, tree)
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert stats["bytes_in_place"] == stats["bytes_read"] > 0
+    _overwrite_segments(rc)
+    assert_trees_equal(expect, restored)
+
+
+def _several_shards_and_numpy_leaves_are_copied(tmp_path, monkeypatch):
+    mesh = Mesh(np.array(jax.devices()), ("x",))
+    rows = jax.device_put(
+        np.arange(64 * 32, dtype=np.float32).reshape(64, 32),
+        NamedSharding(mesh, P("x", None)),
+    )
+    cols = jax.device_put(
+        np.arange(16 * 64, dtype=np.float32).reshape(16, 64),
+        NamedSharding(mesh, P(None, "x")),
+    )
+    host = np.arange(512, dtype=np.int32)
+    tree = {"cols": cols, "host": host, "rows": rows}
+    expect = jax.tree_util.tree_map(lambda x: np.array(x), tree)
+    d, rc = _save_resident(tmp_path, tree)
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert stats["bytes_shm"] == stats["bytes_read"] == (
+        rows.nbytes + cols.nbytes + host.nbytes
+    )
+    # the sharded leaves were assembled in buffers of their own; the numpy
+    # leaf was verified where it lay, and what came back is a copy of it
+    assert stats["bytes_in_place"] == host.nbytes
+    assert isinstance(restored["host"], np.ndarray)
+    assert restored["host"].flags.writeable
+    (_key, s) = _shard_of(rc, "['host']")
+    assert not np.shares_memory(
+        restored["host"], np.frombuffer(s["buf"], dtype=np.uint8)
+    )
+    _overwrite_segments(rc)
+    assert_trees_equal(expect, restored)
+
+
+def _a_reader_copy_lets_the_caller_run(tmp_path, monkeypatch):
+    """The copying path's memcpy releases the GIL.  With switching on a
+    timer as good as off, the caller gets to run before the reader is done
+    only if something in the reader lets go of it: the crc is stubbed out,
+    so that something is the copy."""
+    import sys
+    import zlib
+
+    span, spans = 16 << 20, 8
+    nbytes = span * spans
+    resident = np.full(nbytes, 7, dtype=np.uint8)
+    crc = zlib.crc32(resident[:span]) & 0xFFFFFFFF
+    shard = {
+        "leaf_idx": 0, "shard_idx": 0, "process_index": 0,
+        "index": [[0, nbytes]], "shape": [nbytes], "nbytes": nbytes,
+        "chunks": [[i * span, span, crc] for i in range(spans)],
+    }
+    # half of a leaf of two shards: contiguous there, but not the whole of it
+    leaf = writer_mod._LeafRestore(0, (2 * nbytes,), np.dtype(np.uint8), 2)
+    source = writer_mod._ShardSource(
+        str(tmp_path), shard, leaf, np.dtype(np.uint8),
+        res_buf=memoryview(resident),
+    )
+    assert not source.in_place and source.from_shm
+    started, caller_ran, seen = threading.Event(), threading.Event(), []
+    monkeypatch.setattr(
+        writer_mod, "verify_chunk",
+        lambda data, want, *a, **k: seen.append(caller_ran.is_set()) or want,
+    )
+
+    def reader():
+        started.set()
+        for off, length, want in source.spans:
+            source.read_span(off, length, want)
+
+    t = threading.Thread(target=reader, daemon=True)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(3600.0)
+    try:
+        t.start()
+        started.wait(timeout=60)
+        caller_ran.set()
+        t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not t.is_alive() and len(seen) == spans
+    assert any(seen), "the caller never ran while the reader copied"
+    np.testing.assert_array_equal(leaf.out[:nbytes], resident)
+
+
+@pytest.mark.parametrize("case", [
+    _whole_leaf_shards_verified_where_they_lie,
+    _a_flipped_resident_byte_names_shard_and_offset,
+    _restored_values_outlive_the_segments,
+    _several_shards_and_numpy_leaves_are_copied,
+    _a_reader_copy_lets_the_caller_run,
+], ids=lambda case: case.__name__.lstrip("_"))
+def test_resident_in_place(case, tmp_path, monkeypatch):
+    case(tmp_path, monkeypatch)
 
 
 class TestDeltaSaves:

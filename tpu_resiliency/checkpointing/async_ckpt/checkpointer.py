@@ -945,6 +945,26 @@ def _place_leaf(tmpl: Any, arr: np.ndarray, leaf_path: str) -> Any:
     return np.asarray(arr, dtype=getattr(tmpl, "dtype", None))
 
 
+def _aliases_host(tmpl: Any) -> bool:
+    """Whether ``device_put`` onto ``tmpl``'s placement may hand back an
+    array that IS the host buffer it was given.  The CPU backend does that
+    (jax 0.9.0: ``unsafe_buffer_pointer()`` of the result equals the address
+    of a page-aligned source, read-only or not), and so may a host memory
+    kind; a device with memory of its own copies."""
+    sharding = tmpl.sharding
+    return "host" in (getattr(sharding, "memory_kind", None) or "") or any(
+        d.platform == "cpu" for d in sharding.device_set
+    )
+
+
+def _owned_copy(arr: np.ndarray) -> np.ndarray:
+    """A copy the caller owns, made with the GIL released (``np.copyto``
+    over byte views; a custom dtype's own copy loop may hold it)."""
+    out = np.empty(arr.shape, arr.dtype)
+    np.copyto(out.reshape(-1).view(np.uint8), arr.reshape(-1).view(np.uint8))
+    return out
+
+
 def load_checkpoint(
     ckpt_dir: str,
     template: Any,
@@ -964,18 +984,19 @@ def load_checkpoint(
     computed from ``metadata.json`` (size-bucketed shard read spans with
     their recorded ``(off, len, crc)`` digests) executed by a reader pool
     (``threads``, else ``TPURX_CKPT_RESTORE_THREADS``, else write-engine
-    sizing) that preads chunks straight into preallocated aligned leaf
-    buffers — no intermediate whole-shard bytes objects, no ``from_bytes``
-    copy — verifying every chunk's crc32 in-flight and the composed digest
-    per shard.  As each leaf's shards complete, its ``device_put`` is
-    enqueued while the remaining leaves are still reading, so disk read,
-    verify, and H2D transfer pipeline instead of serializing.
+    sizing) that preads chunks straight into aligned leaf buffers (one
+    lazily-faulted mapping per leaf read from disk) — no intermediate
+    whole-shard bytes objects, no ``from_bytes`` copy — verifying every
+    chunk's crc32 in-flight and the composed digest per shard.  As each
+    leaf's shards complete, its ``device_put`` is enqueued while the
+    remaining leaves are still reading, so read, verify, and H2D transfer
+    pipeline instead of serializing.
 
     ``serial=True`` keeps the one-leaf-at-a-time reference path (the
     restore bench's A/B baseline).  ``stats``, if given, is filled with the
-    engine's accounting (``bytes_read`` / ``bytes_shm`` / ``chunks`` /
-    ``shards`` / ``leaves`` / ``verify_ns`` / ``restore_ns`` /
-    ``threads``).
+    engine's accounting (``bytes_read`` / ``bytes_shm`` /
+    ``bytes_in_place`` / ``chunks`` / ``shards`` / ``leaves`` /
+    ``verify_ns`` / ``restore_ns`` / ``threads``).
 
     **Warm restore**: when the committed generation for ``ckpt_dir`` is
     still shm-resident (published at finalize, see ``resident.py``) and
@@ -986,6 +1007,18 @@ def load_checkpoint(
     ``stats["bytes_shm"]`` reports how much of the restore came warm.
     ``serial=True`` always reads from disk (it is the A/B baseline).
 
+    A resident shard that is the whole of its leaf is not copied on the
+    host at all: its spans are verified **where they lie** and, once all of
+    them have, the leaf is placed from a read-only view of the segment
+    (``stats["bytes_in_place"]``; a leaf of several shards, or one whose
+    box is not contiguous in it, is assembled in a buffer of its own as
+    from disk).  The segment is the stager's again at the next save, so
+    nothing restored may still depend on it when this function returns:
+    transfers from such views are waited for before it does, and where the
+    placement could alias host memory instead of copying it (the CPU
+    backend, a host memory kind, a numpy template leaf) the verified view
+    is copied once, outside the GIL, and the copy is placed.
+
     **Peer-memory sourcing**: ``peers`` (a
     :class:`~.peer_source.PeerRestoreSource`) adds a rung between shm and
     disk — shards whose local files are missing (this host lost its volume,
@@ -994,6 +1027,7 @@ def load_checkpoint(
     in flight and every chunk re-verified against the committed index here.
     ``stats["bytes_peer"]`` reports how much came over the wire.
     """
+    import jax
     import jax.tree_util as jtu
 
     load_id = next(_LOAD_SEQ)
@@ -1036,12 +1070,23 @@ def load_checkpoint(
                 )
         t0 = time.monotonic_ns()
         out_leaves: List[Any] = [None] * len(leaves)
+        # placed arrays whose transfer may still be reading a resident view
+        in_flight: List[Any] = []
 
-        def place(idx: int, arr: np.ndarray) -> None:
+        def place(idx: int, arr: np.ndarray, borrowed: bool = False) -> None:
+            """``borrowed``: ``arr`` is a view of a resident buffer, which
+            outlives this call only until the next save reuses it."""
             with flight.span(IV_LOAD_PLACE, load_id, IV_LOAD):
+                tmpl = leaves[idx]
+                if borrowed and (
+                    not isinstance(tmpl, jax.Array) or _aliases_host(tmpl)
+                ):
+                    arr, borrowed = _owned_copy(arr), False
                 out_leaves[idx] = _place_leaf(
-                    leaves[idx], arr, meta["leaf_paths"][idx]
+                    tmpl, arr, meta["leaf_paths"][idx]
                 )
+                if borrowed:
+                    in_flight.append(out_leaves[idx])
 
         if serial:
             for i in range(len(leaves)):
@@ -1064,8 +1109,13 @@ def load_checkpoint(
                     if payload is not None:
                         raise payload
                     break
-                place(idx, payload)
+                place(idx, payload, borrowed=idx in engine.in_place)
         finally:
+            if in_flight:
+                # placement is over when the device has the bytes: only then
+                # may the views go, and the segments be written again
+                with flight.span(IV_LOAD_PLACE, load_id, IV_LOAD):
+                    jax.block_until_ready(in_flight)
             with flight.span(IV_LOAD_RELEASE, load_id, IV_LOAD):
                 engine.close()
         if stats is not None:

@@ -35,7 +35,15 @@ published bytes equal the device bytes the fingerprints vouched for.
 
 Thread-safety: all registry mutation happens under one module lock; the
 published buffer views are read-only from the restore engine's perspective
-(writes only ever happen after an invalidate-on-reuse).
+(writes only ever happen after an invalidate-on-reuse).  The engine does
+not only read them while it verifies: a shard that is the whole of its leaf
+is placed on the device *from* its published view, so a host-to-device
+transfer may be reading a segment until ``load_checkpoint`` returns — which
+it does only once every such transfer has finished, and with every view of
+its own dropped.  Invalidate-on-reuse therefore stays safe for a save that
+starts after the restore returned (the one order a training loop has); a
+save started on another thread *during* a ``load_checkpoint`` of the
+generation it reuses was never supported and still is not.
 """
 
 from __future__ import annotations

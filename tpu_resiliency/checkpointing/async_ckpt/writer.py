@@ -149,6 +149,11 @@ _RESTORE_SOURCE = counter(
     "shard files; the local-manager ladder adds its own rung labels)",
     labels=("source",),
 )
+_RESTORE_IN_PLACE = counter(
+    "tpurx_ckpt_restore_in_place_bytes_total",
+    "Restored bytes verified where they lay in a resident buffer and placed "
+    "from that view: no reader-side copy (a subset of the shm rung's bytes)",
+)
 _DELTA_SKIPPED_BYTES = counter(
     "tpurx_ckpt_delta_skipped_bytes_total",
     "Bytes a delta save did NOT drain because the chunk crc matched the "
@@ -202,9 +207,13 @@ def resolve_write_threads(requested: Optional[int] = None) -> int:
 
 def resolve_restore_threads(requested: Optional[int] = None) -> int:
     """Reader pool size: explicit request, then ``TPURX_CKPT_RESTORE_THREADS``,
-    then the write-engine sizing — preads and ``zlib.crc32`` both release
-    the GIL, so the same oversubscription argument applies on the read
-    side."""
+    then the write-engine sizing — everything a reader does to a span
+    releases the GIL (``pread``, ``zlib.crc32``, and ``np.copyto`` where a
+    resident span has to be copied at all), so the same oversubscription
+    argument applies on the read side.  A memoryview slice assignment
+    in place of that ``np.copyto`` would break it: one ``memmove`` under the
+    GIL per span runs the pool one thread at a time and keeps the caller
+    from its ``Thread.start()`` and ``device_put`` calls."""
     if requested:
         return max(1, int(requested))
     try:
@@ -956,19 +965,39 @@ def _alloc_aligned(nbytes: int) -> np.ndarray:
 
 
 class _LeafRestore:
-    """One output leaf being assembled by the reader pool."""
+    """One output leaf being assembled by the reader pool.  Its bytes are
+    bound by its first shard source: the leaf's own aligned buffer
+    (:meth:`own_buffer`), or — a lone shard that is the whole leaf and
+    lies sealed in a resident buffer — a read-only view of that buffer
+    (:meth:`adopt`), in which case nothing is allocated at all."""
 
     def __init__(self, leaf_idx: int, global_shape: Tuple[int, ...],
-                 dtype: np.dtype):
+                 dtype: np.dtype, num_shards: int):
         import math
 
         self.leaf_idx = leaf_idx
         self.global_shape = global_shape
+        self.dtype = dtype
+        self.num_shards = num_shards
         self.nbytes = math.prod(int(s) for s in global_shape) * dtype.itemsize
-        self.raw = _alloc_aligned(self.nbytes)
-        self.out = self.raw[: self.nbytes].view(dtype).reshape(global_shape)
+        self.raw: Optional[np.ndarray] = None
+        self.out: Optional[np.ndarray] = None
         self.shards_left = 0
         self.boxes: List[Any] = []
+
+    def _bind(self, raw: np.ndarray) -> None:
+        self.raw = raw
+        self.out = (
+            raw[: self.nbytes].view(self.dtype).reshape(self.global_shape)
+        )
+
+    def own_buffer(self) -> np.ndarray:
+        if self.raw is None:
+            self._bind(_alloc_aligned(self.nbytes))
+        return self.raw
+
+    def adopt(self, buf: memoryview) -> None:
+        self._bind(np.frombuffer(buf.toreadonly(), dtype=np.uint8))
 
 
 class _ShardSource:
@@ -977,12 +1006,20 @@ class _ShardSource:
     index box is C-contiguous there (whole-leaf shards, leading-axis
     sharding), else into an aligned scratch placed on completion.
 
-    Byte sources, in warm-ladder order: a **resident shm buffer** (the
-    committed generation still staged in memory — no file is opened at
-    all), else the shard file — with delta-provenance spans routed to
-    their recorded base files (``chunks`` rows carrying a 4th element
-    index into the shard's ``bases`` path list).  Every span is crc-
-    verified against the committed index regardless of source."""
+    Byte sources, in warm-ladder order: a **resident buffer** (the
+    committed generation still staged in shm, or a shard the peer rung
+    fetched — no file is opened at all), else the shard file — with
+    delta-provenance spans routed to their recorded base files (``chunks``
+    rows carrying a 4th element index into the shard's ``bases`` path
+    list).  Every span is crc-verified against the committed index
+    regardless of source.
+
+    **In place**: a resident buffer that is the whole of its leaf in C
+    order has no destination — the leaf adopts a read-only view of it
+    (:attr:`in_place`), and reading a span is verifying it where it lies.
+    A resident buffer that is only part of its leaf (several shards, a box
+    that is not contiguous there) is still copied, by a call that
+    releases the GIL."""
 
     SITE = "restore_shard"
 
@@ -1046,14 +1083,27 @@ class _ShardSource:
         if not self.spans:
             self.spans = [(0, 0, None)]  # empty shard: one no-op task
         self.scratch: Optional[np.ndarray] = None
+        self.dst: Optional[np.ndarray] = None
+        #: the resident bytes as the copying path's source
+        self._res_u8: Optional[np.ndarray] = None
         co = contiguous_offset(
             leaf.global_shape, s["index"], dtype.itemsize
         )
-        if co is not None and co[1] == self.nbytes:
-            self.dst = leaf.raw[co[0] : co[0] + self.nbytes]
+        self.in_place = bool(
+            self.from_shm and self.nbytes and leaf.num_shards == 1
+            and co == (0, self.nbytes)
+        )
+        if self.in_place:
+            leaf.adopt(res_buf)
         else:
-            self.scratch = _alloc_aligned(self.nbytes)
-            self.dst = self.scratch
+            own = leaf.own_buffer()
+            if self.from_shm and self.nbytes:
+                self._res_u8 = np.frombuffer(res_buf, dtype=np.uint8)
+            if co is not None and co[1] == self.nbytes:
+                self.dst = own[co[0] : co[0] + self.nbytes]
+            else:
+                self.scratch = _alloc_aligned(self.nbytes)
+                self.dst = self.scratch
         self.lock = threading.Lock()
         self.chunks_left = len(self.spans)
         self.span_crcs: List[Tuple[int, int]] = []  # (off, crc)
@@ -1073,16 +1123,23 @@ class _ShardSource:
             return r
 
     def read_span(self, off: int, length: int, want: Optional[int]) -> int:
-        """Worker-thread unit: read the span into its final destination and
-        crc it in-flight.  Returns the verify CPU ns spent."""
+        """Worker-thread unit: read the span into its final destination —
+        or leave it where it lies (:attr:`in_place`) — and crc it
+        in-flight.  Returns the verify CPU ns spent."""
         if length == 0:
             return 0
-        mv = memoryview(self.dst)[off : off + length]
-        if self.res_buf is not None:
-            # verify the destination copy (catches the memcpy too)
-            mv[:] = self.res_buf[off : off + length]
+        if self.in_place:
+            mv = self.res_buf[off : off + length]
         else:
-            self._reader_for(off).pread_into(mv, off, length)
+            dst = self.dst[off : off + length]
+            mv = memoryview(dst)
+            if self._res_u8 is not None:
+                # np.copyto releases the GIL, a memoryview slice assignment
+                # does not; the crc below is of the copy, so it vouches for
+                # this memcpy too
+                np.copyto(dst, self._res_u8[off : off + length])
+            else:
+                self._reader_for(off).pread_into(mv, off, length)
         spent = 0
         if want is not None or self.chunks:
             t0 = time.monotonic_ns()
@@ -1124,7 +1181,11 @@ class _RestoreEngine:
     plan computed from ``metadata.json`` in, fully-verified leaf arrays out
     — pushed onto :attr:`ready` the moment each leaf's shards complete, so
     the consumer's ``device_put`` H2D transfers overlap the remaining
-    reads.  Size-bucketed work stealing (largest span class first) keeps a
+    reads.  A leaf in :attr:`in_place` comes out as a read-only view of the
+    resident buffer it was verified in: the consumer must not let that
+    view, or anything that may still be reading it, outlive the buffer's
+    reuse (``load_checkpoint`` settles its transfers before it returns).
+    Size-bucketed work stealing (largest span class first) keeps a
     late huge leaf from pinning one thread; the first chunk-level crc
     failure cancels all queued work and surfaces as the terminal error."""
 
@@ -1146,6 +1207,7 @@ class _RestoreEngine:
         # disk (shard_idx alone is only unique within one process)
         self._resident = resident or {}
         self.bytes_shm = 0
+        self.bytes_in_place = 0
         #: (leaf_idx, np.ndarray) per completed leaf, then a terminal
         #: ``(None, error-or-None)`` once the pool drains
         self.ready: "queue_mod.Queue[Tuple[Optional[int], Any]]" = (
@@ -1174,7 +1236,8 @@ class _RestoreEngine:
         for leaf_idx, shards in sorted(by_leaf.items()):
             dtype = resolve_dtype(shards[0]["dtype"])
             leaf = _LeafRestore(
-                leaf_idx, tuple(shards[0]["global_shape"]), dtype
+                leaf_idx, tuple(shards[0]["global_shape"]), dtype,
+                num_shards=len(shards),
             )
             self._leaves[leaf_idx] = leaf
             # big shards first so the pool saturates immediately
@@ -1194,6 +1257,10 @@ class _RestoreEngine:
                         length.bit_length(), collections.deque()
                     ).append((src, off, length, want))
                     self._pending += 1
+        #: leaves that come out as views of their resident buffer
+        self.in_place = frozenset(
+            src.leaf.leaf_idx for src in self._sources if src.in_place
+        )
         self._leaves_left = len(self._leaves)
         if self._leaves_left == 0:
             self._live = 0
@@ -1246,11 +1313,15 @@ class _RestoreEngine:
                     _RESTORE_SOURCE.labels(
                         source="shm" if src.from_shm else "disk"
                     ).inc(length)
+                    if src.in_place:
+                        _RESTORE_IN_PLACE.inc(length)
                     with self._cv:
                         self.bytes_read += length
                         self.chunks_read += 1
                         if src.from_shm:
                             self.bytes_shm += length
+                        if src.in_place:
+                            self.bytes_in_place += length
                         self._pending -= 1
                         if self._pending <= 0:
                             self._cv.notify_all()
@@ -1301,6 +1372,7 @@ class _RestoreEngine:
         return {
             "bytes_read": self.bytes_read,
             "bytes_shm": self.bytes_shm,
+            "bytes_in_place": self.bytes_in_place,
             "chunks": self.chunks_read,
             "shards": len(self._sources),
             "leaves": len(self._leaves),
@@ -1320,5 +1392,10 @@ class _RestoreEngine:
         if wedged:
             log.warning("ckpt restore close: reader thread(s) %s still "
                         "wedged in I/O", wedged)
+        # the engine's own views of the resident buffers go with it
         for src in self._sources:
             src.close_readers()
+            src.res_buf = src._res_u8 = None
+        for leaf in self._leaves.values():
+            leaf.raw = leaf.out = None
+        self._resident = {}
