@@ -2,12 +2,12 @@
 """Readings the limits of ``chipbench/limits/<configuration>.json`` are set from.
 
 Not part of a benchmark run.  On the chip, at the cell's own sizes, for each
-seed: the program's first three steps (``make_train_step`` at the
+seed: the program's first three steps (the family's step at the
 configuration's widths, the benchmark's weights and feed), the plain
-reference's, and the control's — the reference computed in the next lower
-precision — each compared with the reference exactly as a run compares the
-program.  A limit goes above the program's largest gap and below the
-control's smallest.
+reference's, and the controls' — the reference computed in the next lower
+precision, by the names the family gives as its ``CONTROLS`` — each compared
+with the reference exactly as a run compares the program.  A limit goes above
+the program's largest gap and below the control's smallest.
 
     chiprun -- python3 chipbench/control.py chipbench/configs/<name>.json <n> 101 102 ...
 
@@ -24,67 +24,63 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-CONTROLS = ("bf16_everywhere",)
 
-
-def program_first_steps(sizes, key, feed, n_steps=3):
+def program_first_steps(family, sizes, key, feed, n_steps=3):
     """The numbers a run takes from the program's first steps, without the
     hooks: the same jitted step, weights, feed and norm readers."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from chipbench import weights
-    from tpu_resiliency.models.transformer import TransformerConfig, make_train_step
 
-    cfg = TransformerConfig(
-        vocab=sizes.vocab_size, d_model=sizes.n_embd, n_heads=sizes.n_head,
-        n_layers=sizes.n_layer, d_ff=sizes.n_inner, max_seq=sizes.n_positions,
-        dtype=jnp.bfloat16)
-    step = make_train_step(cfg)
-    leaf_norms, change_norms = weights.make_norm_fns(sizes)
-    params, opt = weights.make_state_fn(sizes)(key)
+    step = family.make_step(sizes)
+    leaf_norms, change_norms = weights.make_norm_fns(family, sizes)
+    state = weights.make_state_fn(family, sizes)(key)
     losses, grad = [], None
     for i in range(n_steps):
-        params, opt, loss = step(params, opt, feed[i % len(feed)])
+        params, opt, loss = step(*state, feed[i % len(feed)])
+        state = (params, opt)
         if i == 0:
-            grad = np.asarray(leaf_norms(opt["mu"]), np.float64) / (1 - weights.ADAM_B1)
+            grad = np.asarray(leaf_norms(family.first_moment(state)),
+                              np.float64) / (1 - weights.ADAM_B1)
         losses.append(float(loss))
-    change = np.asarray(change_norms(opt["master"], key), np.float64)
-    for leaf in jax.tree_util.tree_leaves((params, opt)):
+    change = np.asarray(change_norms(family.master(state), key), np.float64)
+    for leaf in jax.tree_util.tree_leaves(state):
         leaf.delete()
     return {"loss": losses, "grad_norm": grad.tolist(), "change_norm": change.tolist()}
 
 
-def readings(config_file, seeds, rehearsal=False, controls=CONTROLS,
-             control_seeds=None):
-    """One row per seed; the controls run on the first ``control_seeds`` seeds
-    (all of them by default)."""
-    from chipbench import correct, weights
-    from chipbench.reference import gpt2_family
+def readings(config_file, seeds, rehearsal=False, control_seeds=None):
+    """One row per seed; the family's controls run on the first
+    ``control_seeds`` seeds (all of them by default)."""
+    from chipbench import correct, families, weights
 
-    sizes = weights.load_sizes(config_file, rehearsal=rehearsal)
+    family, sizes = families.of_file(config_file, rehearsal=rehearsal)
+    controls = family.CONTROLS
     rows = []
     for seed in seeds:
         key = weights.seed_key(seed)
         feed = weights.make_feed(sizes, key)
-        program = program_first_steps(sizes, key, feed)
+        program = program_first_steps(family, sizes, key, feed)
 
-        start = lambda: weights.make_reference_start_fn(sizes)(key)  # noqa: E731
+        start = lambda: weights.make_reference_start_fn(family, sizes)(key)  # noqa: E731
 
-        reference = gpt2_family.first_steps(start(), feed, sizes.n_head)
+        reference = family.reference_first_steps(start(), feed, sizes)
         row = {"seed": seed, "program": correct.gaps(program, reference)}
         if control_seeds is not None and len(rows) >= control_seeds:
             controls = ()
         for name in controls:
-            row[name] = correct.gaps(gpt2_family.first_steps(
-                start(), feed, sizes.n_head, precision=name), reference)
+            row[name] = correct.gaps(family.reference_first_steps(
+                start(), feed, sizes, precision=name), reference)
         rows.append(row)
         print(json.dumps(row), flush=True)
     return rows
 
 
-def summary(rows, controls=CONTROLS):
+def summary(rows):
+    """Per number: the program's largest gap over the rows and each control's
+    smallest over the rows that ran it."""
+    controls = sorted({name for r in rows for name in r} - {"seed", "program"})
     out = {}
     for number in ("loss_gap", "grad_norm_gap", "change_norm_gap"):
         out[number] = {"program_largest": max(r["program"][number] for r in rows)}

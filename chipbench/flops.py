@@ -1,7 +1,8 @@
-"""Operations and bytes the algorithm needs, from shapes, and the chip's peaks.
+"""The chip's peaks, and the operations and bytes of what is the product's
+and no model's: the snapshot copy.
 
-Kept with the benchmark so that no later PR can move the yardstick.  The
-functions take a :class:`chipbench.weights.Sizes`.
+Kept with the benchmark so that no later PR can move the yardstick.  A
+model's operations per token are its family's (``chipbench/families/``).
 """
 
 from __future__ import annotations
@@ -23,25 +24,11 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def forward_flops_per_token(sizes) -> float:
-    """Multiply-adds counted as two, one token through the forward pass:
-    the four attention projections and the two feed-forward matmuls of every
-    layer, causal attention over the (T+1)/2 keys an average query sees
-    (scores and weighted values), and the tied output head.  Norms, softmax,
-    GELU and the embedding gather are not counted."""
-    d, f, t = sizes.n_embd, sizes.n_inner, sizes.seq
-    per_layer = 2 * (4 * d * d + 2 * d * f) + 2 * 2 * d * (t + 1) / 2
-    return sizes.n_layer * per_layer + 2 * sizes.vocab_size * d
-
-
-def train_flops_per_token(sizes) -> float:
-    """Forward plus backward (twice the forward), nothing recomputed."""
-    return 3 * forward_flops_per_token(sizes)
-
-
-def step_mfu_pct(sizes, step_seconds: float, device_kind: str) -> float:
-    """Model FLOP/s utilisation of one chip at ``step_seconds`` a step."""
-    achieved = train_flops_per_token(sizes) * sizes.tokens_per_step / step_seconds
+def step_mfu_pct(family, sizes, step_seconds: float, device_kind: str) -> float:
+    """Model FLOP/s utilisation of one chip at ``step_seconds`` a step, by
+    the family's count of a token's forward and backward operations."""
+    achieved = (family.train_flops_per_token(sizes) * sizes.tokens_per_step
+                / step_seconds)
     return 100.0 * achieved / peaks(device_kind)["bf16_flops_per_s"]
 
 

@@ -1,6 +1,6 @@
 """Readers of what set-up measured: its own length and the bare step."""
 
-from chipbench import flops, weights
+from chipbench import families, flops
 
 
 def bare_step_ms(R):
@@ -9,12 +9,12 @@ def bare_step_ms(R):
 
 
 def step_mfu(R):
-    """The benchmark's FLOPs per token x tokens/s of the bare steps over the
+    """The family's FLOPs per token x tokens/s of the bare steps over the
     chip's published bf16 peak."""
     if not R.get("bare_step_s") or R.get("rehearsal"):
         return None
-    sizes = weights.load_sizes(R["config_file"])
-    return flops.step_mfu_pct(sizes, R["bare_step_s"], R["device"]["kind"])
+    family, sizes = families.of_file(R["config_file"])
+    return flops.step_mfu_pct(family, sizes, R["bare_step_s"], R["device"]["kind"])
 
 
 def setup_s(R):

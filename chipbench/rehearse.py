@@ -24,14 +24,12 @@ def main(config_file, with_reference=True):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench import weights
-    from chipbench.reference import gpt2_family
-    from tpu_resiliency.models.transformer import TransformerConfig, make_train_step
+    from chipbench import families, weights
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    sizes = weights.load_sizes(config_file)
+    family, sizes = families.of_file(config_file)
 
     def described(tree):
         return jax.tree_util.tree_map(
@@ -50,16 +48,12 @@ def main(config_file, with_reference=True):
         return row
 
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
-    params, opt = described(jax.eval_shape(weights.make_state_fn(sizes), key))
+    params, opt = described(jax.eval_shape(weights.make_state_fn(family, sizes), key))
     batch = tuple(jax.ShapeDtypeStruct((sizes.rows, sizes.seq), jnp.int32,
                                        sharding=chip) for _ in range(2))
-    cfg = TransformerConfig(
-        vocab=sizes.vocab_size, d_model=sizes.n_embd, n_heads=sizes.n_head,
-        n_layers=sizes.n_layer, d_ff=sizes.n_inner, max_seq=sizes.n_positions,
-        dtype=jnp.bfloat16)
     out = {"state_bytes": sizes.state_bytes, "n_params": sizes.n_params}
     out["train_step"] = report(
-        "train_step", make_train_step(cfg).lower(params, opt, batch).compile())
+        "train_step", family.make_step(sizes).lower(params, opt, batch).compile())
     leaves = jax.tree_util.tree_leaves((params, opt))
     # the expression of checkpointer._SNAP_FN / _SNAP_DONATE_FN
     out["snapshot_copy"] = report("snapshot_copy", jax.jit(
@@ -70,12 +64,13 @@ def main(config_file, with_reference=True):
     out["fingerprint"] = report("fingerprint", weights.make_fingerprint_fn().lower(
         (params, opt)).compile())
     if with_reference:
-        f32 = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=chip), params)
+        f32 = described(jax.eval_shape(
+            weights.make_reference_start_fn(family, sizes), key))
         count = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
         with jax.default_matmul_precision("highest"):
-            out["reference_step"] = report("reference_step", gpt2_family.make_step(
-                sizes.n_head).lower(f32, f32, f32, count, *batch).compile())
+            out["reference_step"] = report(
+                "reference_step", family.make_reference_step(sizes).lower(
+                    f32, f32, f32, count, *batch).compile())
     print(json.dumps(out))
 
 

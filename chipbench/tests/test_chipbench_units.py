@@ -18,7 +18,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, ROOT)
 
-from chipbench import correct, cycles, flops, trace_reduce, weights  # noqa: E402
+from chipbench import correct, cycles, families, flops, trace_reduce  # noqa: E402
+from chipbench.families import gpt2  # noqa: E402
 from chipbench.readers import read_metric  # noqa: E402
 
 
@@ -164,18 +165,18 @@ def test_recorded_v5e_slice(recorded):
 # -- FLOPs and bytes ----------------------------------------------------------------
 
 def test_flops_against_hand_counts():
-    sizes = weights.Sizes(name="hand", n_embd=4, n_head=2, n_layer=2, n_inner=8,
-                          n_positions=6, vocab_size=10, rows=3, seq=6, feed_batches=1)
+    sizes = gpt2.Sizes(name="hand", n_embd=4, n_head=2, n_layer=2, n_inner=8,
+                       n_positions=6, vocab_size=10, rows=3, seq=6, feed_batches=1)
     # per layer: q,k,v,o 4 x (4x4) and two 4x8 matmuls = 128 MACs -> 256 FLOPs;
     # causal attention: scores and values, 2 x 2 x d x (T+1)/2 = 2*2*4*3.5 = 56
     # head: 2 x 10 x 4 = 80
-    assert flops.forward_flops_per_token(sizes) == 2 * (256 + 56) + 80
-    assert flops.train_flops_per_token(sizes) == 3 * (2 * (256 + 56) + 80)
+    assert gpt2.forward_flops_per_token(sizes) == 2 * (256 + 56) + 80
+    assert gpt2.train_flops_per_token(sizes) == 3 * (2 * (256 + 56) + 80)
     assert sizes.n_params == (10 + 6) * 4 + 2 * (4 * 16 + 2 * 32 + 8) + 4
     assert sizes.state_bytes == sizes.n_params * 14 + 4
     assert sizes.tokens_per_step == 18
     # one step of 18 tokens a second, 3 x 704 FLOPs a token, against 197e12
-    assert flops.step_mfu_pct(sizes, 1.0, "TPU v5 lite") == pytest.approx(
+    assert flops.step_mfu_pct(gpt2, sizes, 1.0, "TPU v5 lite") == pytest.approx(
         100 * 3 * 704 * 18 / 197e12)
     assert flops.snapshot_copy_roofline_pct(819, 2.0, "TPU v5 lite") == pytest.approx(
         100 * (2 * 819 / 819e9) / 2.0)
@@ -186,12 +187,14 @@ def test_flops_against_hand_counts():
 
 
 def test_published_sizes_of_the_configurations():
-    xl = weights.load_sizes(os.path.join(ROOT, "chipbench/configs/gpt2-xl-1chip.json"))
-    cb = weights.load_sizes(os.path.join(ROOT, "chipbench/configs/cerebras-gpt-1.3b-1chip.json"))
+    family, xl = families.of_file(os.path.join(ROOT, "chipbench/configs/gpt2-xl-1chip.json"))
+    _, cb = families.of_file(os.path.join(ROOT, "chipbench/configs/cerebras-gpt-1.3b-1chip.json"))
+    assert family is gpt2 is families.load("gpt2")
     assert (xl.n_embd, xl.n_head, xl.n_inner, xl.vocab_size, xl.seq) == (1600, 25, 6400, 50257, 1024)
     assert (cb.n_embd, cb.n_head, cb.n_inner, cb.vocab_size, cb.seq) == (2048, 16, 8192, 50257, 2048)
     assert xl.n_params == 327_836_800 and cb.n_params == 258_129_920
     assert xl.tokens_per_step == cb.tokens_per_step == 4096
+    assert xl.state_bytes == 14 * xl.n_params + 4 and cb.state_bytes == 14 * cb.n_params + 4
 
 
 # -- the comparison ---------------------------------------------------------------------

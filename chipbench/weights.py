@@ -1,10 +1,11 @@
 """The benchmark's inputs: weights, optimizer state and token feed from ``--seed``.
 
 Everything is made on the device in one jitted call, in the types the step is
-run in (bf16 parameters; fp32 master copy, first and second moments), in the
-tree layout ``make_train_step`` takes.  The plain reference draws the same
-numbers through :func:`draw_params` and keeps them in float32, so neither side
-is handed anything the other has made.
+run in and in the tree layout it takes: what those are, the configuration's
+family says (``chipbench/families/``: ``draw_params``, ``make_state``).  The
+plain reference draws the same numbers through the family's ``draw_params``
+and keeps them in float32, so neither side is handed anything the other has
+made.
 
 Also here, because the comparison that decides ``correct`` needs them and no
 later PR may move them: the bit-exact fingerprint of a state tree and the
@@ -13,58 +14,7 @@ per-leaf norms of a tree and of the parameters' change since the seed.
 
 from __future__ import annotations
 
-import dataclasses
-import json
-import math
-
-ADAM_B1 = 0.9  # make_train_step's constant: first gradient = mu_1 / (1 - b1)
-
-
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    """One configuration file, as the benchmark uses it."""
-
-    name: str
-    n_embd: int
-    n_head: int
-    n_layer: int
-    n_inner: int
-    n_positions: int
-    vocab_size: int
-    rows: int
-    seq: int
-    feed_batches: int
-
-    @property
-    def tokens_per_step(self) -> int:
-        return self.rows * self.seq
-
-    @property
-    def n_params(self) -> int:
-        d, f = self.n_embd, self.n_inner
-        per_layer = 4 * d * d + 2 * d * f + 2 * d
-        return (self.vocab_size + self.n_positions) * d + self.n_layer * per_layer + d
-
-    @property
-    def state_bytes(self) -> int:
-        # bf16 parameter + fp32 master, mu, nu; the step counter's 4 bytes
-        return self.n_params * 14 + 4
-
-
-def load_sizes(path: str, rehearsal: bool = False) -> Sizes:
-    with open(path) as f:
-        cfg = json.load(f)
-    batch = dict(cfg["batch"])
-    if rehearsal:
-        cut = cfg["cpu_rehearsal_cut"]
-        cfg = {**cfg, **cut}
-        batch.update(rows=cut["rows"], seq=cut["n_positions"], feed_batches=4)
-    return Sizes(
-        name=cfg["name"], n_embd=cfg["n_embd"], n_head=cfg["n_head"],
-        n_layer=cfg["n_layer"], n_inner=cfg["n_inner"],
-        n_positions=cfg["n_positions"], vocab_size=cfg["vocab_size"],
-        rows=batch["rows"], seq=batch["seq"], feed_batches=batch["feed_batches"],
-    )
+ADAM_B1 = 0.9  # the steps' first-moment decay: first gradient = moment_1 / (1 - b1)
 
 
 def seed_key(seed: int):
@@ -76,55 +26,18 @@ def seed_key(seed: int):
         jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF)
 
 
-def draw_params(sizes: Sizes, key, dtype):
-    """The parameters in ``dtype``: normal draws scaled by 1/sqrt(fan_in)
-    (0.02 for the two embeddings), norm scales 1.  Traceable."""
-    import jax
-    import jax.numpy as jnp
-
-    d, f = sizes.n_embd, sizes.n_inner
-    keys = iter(jax.random.split(key, 2 + 6 * sizes.n_layer))
-
-    def dense(shape, scale=None):
-        scale = 1.0 / math.sqrt(shape[0]) if scale is None else scale
-        draw = jax.random.normal(next(keys), shape, dtype=jnp.float32) * scale
-        return draw.astype(dtype)
-
-    ones = lambda: jnp.ones((d,), dtype=dtype)  # noqa: E731
-    params = {
-        "embed": dense((sizes.vocab_size, d), 0.02),
-        "pos": dense((sizes.n_positions, d), 0.02),
-        "layers": [],
-        "ln_f_scale": ones(),
-    }
-    for _ in range(sizes.n_layer):
-        params["layers"].append({
-            "wq": dense((d, d)), "wk": dense((d, d)), "wv": dense((d, d)),
-            "wo": dense((d, d)), "w1": dense((d, f)), "w2": dense((f, d)),
-            "ln1_scale": ones(), "ln2_scale": ones(),
-        })
-    return params
-
-
-def make_state_fn(sizes: Sizes):
-    """jitted ``seed key -> (params, opt)`` as ``make_train_step`` takes them."""
+def make_state_fn(family, sizes):
+    """jitted ``seed key -> (params, opt)`` as the family's step takes them."""
     import jax
     import jax.numpy as jnp
 
     def chipbench_init_state(key):
-        params = draw_params(sizes, key, jnp.bfloat16)
-        f32 = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-            lambda p: p.astype(jnp.float32), tree)
-        zeros = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-            lambda p: jnp.zeros(p.shape, jnp.float32), tree)
-        opt = {"mu": zeros(params), "nu": zeros(params),
-               "count": jnp.zeros((), jnp.int32), "master": f32(params)}
-        return params, opt
+        return family.make_state(sizes, family.draw_params(sizes, key, jnp.bfloat16))
 
     return jax.jit(chipbench_init_state)
 
 
-def make_feed(sizes: Sizes, key):
+def make_feed(sizes, key):
     """``feed_batches`` batches of (tokens, targets), rows all different, on
     the device; step ``i`` takes batch ``i % feed_batches``."""
     import jax
@@ -168,11 +81,11 @@ def make_fingerprint_fn():
     return jax.jit(chipbench_fingerprint)
 
 
-def make_norm_fns(sizes: Sizes):
+def make_norm_fns(family, sizes):
     """jitted readers of what the reference is compared on: per-leaf norms of
-    a parameter-shaped tree (the first moment after one step gives the first
-    gradient as the optimizer got it), and per-leaf norms of the master
-    copy's change since the seed's draw."""
+    a tree of the draw's structure (the family's ``first_moment`` after one
+    step gives the first gradient as the optimizer got it), and per-leaf norms
+    of the change of the family's ``master`` since the seed's draw."""
     import jax
     import jax.numpy as jnp
 
@@ -185,14 +98,14 @@ def make_norm_fns(sizes: Sizes):
         return norms(tree)
 
     def chipbench_change_norms(master, key):
-        start = draw_params(sizes, key, jnp.bfloat16)
+        start = family.draw_params(sizes, key, jnp.bfloat16)
         return norms(jax.tree_util.tree_map(
             lambda m, s: m - s.astype(jnp.float32), master, start))
 
     return jax.jit(chipbench_leaf_norms), jax.jit(chipbench_change_norms)
 
 
-def make_reference_start_fn(sizes: Sizes):
+def make_reference_start_fn(family, sizes):
     """jitted ``seed key -> float32 tree`` of the seed's draw as the program
     holds it (rounded to bfloat16): where the plain reference starts."""
     import jax
@@ -200,6 +113,7 @@ def make_reference_start_fn(sizes: Sizes):
 
     def chipbench_reference_start(key):
         return jax.tree_util.tree_map(
-            lambda w: w.astype(jnp.float32), draw_params(sizes, key, jnp.bfloat16))
+            lambda w: w.astype(jnp.float32),
+            family.draw_params(sizes, key, jnp.bfloat16))
 
     return jax.jit(chipbench_reference_start)

@@ -2,13 +2,17 @@
 job loop shares.
 
 Copied from ``examples/train_with_launcher.py`` (PR 21) and cut: the same
-composition around ``make_train_step`` — ``inprocess.Wrapper`` with the
+composition around the model's train step — ``inprocess.Wrapper`` with the
 quorum tripwire on manual beats (budget calibrated under the real step, 250 ms
 operator floor), ``FaultToleranceCallback`` heartbeats to the launcher's rank
 monitor, the straggler ``Detector`` around the step, ``NestedRestarterCallback``,
 ``AsyncCheckpointer.async_save`` / ``load_checkpoint`` — called through the
 product's public entry points only.  Started by the launcher CLI; the jax-free
 parent ``chipbench/run.py`` starts that.
+
+The model is found by the configuration file's ``model_type``: a module of
+``chipbench/families/`` gives the sizes, the seed's draw, the state, the
+product's step and the plain reference, and this worker names no model.
 
 What is the benchmark's own, and not the product's: the weights and the feed
 (``weights.py``, from ``--seed``, on the device), the step and the save as a
@@ -110,11 +114,9 @@ def main(argv=None):
     cache_dir = compile_cache.enable()  # before the first jit
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from chipbench import correct, trace_reduce, weights
-    from chipbench.reference import gpt2_family
+    from chipbench import correct, families, trace_reduce, weights
     from tpu_resiliency.checkpointing import AsyncCheckpointer, load_checkpoint
     from tpu_resiliency.checkpointing.async_ckpt import resident
     from tpu_resiliency.fault_tolerance import (
@@ -132,10 +134,6 @@ def main(argv=None):
         FaultToleranceCallback,
         StragglerDetectionCallback,
     )
-    from tpu_resiliency.models.transformer import (
-        TransformerConfig,
-        make_train_step,
-    )
     from tpu_resiliency.telemetry import get_registry
 
     compiles = CompileCounter()
@@ -148,20 +146,15 @@ def main(argv=None):
         sys.exit(f"chipbench worker: needs {args.chips} TPU chip(s), JAX found "
                  f"{device['count']} x {device['platform']}")
 
-    sizes = weights.load_sizes(args.config, rehearsal=args.rehearsal)
+    family, sizes = families.of_file(args.config, rehearsal=args.rehearsal)
     n_first = 3  # steps the reference follows
     annotate = jax.profiler.TraceAnnotation
 
-    cfg = TransformerConfig(
-        vocab=sizes.vocab_size, d_model=sizes.n_embd, n_heads=sizes.n_head,
-        n_layers=sizes.n_layer, d_ff=sizes.n_inner, max_seq=sizes.n_positions,
-        dtype=jnp.bfloat16,
-    )
-    step_jit = make_train_step(cfg)
+    step_jit = family.make_step(sizes)
     key = weights.seed_key(args.seed)
-    init_state = weights.make_state_fn(sizes)
+    init_state = weights.make_state_fn(family, sizes)
     fingerprint = weights.make_fingerprint_fn()
-    leaf_norms, change_norms = weights.make_norm_fns(sizes)
+    leaf_norms, change_norms = weights.make_norm_fns(family, sizes)
 
     def memory():
         stats = [d.memory_stats() or {} for d in jax.local_devices()]
@@ -364,21 +357,22 @@ def main(argv=None):
             jax.block_until_ready(job.state)
             report("model", n_params=sizes.n_params, state_bytes=sizes.state_bytes,
                    rows=sizes.rows, seq=sizes.seq,
-                   dtype=str(job.state[0]["embed"].dtype))
+                   dtypes=",".join(sorted({str(leaf.dtype) for leaf in
+                                           jax.tree_util.tree_leaves(job.state)})))
             # the first steps, through the window's own call and feed; the
             # plain reference follows them after the window
             mu_norms = None
             for i in range(n_first):
                 run_step(cw)
                 if i == 0:
-                    mu_norms = leaf_norms(job.state[1]["mu"])
+                    mu_norms = leaf_norms(family.first_moment(job.state))
             fetch_pending()
             R["first_steps"] = {
                 "loss": [job.losses[i] for i in range(n_first)],
                 "grad_norm": (np.asarray(mu_norms, np.float64)
                               / (1.0 - weights.ADAM_B1)).tolist(),
                 "change_norm": np.asarray(
-                    change_norms(job.state[1]["master"], key), np.float64).tolist(),
+                    change_norms(family.master(job.state), key), np.float64).tolist(),
             }
             report("compiled", compiles=compiles.count,
                    step_cache=step_jit._cache_size())
@@ -469,8 +463,8 @@ def main(argv=None):
         """The plain reference follows the first three steps; run after the
         program's state is freed, so the peak stays the program's."""
         t0 = time.monotonic()
-        start = weights.make_reference_start_fn(sizes)(key)
-        ref = gpt2_family.first_steps(start, feed, sizes.n_head, n_steps=n_first)
+        start = weights.make_reference_start_fn(family, sizes)(key)
+        ref = family.reference_first_steps(start, feed, sizes, n_steps=n_first)
         found = correct.gaps(R["first_steps"], ref)
         limits = correct.load_limits(sizes.name, rehearsal=args.rehearsal)
         R["reference"] = {"numbers": ref, "gaps": found, "limits": limits,
