@@ -1,11 +1,16 @@
 """Reference workloads the resiliency layer wraps and benchmarks against."""
 
+from . import kimi_linear, kimi_linear_reference
+from .kimi_linear import KimiLinearConfig
 from .transformer import TransformerConfig, init_params, forward, loss_fn, make_train_step
 
 __all__ = [
+    "KimiLinearConfig",
     "TransformerConfig",
-    "init_params",
     "forward",
+    "init_params",
+    "kimi_linear",
+    "kimi_linear_reference",
     "loss_fn",
     "make_train_step",
 ]

@@ -22,6 +22,8 @@ from typing import Any, Dict
 
 import numpy as np
 
+from .adamw import adamw_leaf
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -210,8 +212,6 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 1e-3):
     import jax
     import jax.numpy as jnp
 
-    b1, b2, eps, wd = 0.9, 0.95, 1e-8, 0.01
-
     def step(params, opt, batch):
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch, cfg, mesh)
@@ -219,18 +219,6 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 1e-3):
         count = opt["count"] + 1
         cf = count.astype(jnp.float32)
         has_master = "master" in opt
-
-        def upd(p, g, mu, nu, master):
-            g32 = g.astype(jnp.float32)
-            mu2 = b1 * mu + (1 - b1) * g32
-            nu2 = b2 * nu + (1 - b2) * jnp.square(g32)
-            mu_hat = mu2 / (1 - b1 ** cf)
-            nu_hat = nu2 / (1 - b2 ** cf)
-            # update in fp32 against the master copy; cast down only for the
-            # compute params (sub-ulp updates accumulate in the master)
-            m = master if master is not None else p.astype(jnp.float32)
-            m2 = m - lr * (mu_hat / (jnp.sqrt(nu_hat) + eps) + wd * m)
-            return m2.astype(p.dtype), mu2, nu2, m2
 
         flat_p, treedef = jax.tree_util.tree_flatten(params)
         flat_g = jax.tree_util.tree_leaves(grads)
@@ -243,7 +231,7 @@ def make_train_step(cfg: TransformerConfig, mesh=None, lr: float = 1e-3):
         )
         new_p, new_mu, new_nu, new_master = [], [], [], []
         for p, g, mu, nu, m in zip(flat_p, flat_g, flat_mu, flat_nu, flat_master):
-            a, b, c, d = upd(p, g, mu, nu, m)
+            a, b, c, d = adamw_leaf(p, g, mu, nu, m, cf, lr)
             new_p.append(a)
             new_mu.append(b)
             new_nu.append(c)
