@@ -22,7 +22,8 @@ reports its BEST goodput; the gate asserts the closed loop beats that
 best fixed knob by >= 1.1x (``policy_goodput_gain``).  The sim is
 deterministic: same seed, same schedule, same verdict on every host.
 
-Emits one JSON line:  python benchmarks/bench_policy.py [--seed N]
+``tests/test_policy.py`` runs ``run(seed)`` and holds that verdict; by hand:
+python tests/harness/sim_policy.py [--seed N]  (one JSON line).
 """
 
 from __future__ import annotations
@@ -35,7 +36,8 @@ import os
 import random
 import sys
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
 from tpu_resiliency.policy import (  # noqa: E402
@@ -147,7 +149,7 @@ class AdaptivePolicy:
         tau = self.est.tau_opt()
         if not math.isinf(tau):
             # the controller's rule: never act before a fault is measured
-            if self.act.set_cadence(tau, "bench sim") is not None:
+            if self.act.set_cadence(tau, "policy sim") is not None:
                 self.retunes += 1
         applied = self.act.current_cadence_s()
         return applied if applied else self.default_interval_s
@@ -243,7 +245,7 @@ def run(seed: int, trials: int = 3) -> dict:
     results = [run_trial(seed + 101 * i) for i in range(max(1, trials))]
     mean_gain = sum(r["gain"] for r in results) / len(results)
     return {
-        "metric": "bench_policy",
+        "metric": "sim_policy",
         "seed": seed,
         "trials": len(results),
         "policy_adaptive_goodput": round(

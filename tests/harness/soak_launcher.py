@@ -83,8 +83,8 @@ Every process appends profiling events to one JSONL
 for both rings from those events and ASSERTS bounds, so a regression in
 any layer fails the gate rather than hiding in an average.
 
-Gate (documented in README):    python benchmarks/soak_launcher.py --gate
-Quick smoke (CI):               python benchmarks/soak_launcher.py --seconds 45
+Gate (documented in README):    python tests/harness/soak_launcher.py --gate
+Quick smoke (CI):               python tests/harness/soak_launcher.py --seconds 45
 """
 
 from __future__ import annotations
@@ -101,7 +101,8 @@ import tempfile
 import threading
 import time
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
 from tpu_resiliency.utils.env import force_cpu_env  # noqa: E402
@@ -754,9 +755,10 @@ def _gen_fault_schedule(seed, nproc, horizon, probs, shift_at=None,
 def _run_fault_shift_ab(args) -> None:
     """Adaptive-vs-fixed goodput A/B: both arms replay ONE seeded fault
     schedule (same injection timeline) for the same wall time; goodput is
-    durably-saved progress.  Reports ``policy_goodput_gain`` =
-    adaptive / fixed, gated at 1.1x (waived on 1-core hosts, where two
-    gangs + monitors thrash a single CPU)."""
+    durably-saved progress.  ``ok`` says that both arms ran to their end
+    ``ok``; ``policy_goodput_gain`` = adaptive / fixed is reported as a
+    reading of a CPU host's clock, not gated on: which arm wins is held by
+    the simulated clock of ``sim_policy.py``."""
     workdir = tempfile.mkdtemp(prefix="tpurx-soak-ab-")
     sched_path = args.fault_schedule
     if sched_path is None:
@@ -800,9 +802,7 @@ def _run_fault_shift_ab(args) -> None:
     fixed_g = max(1, int(arms["fixed"].get("final_progress") or 0))
     adaptive_g = int(arms["adaptive"].get("final_progress") or 0)
     gain = adaptive_g / fixed_g
-    waived = (os.cpu_count() or 1) <= 1
     arms_ok = bool(arms["fixed"].get("ok") and arms["adaptive"].get("ok"))
-    ok = arms_ok and (waived or gain >= 1.1)
     print(json.dumps({
         "metric": "soak_fault_shift",
         "seconds_per_arm": args.seconds,
@@ -810,11 +810,10 @@ def _run_fault_shift_ab(args) -> None:
         "adaptive_progress": adaptive_g,
         "fixed_progress": fixed_g,
         "policy_goodput_gain": round(gain, 3),
-        "policy_gate_waived": waived,
         "arms_ok": arms_ok,
-        "ok": ok,
+        "ok": arms_ok,
     }))
-    sys.exit(0 if ok else 1)
+    sys.exit(0 if arms_ok else 1)
 
 
 def _free_port() -> int:

@@ -195,35 +195,61 @@ def test_tripwire_stop_wakes_parked_waiter_fast(beater):
 def test_quorum_monitor_futex_lane_end_to_end():
     """QuorumMonitor(native_beat, futex_tripwire): a stamp freeze fires
     on_stale through the local tripwire lane without waiting for a
-    collective round."""
+    collective round.
+
+    Held as an ordering, not against the host's clock: the collective lane
+    dispatches once at start and then not again for ``interval`` = 60 s, so
+    a trip that lands while the monitor's count of evaluated rounds still
+    reads what it read at the freeze cannot have come from a round that saw
+    the frozen stamp; and the tripwire counts its own trip before it calls
+    on_stale, so the first hit says which lane raised it."""
     _require_native()
     import jax
     from tpu_resiliency.parallel.mesh import make_mesh
+    from tpu_resiliency.telemetry import get_registry
+
+    def stale_waits():
+        return get_registry().value_of(
+            "tpurx_quorum_futex_waits_total", {"outcome": "stale"})
 
     mesh = make_mesh(("all",), (len(jax.devices()),))
     hits = []
     mon = QuorumMonitor(
-        mesh, budget_ms=1e9, interval=0.01,
-        on_stale=lambda age: hits.append((age, time.monotonic())),
+        mesh, budget_ms=1e9, interval=60.0,
+        on_stale=lambda age: hits.append(
+            (age, mon._tripwire.trip_count, mon._last_seq)),
         use_pallas=False, auto_beat_interval=0.0005, fetch_workers=2,
         native_beat=True, futex_tripwire=True,
     )
     try:
         mon.calibrate(n_ticks=5, min_budget_ms=0.5, margin_ms=0.3)
-        mon.budget_ms = min(mon.budget_ms, 5.0)
+        # the lane's wiring is what is held here, not how tight a budget an
+        # idle interpreter calibrates: a few ms false-trips on a host whose
+        # cores six test workers share
+        mon.budget_ms = max(mon.budget_ms, 50.0)
         mon.start()
         if mon._native_beater is None or not mon._native_beater.alive:
             pytest.skip("native beater unavailable")
+        assert mon._tripwire.beater is mon._native_beater  # futex mode
+        deadline = time.monotonic() + 30.0
+        while mon._last_seq < 1 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        rounds_at_freeze = mon._last_seq
+        assert rounds_at_freeze >= 1, "the first collective round never ended"
         time.sleep(0.15)
         assert not hits, f"false trip: {hits}"
-        t_hang = time.monotonic()
+        stale_before = stale_waits()
         mon.stop_auto_beat()
-        deadline = time.monotonic() + 3.0
+        deadline = time.monotonic() + 30.0
         while not hits and time.monotonic() < deadline:
             time.sleep(0.0005)
         assert hits, "futex lane never fired"
-        # local wake-path detection: far under the collective cadence
-        assert (hits[0][1] - t_hang) * 1e3 < 500
+        age_ms, trips, rounds = hits[0]
+        assert trips == 1, f"the first trip came from another lane: {hits}"
+        assert rounds == rounds_at_freeze, (
+            f"a collective round ran between the freeze and the trip: {hits}")
+        assert age_ms > mon.budget_ms
+        assert stale_waits() - stale_before >= 1
     finally:
         mon.stop()
 

@@ -30,7 +30,7 @@ from tpurx_lint import run_lint
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 PKG = os.path.join(REPO, "tpu_resiliency")
 
-LINT_PATHS = ["tpu_resiliency", "tests", "benchmarks", "tpurx_lint"]
+LINT_PATHS = ["tpu_resiliency", "tests", "tpurx_lint"]
 
 
 def _tracked_files():
@@ -78,6 +78,44 @@ def test_native_build_outputs_are_gitignored():
             ["git", "check-ignore", "-q", artifact], cwd=REPO, timeout=30,
         ).returncode
         assert rc == 0, f"{artifact} is not gitignored"
+
+
+def test_retired_cpu_benchmark_stays_gone():
+    """The pre-chip benchmark (one script at the root, one directory of
+    CPU lanes) was deleted in PR 31: ``chipbench/`` is the benchmark and
+    ``PERF.md`` the one place a speed is written.  Neither path exists, and
+    no tracked file names either outside the records that tell the history
+    (and ``BASELINE.md``, which speaks of the reference's own directory)."""
+    script = "bench" + ".py"
+    directory = "bench" + "marks"
+    assert not os.path.exists(os.path.join(REPO, script))
+    assert not os.path.exists(os.path.join(REPO, directory))
+    records = {
+        "CHANGES.md", "PERF.md", "ROADMAP.md", "SURVEY.md", "BASELINE.md",
+        "PERF_LEDGER.jsonl", "ISSUE.md",
+    }
+    # not part of a longer name: chipbench/ and BENCHMARK.json pass
+    retired = re.compile(
+        r"(?:^|[^A-Za-z_])(?:" + re.escape(script) + "|" + directory + "/)",
+        re.MULTILINE,
+    )
+    offenders = []
+    for rel in _tracked_files():
+        if rel in records:
+            continue
+        try:
+            with open(os.path.join(REPO, rel), errors="replace") as f:
+                text = f.read()
+        except OSError:
+            continue
+        offenders += [
+            f"{rel}:{text.count(chr(10), 0, m.start()) + 1}"
+            for m in retired.finditer(text)
+        ]
+    assert not offenders, (
+        f"the retired CPU benchmark is named again (point at chipbench/ or "
+        f"PERF.md instead): {offenders}"
+    )
 
 
 # -- framework-backed shims (rule IDs TPURX001-004, see docs/lint.md) --------

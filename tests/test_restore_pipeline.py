@@ -4,7 +4,7 @@ The restore engine (``async_ckpt/writer._RestoreEngine``) mirrors the write
 engine: a plan from metadata.json, size-bucketed chunked reads on a thread
 pool, crc verified in-flight, per-leaf device_put overlap.  Everything here
 runs tier-1-sized (small states, ``threads=2``) so the pipeline is
-exercised on every CI pass without the slow 1 GiB bench lane.
+exercised on every CI pass.
 """
 
 import os
@@ -36,6 +36,8 @@ from tpu_resiliency.checkpointing.coverage import (
 from tpu_resiliency.checkpointing.integrity import FOOTER_BYTES
 from tpu_resiliency.telemetry import get_registry
 from tpu_resiliency.utils.dtypes import coerce_dtype
+
+from harness.serial_restore import serial_restore
 
 
 def _counter_sum(name):
@@ -107,7 +109,7 @@ def test_parallel_matches_serial(tmp_path):
     finally:
         ckpt.close()
     par = load_checkpoint(d, tree, threads=3)
-    ser = load_checkpoint(d, tree, serial=True)
+    ser = serial_restore(d, tree)
     assert_trees_equal(par, ser)
     assert_trees_equal(par, tree)
 

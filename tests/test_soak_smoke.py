@@ -1,39 +1,46 @@
-"""CI smoke of the chaos-soak regression gate (benchmarks/soak_launcher.py).
+"""CI smoke of the chaos-soak regression gate (tests/harness/soak_launcher.py).
 
 A compressed run of the full-stack gate: launcher + external journaled
 control plane (randomly killed mid-run) + in-process ring + quorum
 tripwire, randomized fault injection, detect->recover latencies derived
 from the shared profiling JSONL with bounds asserted.  The 15-minute gate
-is ``python benchmarks/soak_launcher.py --gate``; this smoke keeps the
+is ``python tests/harness/soak_launcher.py --gate``; this smoke keeps the
 same machinery honest on every suite run.
 """
 
 import json
+import math
 import os
 import subprocess
 import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
+SOAK = REPO / "tests" / "harness" / "soak_launcher.py"
 
 
-def test_soak_smoke_chaos_store_and_quorum():
+def _soak(*flags):
+    """Run one campaign of the soak launcher; its report (the last JSON
+    line it prints)."""
     proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "50", "--chaos-store", "--quorum",
-            "--store-kill-every", "18", "28",
-            "--exc-p", "0.02", "--qstall-p", "0.012", "--cwedge-p", "0.008",
-            # generous bounds: this is a loaded 1-core CI host; the gate run
-            # uses the defaults
-            "--inner-bound-ms", "15000", "--outer-bound-ms", "60000",
-        ],
+        [sys.executable, str(SOAK), *flags],
         cwd=str(REPO), capture_output=True, text=True, timeout=240,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
     assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
+    return json.loads(last[-1])
+
+
+def test_soak_smoke_chaos_store_and_quorum():
+    report = _soak(
+        "--seconds", "50", "--chaos-store", "--quorum",
+        "--store-kill-every", "18", "28",
+        "--exc-p", "0.02", "--qstall-p", "0.012", "--cwedge-p", "0.008",
+        # generous bounds: this is a loaded 1-core CI host; the gate run
+        # uses the defaults
+        "--inner-bound-ms", "15000", "--outer-bound-ms", "60000",
+    )
     assert report["ok"], report
     assert report["store_kills"] >= 1, report
     assert report["monotone_progress"], report
@@ -57,17 +64,9 @@ def test_soak_smoke_corrupt_blob_fallback_restore():
     checkpoint is bit-flipped mid-run and the gang hard-restarts; the
     restarted ranks must detect + quarantine the corruption and
     fallback-restore the next-oldest valid iteration on all ranks."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "45", "--corrupt-blob", "bitflip",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "45", "--corrupt-blob", "bitflip",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["ckpt_ok"], report
     assert report["corrupted_iter"] is not None, report
@@ -86,17 +85,9 @@ def test_soak_smoke_peer_mem_kill_falls_to_disk():
     rank drops every peer-memory chunk request, so each other rank —
     resident copy shed — must time the rung out and restore from its OWN
     disk blob at fallback depth 0 (colder source, same iteration)."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "35", "--peer-mem-kill",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "35", "--peer-mem-kill",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["peer_ok"], report
     drills = report["peer_drills"]
@@ -112,17 +103,9 @@ def test_soak_smoke_link_degrade_no_restart():
     must absorb the bad link IN PROCESS (deadline trip -> retry ->
     re-layout), every rank must finish, and the launcher ring must record
     ZERO restart cycles."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "110", "--link-degrade",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "110", "--link-degrade",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["coll_ok"], report
     # zero pod-wide restarts: the whole point of the degrade ladder
@@ -139,22 +122,14 @@ def test_soak_smoke_store_outage_mid_save():
     """The store-outage-mid-save fault class: targeted store kills inside
     rank 0's store-backed save windows; the unified retry policy must ride
     the save through the outage (saves_done tracks saves_started)."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "55", "--store-kill-mid-save",
-            "--save-every", "30", "--store-down", "2.0",
-            # isolate the fault class: no random worker faults
-            "--exc-p", "0", "--crash-p", "0", "--hang-p", "0",
-            "--qstall-p", "0", "--cwedge-p", "0",
-            "--inner-bound-ms", "15000", "--outer-bound-ms", "60000",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "55", "--store-kill-mid-save",
+        "--save-every", "30", "--store-down", "2.0",
+        # isolate the fault class: no random worker faults
+        "--exc-p", "0", "--crash-p", "0", "--hang-p", "0",
+        "--qstall-p", "0", "--cwedge-p", "0",
+        "--inner-bound-ms", "15000", "--outer-bound-ms", "60000",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["saves_started"] >= 1, report
     assert report["saves_ok"], report
@@ -169,17 +144,9 @@ def test_soak_smoke_ramp_degrade_evacuates_before_hard_fault():
     never evacuate the healthy rank, and the evacuated slot must
     warm-join from peer memory with zero disk bytes — no global
     restore."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "120", "--ramp-degrade",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "120", "--ramp-degrade",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["evac_ok"], report
     assert report["hard_faults"] == 0, report
@@ -197,21 +164,13 @@ def test_soak_smoke_store_longpoll_abort_lands():
     propagation budget + 2x poll quantum (the historical flake parked the
     raise behind one ~30s uninterruptible recv) and no rank may ever exit
     ret=None."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--seconds", "12", "--store-longpoll-abort",
-            # loaded 1-core CI host: abort propagation (not the store
-            # slicing) eats scheduler latency; the quantum contract itself
-            # is asserted tightly by tests/test_store_interrupt.py
-            "--longpoll-bound-s", "10.0",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    report = _soak(
+        "--seconds", "12", "--store-longpoll-abort",
+        # loaded 1-core CI host: abort propagation (not the store
+        # slicing) eats scheduler latency; the quantum contract itself
+        # is asserted tightly by tests/test_store_interrupt.py
+        "--longpoll-bound-s", "10.0",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["lp_ok"], report
     assert report["lp_episodes_injected"] >= 1, report
@@ -228,7 +187,7 @@ def test_fault_schedule_generation_is_deterministic():
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
-        "soak_launcher", str(REPO / "benchmarks" / "soak_launcher.py"))
+        "soak_launcher", str(SOAK))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     kw = dict(shift_at=1000, shift_mult=6.0)
@@ -246,22 +205,16 @@ def test_fault_schedule_generation_is_deterministic():
 def test_soak_smoke_fault_shift_goodput_ab():
     """The adaptive-vs-fixed goodput A/B: both arms replay ONE seeded
     fault schedule; the adaptive arm closes the loop (estimator -> Young/
-    Daly cadence -> SaveScheduler) on real telemetry.  The 1.1x gain gate
-    is waived on 1-core hosts; the mechanics must still hold: both arms
-    finish ok and a finite gain is measured."""
-    proc = subprocess.run(
-        [
-            sys.executable, str(REPO / "benchmarks" / "soak_launcher.py"),
-            "--fault-shift", "--seconds", "20", "--fault-seed", "11",
-        ],
-        cwd=str(REPO), capture_output=True, text=True, timeout=240,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    Daly cadence -> SaveScheduler) on real telemetry.  The mechanics are
+    what is held here: both arms finish ok, both make durable progress and
+    a finite gain is reported.  Which arm wins is not read off 20 s of a
+    shared CPU host; ``test_policy.py`` holds that ordering on the
+    simulated clock of ``harness/sim_policy.py``."""
+    report = _soak(
+        "--fault-shift", "--seconds", "20", "--fault-seed", "11",
     )
-    last = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert last, proc.stdout[-2000:] + proc.stderr[-2000:]
-    report = json.loads(last[-1])
     assert report["ok"], report
     assert report["arms_ok"], report
-    assert report["policy_goodput_gain"] > 0, report
+    assert math.isfinite(report["policy_goodput_gain"]), report
     assert report["fixed_progress"] > 0, report
     assert report["adaptive_progress"] > 0, report

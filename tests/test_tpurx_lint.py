@@ -67,7 +67,7 @@ class TestBarePrint:
                                 "import logging\nlogging.info('hi')\n",
                                 rule="TPURX001")
         # scripts outside the library may print
-        assert not lint_snippet(tmp_path, "benchmarks/x.py", "print('hi')\n",
+        assert not lint_snippet(tmp_path, "examples/x.py", "print('hi')\n",
                                 rule="TPURX001")
 
 
@@ -648,7 +648,7 @@ class TestRawCollective:
                 return lax.cumsum(x, axis=0)
         """, rule="TPURX014")
         # scripts outside the library may call raw collectives
-        assert not lint_snippet(tmp_path, "benchmarks/x.py", """
+        assert not lint_snippet(tmp_path, "examples/x.py", """
             from jax.experimental import multihost_utils
 
             def f(x):
@@ -765,7 +765,7 @@ class TestWallClockDuration:
             tmp_path, "tpu_resiliency/attribution/trace_analyzer.py",
             snippet, rule="TPURX016")
         assert not lint_snippet(
-            tmp_path, "benchmarks/x.py", snippet, rule="TPURX016")
+            tmp_path, "examples/x.py", snippet, rule="TPURX016")
 
 
 # ---------------------------------------------------------------------------

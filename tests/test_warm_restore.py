@@ -37,6 +37,8 @@ from tpu_resiliency.checkpointing.local.replication import (
 from tpu_resiliency.store import StoreClient
 from tpu_resiliency.telemetry import get_registry
 
+from harness.serial_restore import serial_restore
+
 
 def _source_bytes(source):
     return get_registry().value_of(
@@ -120,8 +122,11 @@ class TestResidentRestore:
         finally:
             cp.close()
         assert resident_mod.lookup(d) is not None
-        restored = load_checkpoint(d, tree, serial=True)
-        assert_trees_equal(tree, restored)
+        stats = {}
+        warm = load_checkpoint(d, tree, stats=stats)
+        assert stats["bytes_shm"] == stats["bytes_read"]
+        assert_trees_equal(warm, serial_restore(d, tree))
+        assert_trees_equal(tree, warm)
 
     def test_sharded_leaves_warm_and_cold(self, tmp_path):
         """Row sharding exercises the direct-into-leaf-buffer path, column
@@ -391,7 +396,7 @@ class TestDeltaSaves:
         # cold restores must resolve provenance across generation dirs
         resident_mod.invalidate()
         assert_trees_equal(t2, load_checkpoint(d2, t2, threads=2))
-        assert_trees_equal(t2, load_checkpoint(d2, t2, serial=True))
+        assert_trees_equal(t2, serial_restore(d2, t2))
 
     def test_delta_then_layout_change_invalidates(self, tmp_path):
         """Delta chain then a layout change: the resident generation of the

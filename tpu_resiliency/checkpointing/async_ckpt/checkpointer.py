@@ -69,7 +69,6 @@ from .staging import (
 from .writer import (
     _RestoreEngine,
     is_committed,
-    read_leaf,
     read_metadata,
     resolve_restore_threads,
     resolve_write_threads,
@@ -970,7 +969,6 @@ def load_checkpoint(
     template: Any,
     reader: Optional[CachedMetadataReader] = None,
     threads: Optional[int] = None,
-    serial: bool = False,
     stats: Optional[Dict[str, Any]] = None,
     resident: Optional[bool] = None,
     peers: Optional[Any] = None,
@@ -992,11 +990,9 @@ def load_checkpoint(
     remaining leaves are still reading, so read, verify, and H2D transfer
     pipeline instead of serializing.
 
-    ``serial=True`` keeps the one-leaf-at-a-time reference path (the
-    restore bench's A/B baseline).  ``stats``, if given, is filled with the
-    engine's accounting (``bytes_read`` / ``bytes_shm`` /
-    ``bytes_in_place`` / ``chunks`` / ``shards`` / ``leaves`` /
-    ``verify_ns`` / ``restore_ns`` / ``threads``).
+    ``stats``, if given, is filled with the engine's accounting
+    (``bytes_read`` / ``bytes_shm`` / ``bytes_in_place`` / ``chunks`` /
+    ``shards`` / ``leaves`` / ``verify_ns`` / ``restore_ns`` / ``threads``).
 
     **Warm restore**: when the committed generation for ``ckpt_dir`` is
     still shm-resident (published at finalize, see ``resident.py``) and
@@ -1005,7 +1001,6 @@ def load_checkpoint(
     generation no checkpoint file is opened at all, metadata included.
     Every chunk is still verified against the committed index crcs;
     ``stats["bytes_shm"]`` reports how much of the restore came warm.
-    ``serial=True`` always reads from disk (it is the A/B baseline).
 
     A resident shard that is the whole of its leaf is not copied on the
     host at all: its spans are verified **where they lie** and, once all of
@@ -1034,7 +1029,7 @@ def load_checkpoint(
     with flight.span(IV_LOAD, load_id):
         with flight.span(IV_LOAD_PLAN, load_id, IV_LOAD):
             use_res = env.CKPT_RESIDENT.get() if resident is None else resident
-            rc = resident_mod.lookup(ckpt_dir) if (use_res and not serial) else None
+            rc = resident_mod.lookup(ckpt_dir) if use_res else None
             res_bufs: Optional[Dict[Tuple[int, int, int], memoryview]] = None
             if rc is not None:
                 res_bufs = {
@@ -1050,7 +1045,7 @@ def load_checkpoint(
                     )
                 meta = (reader or _default_reader).read(ckpt_dir)
 
-            if peers is not None and not serial:
+            if peers is not None:
                 # peer-memory rung: pull shards whose local bytes are missing
                 # from other ranks' resident generations, then hand them to the
                 # engine as additional in-memory sources (chunk crcs re-verified
@@ -1068,7 +1063,6 @@ def load_checkpoint(
                     f"template has {len(leaves)} leaves, checkpoint has "
                     f"{len(meta['leaf_paths'])}"
                 )
-        t0 = time.monotonic_ns()
         out_leaves: List[Any] = [None] * len(leaves)
         # placed arrays whose transfer may still be reading a resident view
         in_flight: List[Any] = []
@@ -1088,14 +1082,6 @@ def load_checkpoint(
                 if borrowed:
                     in_flight.append(out_leaves[idx])
 
-        if serial:
-            for i in range(len(leaves)):
-                place(i, read_leaf(ckpt_dir, meta, i))
-            if stats is not None:
-                stats.update(
-                    {"threads": 1, "restore_ns": time.monotonic_ns() - t0}
-                )
-            return jtu.tree_unflatten(treedef, out_leaves)
         with flight.span(IV_LOAD_START, load_id, IV_LOAD):
             engine = _RestoreEngine(
                 ckpt_dir, meta, num_threads=resolve_restore_threads(threads),

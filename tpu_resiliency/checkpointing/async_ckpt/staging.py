@@ -138,7 +138,7 @@ class StagedTree:
     plan_sig: str = ""
     bytes_allocated: int = 0              # shm bytes newly created this staging
     bytes_reused: int = 0                 # shm bytes reused from a pooled tree
-    # pipelining telemetry for the last staging pass (bench: stage_overlap_pct)
+    # pipelining telemetry for the last staging pass (last_stage_stats)
     stage_wait_s: float = 0.0             # summed per-shard D2H completion waits
     stage_copy_s: float = 0.0             # summed memcpy-into-shm time
     stage_overlap_pct: float = 0.0        # % of memcpy overlapped with live D2H

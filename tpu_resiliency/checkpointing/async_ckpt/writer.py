@@ -873,8 +873,8 @@ def read_metadata(ckpt_dir: str) -> Dict[str, Any]:
 def read_leaf(ckpt_dir: str, meta: Dict[str, Any], leaf_idx: int) -> np.ndarray:
     """Assemble a full global array for one leaf from its shards — the
     SERIAL reference path (one shard at a time, whole-buffer reads).  The
-    parallel pipeline is :class:`_RestoreEngine`; this stays as the restore
-    bench's A/B baseline and the one-leaf escape hatch.  Every shard file
+    parallel pipeline is :class:`_RestoreEngine`; this stays as the reader the
+    tests hold the engine against and the one-leaf escape hatch.  Every file
     is digest-verified against the index-recorded chunk crcs before any
     element is placed — a torn or bit-flipped shard raises
     :class:`..integrity.CheckpointCorruptError` instead of restoring

@@ -31,12 +31,12 @@ Built-in rungs:
 - :class:`AbortPeerExchange` — close local-ckpt replication sockets.
 - :class:`AbortQuorumMonitor` — stop the device-quorum tick thread (it would
   otherwise keep dispatching collectives into a broken mesh).
-- :class:`ShrinkMeshStage` — **opt-in, measured**: tear down the
-  ``jax.distributed`` client in-process so the next iteration can re-init
-  over the surviving hosts (see ``benchmarks/mesh_shrink_experiment.py``
-  and the per-JAX-version result matrix in ``docs/inprocess.md``).  A
-  wedged runtime can block the shutdown past any Python control — hence
-  the hard per-stage deadline with automatic fallback to the backstop.
+- :class:`ShrinkMeshStage` — **opt-in, not measured on the chip**: tear
+  down the ``jax.distributed`` client, caches and backends in-process so
+  the next iteration can re-init over the surviving hosts
+  (``docs/inprocess.md``, "Mesh shrink").  A wedged runtime can block the
+  shutdown past any Python control — hence the hard per-stage deadline
+  with automatic fallback to the backstop.
 - :class:`ClearJaxCaches` — drop compiled-executable caches so the next
   iteration re-traces against the new topology when world size changed.
 """
@@ -350,14 +350,14 @@ class AbortQuorumMonitor(AbortStage):
 
 
 class ShrinkMeshStage(AbortStage):
-    """Opt-in, measured in-process mesh-shrink (SURVEY §7(a)).
+    """Opt-in in-process mesh-shrink (SURVEY §7(a)).
 
-    Tears down the ``jax.distributed`` client and compiled caches *inside
-    the process* so the next restart iteration can re-init at the surviving
-    world size without a respawn.  Whether the re-init half actually works
-    is a per-JAX-version property — measured by
-    ``benchmarks/mesh_shrink_experiment.py`` and recorded in
-    ``docs/inprocess.md`` — so this rung is gated:
+    Tears down the ``jax.distributed`` client, the compiled caches and the
+    backends *inside the process* so the next restart iteration can re-init
+    at the surviving world size without a respawn.  Whether the re-init
+    half works is a property of the JAX version and of how the peer left
+    (``docs/inprocess.md``, "Mesh shrink"), and the chip has not measured
+    it (no cell sets ``TPURX_SHRINK_MESH``) — so this rung is gated:
 
     - opt-in via constructor or ``TPURX_SHRINK_MESH=1``;
     - a hard ``timeout`` (a wedged runtime can block ``shutdown()`` in C++
@@ -393,9 +393,9 @@ class ShrinkMeshStage(AbortStage):
         else:
             detail.append("no distributed client")
         jax.clear_caches()
-        # the full reset (measured by benchmarks/mesh_shrink_experiment.py):
-        # clearing compiled caches is NOT enough — jax.distributed refuses
-        # re-init while backends are live, so the backends must go too
+        # the full reset: clearing compiled caches is NOT enough —
+        # jax.distributed refuses re-init while backends are live, so the
+        # backends must go too
         jeb.clear_backends()
         detail.append("caches+backends cleared")
         # reset the bootstrap helper so the next iteration's initialize

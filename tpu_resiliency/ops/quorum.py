@@ -436,8 +436,8 @@ class NativeBeater:
 
     def freeze(self) -> None:
         """Stop stamping WITHOUT joining the C thread: the stamp freezes
-        within one beat interval, exactly as on a real wedge — benchmarks
-        use this so freeze->detect latency excludes the caller's join time.
+        within one beat interval, exactly as on a real wedge — tests and
+        ``chip_smoke.py`` use this so freeze->detect excludes a join.
         :meth:`stop` must still follow to join and free."""
         if self._handle is not None:
             self._lib.tpurx_beat_freeze(self._handle)
@@ -1085,7 +1085,7 @@ class QuorumMonitor:
         self._tripwire.start()
 
     def stop_auto_beat(self) -> None:
-        """Stop the liveness beater (tests/benchmarks simulate a wedged
+        """Stop the liveness beater (tests simulate a wedged
         process this way — stamps freeze while the tick loop, playing the
         healthy peers' role, keeps reducing)."""
         self._beater_stop.set()

@@ -77,11 +77,11 @@ class DegradePolicy:
 
 
 def default_relayout() -> str:
-    """The in-process half of the measured re-init recipe
-    (``benchmarks/mesh_shrink_experiment.py``): drop compiled executables so
-    the re-run re-traces against the current (possibly changed) topology.
+    """The in-process half of the re-init recipe: drop compiled executables
+    so the re-run re-traces against the current (possibly changed) topology.
     The full teardown — distributed client + backends — is the *shrink*
-    rung's job via the abort ladder."""
+    rung's job via the abort ladder (``ShrinkMeshStage``; not measured on
+    the chip)."""
     import jax
 
     jax.clear_caches()

@@ -30,8 +30,8 @@ independent :class:`~tpu_resiliency.store.server.StoreServer` shards:
 
 Server side, :class:`ShardServerGroup` hosts K asyncio shards in one
 process (tests, single-host jobs) and :func:`spawn_shard_subprocess` spawns
-one shard as a separate kill-able process (bench fan-in lanes, soak fault
-injection, production one-process-per-core layouts).
+one shard as a separate kill-able process (soak fault injection,
+production one-process-per-core layouts).
 """
 
 from __future__ import annotations
@@ -1052,8 +1052,8 @@ def spawn_shard_subprocess(
     connect_timeout: float = 20.0,
 ) -> subprocess.Popen:
     """One shard as a separate OS process (SIGKILL-able fault-injection
-    target; real multi-core parallelism for the bench fan-in lanes).  Blocks
-    until the shard accepts connections."""
+    target; a core of its own in production layouts).  Blocks until the
+    shard accepts connections."""
     cmd = [
         sys.executable, "-m", "tpu_resiliency.store.server",
         "--host", host, "--port", str(port),

@@ -11,8 +11,8 @@ turns per-process dumps into one causal story.
 
 Phase accounting is transition-based: :meth:`Episode.phase` ends the
 current phase and starts the named one, so the decomposed phases sum to
-the episode's wall time by construction (the bench lane's
-``episode_phase_coverage_pct`` gate proves no uninstrumented gap).  At
+the episode's wall time by construction (:meth:`Episode.coverage_pct`
+reads any uninstrumented gap).  At
 :meth:`Episode.close` each phase lands in
 ``tpurx_episode_phase_ns{phase,fault_class}`` and the per-rank summary is
 published to the store under ``episode/<id>/rank/<r>`` for ``smonsvc``'s
@@ -59,7 +59,7 @@ CURRENT_KEY = "episode/current"
 
 _lock = threading.Lock()
 _current: Optional["Episode"] = None
-_recent: List["Episode"] = []   # closed episodes, in-process (bench lane)
+_recent: List["Episode"] = []   # closed episodes, in-process (recent())
 _RECENT_KEEP = 64
 _local_seq = itertools.count(1)
 

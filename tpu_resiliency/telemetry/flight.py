@@ -11,7 +11,7 @@ Design:
 - **Preallocated ring, lock-free append.**  One slot store per event —
   ``ring[next(counter) & mask] = (mono_ns, name, episode, args)`` — no
   allocation beyond the slot tuple, no lock (the itertools counter is
-  GIL-atomic), sub-µs per append (bench lane ``tm_flight_append_ns``).
+  GIL-atomic).
 - **``TPURX_FLIGHT=0`` no-op** — the module-level :func:`record` becomes
   a shared no-op, same discipline as the registry's ``TPURX_TELEMETRY=0``.
   Call sites must use attribute access (``flight.record(...)``), never

@@ -420,7 +420,7 @@ class Registry:
             return self._metrics.get(name)
 
     def value_of(self, name: str, labels: Optional[Dict[str, str]] = None) -> float:
-        """Convenience for tests/bench: current value of a counter/gauge
+        """Convenience for tests: current value of a counter/gauge
         sample (0.0 when absent/disabled)."""
         metric = self.get(name)
         if metric is None:

@@ -199,7 +199,7 @@ CYCLE = Knob(
     "namespaces store keys and checkpoint rounds.", group="identity")
 REPO = Knob(
     "TPURX_REPO", str, None,
-    "Absolute path to the repo checkout; set by bench/soak harnesses for "
+    "Absolute path to the repo checkout; set by test and example harnesses for "
     "their generated worker scripts.", group="identity")
 
 # -- control-plane store ----------------------------------------------------
@@ -593,11 +593,6 @@ LLM_TIMEOUT_S = Knob(
     "TPURX_LLM_TIMEOUT_S", float, 30.0,
     "Per-request timeout for the attribution LLM.", group="attribution")
 
-# -- bench / harness --------------------------------------------------------
-BENCH_DEADLINE_S = Knob(
-    "TPURX_BENCH_DEADLINE_S", int, 480,
-    "SIGALRM deadline for a full bench.py run.", group="bench")
-
 _GROUP_TITLES = {
     "identity": "Job identity",
     "store": "Control-plane store",
@@ -608,7 +603,6 @@ _GROUP_TITLES = {
     "collectives": "Collectives",
     "policy": "Adaptive policy",
     "attribution": "Attribution / LLM",
-    "bench": "Bench & harness",
     "general": "General",
 }
 

@@ -5,8 +5,8 @@ Capability parity with ``shared_utils/profiling.py:28-149``
 pipeline — FAILURE_DETECTED → RENDEZVOUS_* → WORKER_START_* — which is how
 hang-detection latency and restart latency are measured end to end.
 
-Events are JSON lines so external tooling (and our own bench) can consume
-them without importing the package.
+Events are JSON lines so external tooling (and the soak harness) can
+consume them without importing the package.
 
 Each record carries the live fault-episode id (``telemetry/episode.py``)
 and is mirrored into the flight-recorder ring, and each sink file opens

@@ -1,9 +1,8 @@
 """Unified retry/backoff policy — one audited degradation behavior.
 
-Before this module the repo had five divergent ad-hoc retry loops (store
-client connect, store client round-trip, local-ckpt replication sends,
-health-daemon probes, bench TPU acquisition), each with its own cadence,
-bound, and blind spot.  Chameleon's argument (PAPERS.md) applies to retries
+Before this module the repo had divergent ad-hoc retry loops (store client
+connect, store client round-trip, local-ckpt replication sends,
+health-daemon probes), each with its own cadence, bound, and blind spot.  Chameleon's argument (PAPERS.md) applies to retries
 as much as to recovery tiers: the *policy* should be a single declared
 object selected per call site, not re-derived inline — so outage behavior
 is auditable and telemetry-visible in one place.

@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to lint (default: tpu_resiliency tests "
-                         "benchmarks tpurx_lint)")
+                         "tpurx_lint)")
     ap.add_argument("--root", default=None,
                     help="repo root for relative paths (default: cwd)")
     ap.add_argument("--format", choices=("text", "json", "sarif"),

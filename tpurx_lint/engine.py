@@ -23,7 +23,7 @@ from .source import ParsedFile
 
 _SKIP_DIRS = {"__pycache__", ".git", ".claude", "node_modules", ".venv"}
 
-DEFAULT_PATHS = ["tpu_resiliency", "tests", "benchmarks", "tpurx_lint"]
+DEFAULT_PATHS = ["tpu_resiliency", "tests", "tpurx_lint"]
 
 
 @dataclass

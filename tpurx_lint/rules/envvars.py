@@ -160,7 +160,7 @@ class EnvRegistryRule(Rule):
         "tpu_resiliency/policy/ is banned except for launcher identity "
         "republication (WRITE_EXEMPT)."
     )
-    scope = ("tpu_resiliency/", "benchmarks/")
+    scope = ("tpu_resiliency/",)
     exclude = (ENV_MODULE,)
 
     def check_file(self, pf):
