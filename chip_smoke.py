@@ -31,6 +31,7 @@ import argparse
 import dataclasses
 import json
 import os
+import re
 import shutil
 import signal
 import socket
@@ -337,8 +338,12 @@ def judge(ph, args, shape, nproc, n_chips, built, beat, rc, holders, events,
         r_stall = by("restore", cycle=0, iteration=2)
         enter2 = by("enter", cycle=0, iteration=2)
         lanes = sorted((enter2[0].get("quorum_trips") or {})) if enter2 else []
-        age = enter2[0].get("last_age_ms") if enter2 else None
-        trips = launcher_log.count("quorum tripwire: heartbeat stale")
+        # the age that tripped, in the tripwire's own line: the monitor's
+        # last age at the re-entry is the stall's only if no tick fell
+        # between the wrapper's re-arm and the worker's report
+        stale = re.findall(r"quorum tripwire: heartbeat stale by ([0-9.]+)ms",
+                           launcher_log)
+        trips, age = len(stale), float(stale[0]) if stale else None
         summary["quorum"] = {
             "lane": budget.get("lane"), "pallas": budget.get("pallas"),
             "budget_ms": budget.get("budget_ms"),

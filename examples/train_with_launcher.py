@@ -379,6 +379,18 @@ def main(argv=None):
                     shadow = jax.block_until_ready(jax.jit(
                         lambda tree: jax.tree_util.tree_map(jnp.copy, tree)
                     )((params, opt)))
+                    # The tripwire recomputes its budget once, from its first
+                    # 256 ticks, and counts the ticks of protected phases
+                    # among them (the budget is infinite there, so every age
+                    # passes for healthy): ages of a ping-less compile put
+                    # that budget past the packed-age cap, a tripwire that
+                    # can never fire.  Inside a protected phase the exit puts
+                    # the budget back, outside it stays.  When the 256th tick
+                    # comes depends on the load, so it is made to come here.
+                    t0 = time.monotonic()
+                    while (not getattr(cw.quorum.monitor, "_recal_done", True)
+                           and time.monotonic() - t0 < 20.0):
+                        time.sleep(0.05)
 
                 def one_step():
                     nonlocal shadow

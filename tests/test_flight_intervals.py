@@ -178,9 +178,11 @@ def test_every_interval_is_a_span_pair_of_the_trace_cli():
     """``telemetry/trace.py`` renders an interval only if ``SPAN_PAIRS``
     names its pair; the span carries the interval's own name."""
     import tpu_resiliency.checkpointing.async_ckpt.checkpointer  # noqa: F401
+    import tpu_resiliency.inprocess.monitor_thread  # noqa: F401
 
     product = [iv for iv in flight.intervals() if not iv.name.startswith("test.")]
-    assert {iv.name for iv in product} >= set(SAVE_INTERVALS + LOAD_INTERVALS)
+    assert {iv.name for iv in product} >= set(
+        SAVE_INTERVALS + LOAD_INTERVALS + ("inproc.coalesce",))
     for iv in product:
         end, name, _cat = trace.SPAN_PAIRS[iv.begin_event]
         assert end == iv.end_event

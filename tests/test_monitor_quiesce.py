@@ -60,7 +60,8 @@ def test_no_reraise_escapes_after_quiesce(ops):
     timed drain the second scheduled raise escaped; the handshake makes the
     window zero."""
     mon = MonitorThread(
-        ops, 0, threading.get_ident(), last_call_wait=0.0, poll_interval=0.05
+        ops, 0, threading.get_ident(), [0], last_call_wait=0.0,
+        poll_interval=0.05,
     )
     mon.start()
     try:
@@ -92,7 +93,7 @@ def test_quiesce_cancels_undelivered_raise(ops):
     """Adversarial schedule: a raise lands in the async-exc slot from a
     helper thread; wherever the interpreter delivers it, after
     ``quiesce_raises`` returns the slot is empty and nothing fires."""
-    mon = MonitorThread(ops, 0, threading.get_ident())  # never started
+    mon = MonitorThread(ops, 0, threading.get_ident(), [0])  # never started
     main = threading.get_ident()
     t = threading.Thread(
         target=lambda: async_raise(main, RankShouldRestart), daemon=True
@@ -112,7 +113,7 @@ def test_quiesce_cancels_undelivered_raise(ops):
 
 
 def test_quiesce_requires_monitored_thread(ops):
-    mon = MonitorThread(ops, 0, threading.get_ident())
+    mon = MonitorThread(ops, 0, threading.get_ident(), [0])
     err = {}
 
     def other():

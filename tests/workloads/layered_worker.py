@@ -45,6 +45,7 @@ Scenario (env LAYERED_SCENARIO):
            kill) + ``inprocess/nested_restarter.py:36-107``.
 """
 
+import itertools
 import os
 import sys
 import time
@@ -59,13 +60,18 @@ from tpu_resiliency.inprocess.nested_restarter import NestedRestarterCallback
 RANK = int(os.environ["TPURX_RANK"])
 CYCLE = int(os.environ["TPURX_CYCLE"])
 SCENARIO = os.environ.get("LAYERED_SCENARIO", "inner")
-# inner/stall recover IN-PROCESS: the healthy rank must not be able to
-# complete the whole fn before the trip -> abort ladder -> restart raise
-# lands on a loaded host (completion would legitimately end the job at
-# iteration 0).  wedged/outer DEPEND on the short run: rank 0 finishing
-# cycle 0 quickly is part of those scenarios' choreography.
-STEPS = int(os.environ.get("LAYERED_STEPS")
-            or (120 if SCENARIO in ("inner", "stall") else 40))
+# wedged/outer DEPEND on the short run: rank 0 finishing cycle 0 quickly is
+# part of those scenarios' choreography.
+STEPS = int(os.environ.get("LAYERED_STEPS") or 40)
+# inner/stall recover IN-PROCESS: the healthy rank must not complete the
+# whole fn before the trip -> abort ladder -> restart raise lands
+# (completion would legitimately end the job at iteration 0, both ranks
+# short of "done@1").  No count of steps holds that order on a loaded host
+# — with TPURX_SHRINK_MESH=1 the ladder's first `import jax` and
+# `clear_backends` alone take 1.3-1.5 s on an idle one — so in the faulted
+# iteration the healthy rank steps until the raise lands; the launcher
+# call's timeout is the bound.
+UNTIL_RESTARTED = SCENARIO in ("inner", "stall") and CYCLE == 0 and RANK != 1
 
 quorum_kw = {}
 if SCENARIO == "stall":
@@ -112,7 +118,9 @@ def train(call_wrapper=None):
     state = call_wrapper.state
     print(f"train rank={state.active_rank} world={state.active_world_size} "
           f"iter={it} cycle={CYCLE}", flush=True)
-    for step in range(STEPS):
+    for step in (
+        itertools.count() if UNTIL_RESTARTED and it == 0 else range(STEPS)
+    ):
         call_wrapper.ping()
         client.send_heartbeat()
         # at-abort fingerprint feed: the step's collective, at dispatch

@@ -4,8 +4,11 @@ Capability parity with ``inprocess/monitor_thread.py:58-213``: a daemon
 thread per iteration that blocks on the iteration's interruption-log key; on
 a record appearing it
 
-1. waits ``last_call_wait`` so concurrent faults on other ranks coalesce into
-   one restart (reference ``wrap.py:162`` semantics),
+1. waits for other ranks' faults of the same iteration so that they coalesce
+   into one restart (reference ``wrap.py:162`` semantics): until every
+   surviving rank is named in the iteration's interruption log, and at most
+   ``last_call_wait`` (a world of one is named by the record that woke the
+   thread, so its window is one store read long),
 2. runs the Abort plugin (cancel aux engines — the JAX analog of NCCL abort),
 3. asynchronously raises :class:`RankShouldRestart` into the main thread via
    ``PyThreadState_SetAsyncExc``, repeatedly, until the wrapper catches it
@@ -18,16 +21,21 @@ from __future__ import annotations
 import ctypes
 import threading
 import time
-from typing import Callable, Optional
+from typing import Callable, Iterable, List, Optional
 
 from ..telemetry import counter, flight, histogram
 from ..utils.logging import get_logger
+from .attribution import InterruptionRecord
 from .exceptions import RankShouldRestart
 from .store_ops import InprocStore
 
 log = get_logger("monitor_thread")
 
 EV_TRIP = flight.declare_event("monitor.trip", "iteration", "interruptions")
+# the coalescing window; ident = the iteration
+IV_COALESCE = flight.declare_interval(
+    "inproc.coalesce_begin", "inproc.coalesce_end"
+)
 
 _TRIPS = counter(
     "tpurx_monitor_trips_total",
@@ -37,6 +45,19 @@ _TRIP_TO_CAUGHT_NS = histogram(
     "tpurx_monitor_trip_to_caught_ns",
     "Interruption observed to RankShouldRestart acknowledged by the wrapper",
 )
+_COALESCE_WAIT_NS = histogram(
+    "tpurx_monitor_coalesce_wait_ns",
+    "First interruption record seen to the coalescing window closed",
+)
+_COALESCE = counter(
+    "tpurx_monitor_coalesce_total",
+    "Coalescing windows, by what closed them (every surviving rank named in"
+    " the interruption log, or last_call_wait passed)",
+    labels=("closed_by",),
+)
+
+# the log is re-read this often while a surviving rank is still unnamed
+_COALESCE_POLL_S = 0.05
 
 
 def cancel_async_raise(tid: int) -> None:
@@ -74,6 +95,7 @@ class MonitorThread:
         ops: InprocStore,
         iteration: int,
         main_tid: int,
+        survivors: Iterable[int],
         abort_fn: Optional[Callable] = None,
         last_call_wait: float = 0.2,
         poll_interval: float = 1.0,
@@ -84,6 +106,8 @@ class MonitorThread:
         self.main_tid = main_tid
         self.abort_fn = abort_fn
         self.last_call_wait = last_call_wait
+        # the iteration's live ranks: the ones the window waits for
+        self.survivors = frozenset(survivors)
         self.poll_interval = poll_interval
         self.on_trip = on_trip
         self._stop = threading.Event()
@@ -113,9 +137,7 @@ class MonitorThread:
                 break
         if self._stop.is_set():
             return
-        # coalesce concurrent faults
-        time.sleep(self.last_call_wait)
-        records = self.ops.get_interruptions(self.iteration)
+        records = self._coalesce()
         log.warning(
             "iteration %s interrupted: %s",
             self.iteration,
@@ -152,6 +174,27 @@ class MonitorThread:
                 async_raise(self.main_tid, RankShouldRestart)
             if self._caught.wait(timeout=0.5):
                 return
+
+    def _coalesce(self) -> List[InterruptionRecord]:
+        """Coalesce concurrent faults: re-read the iteration's log until every
+        surviving rank is named in it — no live rank is left whose record
+        could still change the picture — or ``last_call_wait`` has passed
+        since the wake.  Returns the last read."""
+        t0 = time.monotonic_ns()
+        with flight.span(IV_COALESCE, self.iteration):
+            while True:
+                records = self.ops.get_interruptions(self.iteration)
+                # an origin_rank of -1 (the rank recorded itself) names nobody
+                named = {r.rank for r in records} | {r.origin_rank for r in records}
+                left = self.last_call_wait - (time.monotonic_ns() - t0) / 1e9
+                if self.survivors <= named or left <= 0:
+                    break
+                time.sleep(min(_COALESCE_POLL_S, left))
+        _COALESCE_WAIT_NS.observe(time.monotonic_ns() - t0)
+        _COALESCE.labels(
+            "all_named" if self.survivors <= named else "deadline"
+        ).inc()
+        return records
 
     def mark_caught(self) -> None:
         """Called by the wrapper once RankShouldRestart reached its handler.

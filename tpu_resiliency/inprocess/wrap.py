@@ -431,6 +431,7 @@ class CallWrapper:
                 self.ops,
                 iteration,
                 main_tid,
+                survivors,
                 abort_fn=self._abort_fn,
                 last_call_wait=w.last_call_wait,
                 poll_interval=w.monitor_thread_interval,

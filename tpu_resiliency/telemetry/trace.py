@@ -89,6 +89,10 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     "ckpt.load.release_begin": (
         "ckpt.load.release_end", "ckpt.load.release", "checkpointing",
     ),
+    # the monitor thread's coalescing window; ident = the wrapper iteration
+    "inproc.coalesce_begin": (
+        "inproc.coalesce_end", "inproc.coalesce", "inprocess",
+    ),
     # predict-and-evacuate: risk crossing → replacement's warm join is
     # the planned-handoff MTTR span (evac.ckpt_ahead / evac.promote
     # render as instants inside it)
