@@ -171,6 +171,55 @@ def test_quorum_tick_pipelined():
     assert hits
 
 
+@pytest.mark.parametrize("identify", [False, True])
+def test_quorum_calibration_follows_the_beat_period(identify):
+    """Manual beats: a calibration tick blocks, so the age it reads after the
+    load is dispatch time, while the running loop's ticks read up to a whole
+    beat period, and two when a loop one step ahead drains the device.  The
+    period is sampled before each load and the budget is two and a half of
+    their median where that passes the operator's floor (a 0.21 s step
+    beside a 250 ms floor false-tripped a healthy job on the chip); a floor
+    above that still binds, one late beat does not move it, and identify
+    mode keeps the budget under the packed-age cap, past which it could
+    never trip."""
+    from tpu_resiliency.ops.quorum import AGE_CAP_MS
+
+    mesh = make_mesh(("all",), (8,))
+    mon = QuorumMonitor(mesh, budget_ms=1e9, interval=0.01, use_pallas=False,
+                        identify=identify)
+    busy_s = [0.04]
+    reduce = mon._fn
+
+    def blocking_reduce(stamps):  # the device is busy with the step: the
+        out = reduce(stamps)      # age is taken at dispatch, the result
+        time.sleep(busy_s[0])     # comes when the step has drained
+        return out
+
+    mon._fn = blocking_reduce
+    try:
+        budget = mon.calibrate(n_ticks=5, min_budget_ms=20.0, load_fn=mon.beat)
+        period = mon.last_calibration_period_ms
+        assert period >= 40.0
+        assert mon.last_calibration_p99_ms < period  # dispatch time, not a step
+        assert budget >= 2.5 * period
+        # one late beat in five is no period: the median holds
+        late = iter([0.0, 0.3, 0.0, 0.0, 0.0])
+        mon.calibrate(n_ticks=5, min_budget_ms=20.0,
+                      load_fn=lambda: (time.sleep(next(late)), mon.beat()))
+        assert mon.last_calibration_period_ms < 150.0
+        # the floor binds when the periods' share stays under it
+        assert mon.calibrate(n_ticks=5, min_budget_ms=900.0,
+                             load_fn=mon.beat) == 900.0
+        # no load, no period: the old formula alone
+        mon.calibrate(n_ticks=3, min_budget_ms=1.0)
+        assert mon.last_calibration_period_ms is None
+        if identify:
+            busy_s[0] = 0.6
+            assert mon.calibrate(n_ticks=3, load_fn=mon.beat) < AGE_CAP_MS
+    finally:
+        mon.stop()
+
+
 def test_quorum_overlapped_loop_and_calibrate():
     """fetch_workers>0: dispatches overlap result readbacks; calibrated
     budget derives from observed healthy ages; auto-beat keeps the pod

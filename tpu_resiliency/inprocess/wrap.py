@@ -275,13 +275,15 @@ class CallWrapper:
 
     def calibrate_quorum(self, load_fn: Callable[[], Any],
                          n_ticks: int = 20) -> Optional[float]:
-        """Derive the quorum budget from healthy tick ages sampled while
-        ``load_fn`` runs — one real training step with its :meth:`ping`, so
-        the ages embed the step time and the host contention of THIS model
-        on THIS device.  The constructor budget is tuned for nothing: with
-        manual beats it must exceed the step time, which only the workload
-        knows.  Call once the step is compiled and warm.  Returns the new
-        budget (ms), or None without a quorum tripwire."""
+        """Derive the quorum budget from healthy tick ages and beat periods
+        sampled while ``load_fn`` runs — one real training step with its
+        :meth:`ping`, so they embed the host contention and the step time of
+        THIS model on THIS device (the tick after a step reads dispatch time;
+        the time from one ping to the next is the step, and the budget is at
+        least two of them).  The constructor budget is tuned for nothing:
+        with manual beats it must exceed the step time, which only the
+        workload knows.  Call once the step is compiled and warm.  Returns
+        the new budget (ms), or None without a quorum tripwire."""
         if not self.quorum:
             return None
         return self.quorum.monitor.calibrate(

@@ -43,7 +43,7 @@ import math
 from typing import Any, Dict, Tuple
 
 from ..telemetry import gauge
-from .adamw import adamw_leaf
+from .adamw import adamw_tree, init_adamw_state
 
 _EXPERT_LOAD_MAX = gauge(
     "tpurx_model_expert_load_max", "largest load of a held expert in the last step")
@@ -451,33 +451,36 @@ def forward(params: Dict, tokens, cfg: KimiLinearConfig, router_bias=None):
     return logits, jnp.stack(loads)
 
 
+def next_token_loss(logits, targets):
+    """Mean cross-entropy of ``logits`` [..., vocabulary rows] against
+    ``targets`` [...], in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+
 def loss_fn(params, batch, cfg: KimiLinearConfig, router_bias=None):
     """``(mean next-token cross-entropy over the held rows of the
     vocabulary, load)``."""
     import jax
-    import jax.numpy as jnp
 
     tokens, targets = batch
     logits, load = forward(params, tokens, cfg, router_bias)
     with jax.named_scope("head.loss"):
-        logits = logits.astype(jnp.float32)
-        picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(jax.nn.logsumexp(logits, axis=-1) - picked), load
+        return next_token_loss(logits, targets), load
 
 
 def init_opt_state(params, cfg: KimiLinearConfig):
     """Two float32 moments a leaf; a float32 master copy of every leaf that
     is not float32 itself (None where it is: an empty subtree); the step
     count; the router's bias and the last step's load."""
-    import jax
     import jax.numpy as jnp
 
-    zeros = lambda: jax.tree_util.tree_map(  # noqa: E731
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
     return {
-        "mu": zeros(), "nu": zeros(), "count": jnp.zeros((), jnp.int32),
-        "master": jax.tree_util.tree_map(
-            lambda p: None if p.dtype == jnp.float32 else p.astype(jnp.float32), params),
+        **init_adamw_state(params),
         "router_bias": jnp.zeros((cfg.n_expert_layers, cfg.num_experts), jnp.float32),
         "router_load": jnp.zeros((cfg.n_expert_layers, cfg.num_experts), jnp.int32),
     }
@@ -494,24 +497,12 @@ def make_train_step(cfg: KimiLinearConfig, lr: float = 1e-3):
     def step(params, opt, batch):
         (loss, load), grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch, cfg, opt["router_bias"]), has_aux=True)(params)
-        count = opt["count"] + 1
-        cf = count.astype(jnp.float32)
-        no_master = lambda x: x is None  # noqa: E731
-        out = jax.tree_util.tree_map(
-            lambda p, g, mu, nu, master: adamw_leaf(p, g, mu, nu, master, cf, lr),
-            params, grads, opt["mu"], opt["nu"], opt["master"], is_leaf=no_master)
-        pick = lambda i: jax.tree_util.tree_map(  # noqa: E731
-            lambda _, o: o[i], params, out)
-        masters = jax.tree_util.tree_map(
-            lambda _, o, old: None if old is None else o[3],
-            params, out, opt["master"], is_leaf=no_master)
+        params, new_opt = adamw_tree(params, grads, opt, lr)
         spread = jnp.mean(load.astype(jnp.float32), axis=-1, keepdims=True) - load
-        new_opt = {
-            "mu": pick(1), "nu": pick(2), "count": count, "master": masters,
-            "router_bias": opt["router_bias"] + cfg.bias_update_rate * jnp.sign(spread),
-            "router_load": load,
-        }
-        return pick(0), new_opt, loss
+        new_opt.update(
+            router_bias=opt["router_bias"] + cfg.bias_update_rate * jnp.sign(spread),
+            router_load=load)
+        return params, new_opt, loss
 
     return jax.jit(step, donate_argnums=(0, 1))
 

@@ -862,6 +862,12 @@ class FusedStepQuorum:
         return age_ms
 
 
+# calibrated budgets are at least this many median beat periods: the two a
+# loop one step ahead goes without a beat when it drains the device, and a
+# quarter more for steps that grow after calibration
+PERIOD_SAFETY = 2.5
+
+
 class QuorumMonitor:
     """Host driver for the on-device quorum tripwire.
 
@@ -945,6 +951,7 @@ class QuorumMonitor:
         self.last_max_age_ns: Optional[int] = None
         self.last_stale_device: Optional[int] = None
         self.last_calibration_p99_ms: Optional[float] = None
+        self.last_calibration_period_ms: Optional[float] = None
         # Online recalibration: a pre-start calibrate() can only sample an
         # IDLE interpreter, and an idle-calibrated budget undershoots the
         # stamp lateness real training produces (false trips) — so after
@@ -1119,6 +1126,22 @@ class QuorumMonitor:
         ``margin_ms``: a budget calibrated on an idle interpreter undershoots
         the stamp lateness a busy one produces and then false-trips.
 
+        A calibration tick blocks, so it drains the device and the age it
+        reads after ``load_fn`` is dispatch time whatever the step lasts.
+        The running loop's ticks are not so lucky: with manual beats they
+        read anything up to the time from one beat to the next, and up to
+        two such periods when a loop that runs one step ahead drains the
+        device after its last beat (a ``block_until_ready`` before a save).
+        So the freshest stamp's age is also sampled right BEFORE each
+        ``load_fn`` but the first — the beat period under load where
+        ``load_fn`` beats, beater jitter where an auto-beater does — and the
+        budget is at least ``PERIOD_SAFETY`` times their median (kept in
+        ``last_calibration_period_ms``): those two periods and a quarter
+        more for steps that grow after calibration.  On the chip a 0.21 s
+        step beside a 250 ms floor restarted a healthy job, and a budget of
+        two periods left a loop's drain 20-40 ms of room.  The
+        median and not the p99: one late beat in a dozen is no period.
+
         The floor physics (BASELINE north-star accounting): in XLA's
         execution model a collective observes stamps only at dispatch, so
         end-to-end detection = budget + dispatch cadence + one readback.
@@ -1131,9 +1154,12 @@ class QuorumMonitor:
         calibration find the platform's true floor (the measured p99 is
         kept in ``last_calibration_p99_ms``)."""
         self._start_beater()
-        ages = []
-        for _ in range(max(3, n_ticks)):
+        ages, periods = [], []
+        for i in range(max(3, n_ticks)):
             if load_fn is not None:
+                if i:
+                    periods.append(clamp_future_ns(stamp_age_ns(
+                        now_stamp_ns(), self._current_stamp())) / 1e6)
                 load_fn()
             saved = self.budget_ms
             self.budget_ms = float("inf")  # no trips during calibration
@@ -1144,7 +1170,25 @@ class QuorumMonitor:
         ages_arr = np.asarray(sorted(ages), dtype=np.float64)
         p99 = float(ages_arr[min(len(ages_arr) - 1, int(0.99 * len(ages_arr)))])
         self.last_calibration_p99_ms = p99
-        self.budget_ms = max(min_budget_ms, safety * p99 + margin_ms)
+        budget = max(min_budget_ms, safety * p99 + margin_ms)
+        period = float(np.median(periods)) if periods else None
+        if period is not None:
+            budget = max(budget, PERIOD_SAFETY * period + margin_ms)
+        self.last_calibration_period_ms = period
+        if self.identify and budget >= AGE_CAP_MS:
+            # packed ages saturate at the cap: a budget there never trips
+            log.warning(
+                "calibrated quorum budget %.0fms is past the %.0fms "
+                "identify-mode age cap; clamped to %.0fms",
+                budget, AGE_CAP_MS, 0.9 * AGE_CAP_MS,
+            )
+            budget = 0.9 * AGE_CAP_MS
+        log.info(
+            "quorum calibration: budget %.1fms (p99 tick age %.2fms, median beat "
+            "period %s over %d ticks)", budget, p99,
+            "not sampled" if period is None else "%.2fms" % period, len(ages),
+        )
+        self.budget_ms = budget
         return self.budget_ms
 
     def _observe_healthy_age(self, age: float) -> None:
