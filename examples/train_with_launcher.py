@@ -295,10 +295,13 @@ def main(argv=None):
                 with open(f"{path}.fingerprints.{rank}.json") as f:
                     saved_fp = json.load(f)
                 shm, read = stats.get("bytes_shm", 0), stats.get("bytes_read", 0)
+                # the committed generation's two warm rungs: the snapshot
+                # slot still on the chip, then its staged copy in shm
+                device = stats.get("bytes_device", 0)
                 report(
                     "restore", step=last, iteration=cw.iteration,
-                    source="resident" if read and shm == read else "disk",
-                    bytes_read=read, bytes_shm=shm,
+                    source="resident" if read and device + shm == read else "disk",
+                    bytes_read=read, bytes_device=device, bytes_shm=shm,
                     bit_equal=fingerprints(state) == saved_fp,
                     same_sharding=all(
                         got.sharding.is_equivalent_to(want.sharding, got.ndim)

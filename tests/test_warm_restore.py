@@ -360,6 +360,329 @@ def test_resident_in_place(case, tmp_path, monkeypatch):
     case(tmp_path, monkeypatch)
 
 
+# -- the device rung: the sealed snapshot slot the chip still holds ------------
+
+
+def _rejected(reason):
+    return get_registry().value_of(
+        "tpurx_ckpt_restore_device_rejected_total", {"reason": reason}
+    )
+
+
+def device_tree(seed=0):
+    """Device leaves only: what the slot alone can serve."""
+    k = jax.random.PRNGKey(seed)
+    return {
+        "w": jax.device_put(jax.random.normal(k, (64, 32))),
+        "h": jax.device_put(jax.random.normal(k, (16, 8)).astype("bfloat16")),
+        "b": jax.device_put(np.arange(256, dtype=np.float32)),
+    }
+
+
+def _device_bytes(tree):
+    return sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree)
+        if isinstance(leaf, jax.Array)
+    )
+
+
+@pytest.fixture
+def sealed_save(tmp_path):
+    """``save(tree) -> (dir, checkpointer)``: a committed single-process save
+    in snapshot mode through a ring of two (the CPU default, ``sync``, keeps
+    no slot), the checkpointer left open so the slot stays live."""
+    made = []
+
+    def save(tree, name="ck", cp=None):
+        if cp is None:
+            cp = AsyncCheckpointer(digest=True, resident=True,
+                                   stage_mode="snapshot", stage_buffers=2)
+            made.append(cp)
+        d = str(tmp_path / name)
+        cp.save(tree, d, extra_metadata={"iteration": 1})
+        return d, cp
+
+    yield save
+    for cp in made:
+        cp.close()
+
+
+def _slot_serves_a_committed_save(sealed_save, monkeypatch):
+    tree = device_tree(21)
+    d, _cp = sealed_save(tree)
+    rc = resident_mod.lookup(d)
+    assert rc.complete and rc.device is not None and rc.buffers()
+    assert rc.device.plan_sig == rc.plan_sig
+    _forbid_file_reads(monkeypatch)
+    monkeypatch.setattr(  # nor a reader thread: the engine is not built
+        ckpt_mod, "_RestoreEngine",
+        lambda *a, **k: pytest.fail("the slot served every leaf"))
+    before = _source_bytes("device"), _source_bytes("shm")
+    stats = {}
+    restored = load_checkpoint(d, tree, stats=stats)
+    assert_trees_equal(tree, restored)
+    assert stats["bytes_device"] == stats["bytes_read"] == _device_bytes(tree)
+    assert stats["bytes_shm"] == 0 and stats["leaves"] == 3
+    assert _source_bytes("device") - before[0] == _device_bytes(tree)
+    assert _source_bytes("shm") == before[1]
+    for got, want in zip(jax.tree_util.tree_leaves(restored),
+                         jax.tree_util.tree_leaves(tree)):
+        assert got.dtype == want.dtype and got.sharding == want.sharding
+        assert got.committed == want.committed
+
+
+def _resident_false_reads_disk_and_touches_neither_rung(sealed_save, monkeypatch):
+    tree = device_tree(22)
+    d, _cp = sealed_save(tree)
+    before = {s: _source_bytes(s) for s in ("device", "shm", "disk")}
+    stats = {}
+    restored = load_checkpoint(d, tree, stats=stats, resident=False)
+    assert_trees_equal(tree, restored)
+    assert stats["bytes_device"] == 0 and stats["bytes_shm"] == 0
+    assert stats["bytes_read"] == _device_bytes(tree)
+    assert _source_bytes("device") == before["device"]
+    assert _source_bytes("shm") == before["shm"]
+    assert _source_bytes("disk") - before["disk"] == _device_bytes(tree)
+    assert resident_mod.lookup(d).device is not None  # and stays published
+
+
+def _numpy_leaves_come_from_shm_in_the_same_call(sealed_save, monkeypatch):
+    tree = {**device_tree(23), "host": np.arange(512, dtype=np.int32),
+            "step": np.int64(7)}
+    d, _cp = sealed_save(tree)
+    _forbid_file_reads(monkeypatch)
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert_trees_equal(tree, restored)
+    host_bytes = tree["host"].nbytes + tree["step"].nbytes
+    assert stats["bytes_device"] == _device_bytes(tree)
+    assert stats["bytes_shm"] == host_bytes
+    assert stats["bytes_read"] == _device_bytes(tree) + host_bytes
+    assert stats["leaves"] == 5 and isinstance(restored["host"], np.ndarray)
+
+
+def _restored_leaves_share_no_buffer_with_the_slot(sealed_save, monkeypatch):
+    tree = device_tree(24)
+    expect = jax.tree_util.tree_map(lambda x: np.array(x), tree)
+    d, _cp = sealed_save(tree)
+    slot = {leaf.unsafe_buffer_pointer()
+            for leaf in resident_mod.lookup(d).device.leaves}
+    for _ in range(2):  # the slot serves the next fault too
+        stats = {}
+        restored = load_checkpoint(d, tree, stats=stats)
+        assert stats["bytes_device"] == _device_bytes(tree)
+        assert_trees_equal(expect, restored)
+        for leaf in jax.tree_util.tree_leaves(restored):
+            assert leaf.unsafe_buffer_pointer() not in slot
+            leaf.delete()  # what a donating step does to its state
+    assert not any(l.is_deleted() for l in resident_mod.lookup(d).device.leaves)
+
+
+def _the_next_save_takes_the_slot(d, cp, tree, sealed_save, monkeypatch):
+    """The call pops the drained slot to donate it, and its stager then takes
+    the one pooled staging tree too: the old generation keeps neither part,
+    and the new one is served from the slot it was bound to."""
+    real = cp._ring_snapshot
+
+    def popped_first(*args):
+        out = real(*args)
+        # the call has the slot; the stager has not yet taken the shm tree
+        assert resident_mod.lookup(d).device is None
+        assert resident_mod.lookup(d).buffers()
+        return out
+
+    monkeypatch.setattr(cp, "_ring_snapshot", popped_first)
+    d2, _ = sealed_save(tree, name="next", cp=cp)
+    assert cp.snap_ring_stats["reused"] == 1
+    assert resident_mod.lookup(d2).device.slot is cp._snap_ring[-1]
+    stats = {}
+    assert_trees_equal(tree, load_checkpoint(d2, tree, stats=stats))
+    assert stats["bytes_device"] == _device_bytes(tree)
+    return "disk"
+
+
+def _close_clears_the_ring(d, cp, tree, sealed_save, monkeypatch):
+    cp.close()
+    return "shm"
+
+
+def _the_backends_are_cleared(d, cp, tree, sealed_save, monkeypatch):
+    """``ShrinkMeshStage`` with the backends' clearing simulated: the device
+    part is gone by the time the arrays would be."""
+    import jax.extend.backend as jeb
+
+    from tpu_resiliency.inprocess.abort import ShrinkMeshStage
+    from tpu_resiliency.parallel import distributed as dist_mod
+
+    seen = []
+    monkeypatch.setattr(dist_mod, "_initialized", dist_mod._initialized)
+    monkeypatch.setattr(
+        jeb, "clear_backends",
+        lambda: seen.append(resident_mod.lookup(d).device))
+    assert "backends cleared" in ShrinkMeshStage(enabled=True).release()
+    assert seen == [None]
+    return "shm"
+
+
+@pytest.mark.parametrize("reuse", [
+    _the_next_save_takes_the_slot, _close_clears_the_ring,
+    _the_backends_are_cleared,
+], ids=lambda reuse: reuse.__name__.lstrip("_"))
+def test_device_part_is_unpublished_on_reuse(reuse, sealed_save, monkeypatch):
+    tree = device_tree(25)
+    d, cp = sealed_save(tree)
+    assert resident_mod.lookup(d).device is not None
+    serves = reuse(d, cp, tree, sealed_save, monkeypatch)
+    rc = resident_mod.lookup(d)
+    if serves == "shm":  # the shm part is untouched
+        assert rc.device is None and rc.buffers()
+        _forbid_file_reads(monkeypatch)
+    else:
+        assert rc is None
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert_trees_equal(tree, restored)
+    assert stats["bytes_device"] == 0 and stats["bytes_read"] == _device_bytes(tree)
+    assert stats["bytes_shm"] == (_device_bytes(tree) if serves == "shm" else 0)
+
+
+def _a_swapped_slot_leaf_fails_closed(sealed_save, monkeypatch):
+    tree = device_tree(26)
+    d, _cp = sealed_save(tree)
+    part = resident_mod.lookup(d).device
+    row = part.dev_idx.index(sorted(tree).index("w"))
+    part.leaves[row] = part.leaves[row] + 1.0  # other bytes, after the seal
+    _forbid_file_reads(monkeypatch)
+    before = _rejected("seal"), _source_bytes("device")
+    stats = {}
+    restored = load_checkpoint(d, tree, threads=2, stats=stats)
+    assert_trees_equal(tree, restored)  # the saved bytes, from shm
+    assert stats["bytes_device"] == 0
+    assert stats["bytes_shm"] == stats["bytes_read"] == _device_bytes(tree)
+    assert _rejected("seal") - before[0] == 1
+    assert _source_bytes("device") == before[1]
+    assert resident_mod.lookup(d).device is None
+    assert resident_mod.lookup(d).buffers()
+
+
+def _another_dtype_or_sharding_takes_the_engine_for_that_leaf(sealed_save, monkeypatch):
+    mesh = Mesh(np.array(jax.devices()), ("x",))
+    rows = NamedSharding(mesh, P("x", None))
+    tree = {
+        "cast": jax.device_put(np.arange(128, dtype=np.float32)),
+        "moved": jax.device_put(
+            np.arange(64 * 32, dtype=np.float32).reshape(64, 32), rows),
+        "same": jax.device_put(
+            np.arange(16 * 64, dtype=np.float32).reshape(16, 64), rows),
+    }
+    d, _cp = sealed_save(tree)
+    template = {
+        "cast": jax.device_put(np.zeros(128, dtype=np.float16)),
+        "moved": jax.device_put(
+            np.zeros((64, 32), np.float32), NamedSharding(mesh, P(None, "x"))),
+        "same": tree["same"],
+    }
+    before = _rejected("template")
+    stats = {}
+    restored = load_checkpoint(d, template, threads=2, stats=stats)
+    assert _rejected("template") - before == 2
+    assert stats["bytes_device"] == tree["same"].nbytes
+    assert stats["bytes_shm"] == tree["cast"].nbytes + tree["moved"].nbytes
+    assert restored["cast"].dtype == np.float16
+    np.testing.assert_array_equal(
+        np.asarray(restored["cast"]), np.arange(128, dtype=np.float16))
+    for name in ("moved", "same"):
+        np.testing.assert_array_equal(
+            np.asarray(restored[name]), np.asarray(tree[name]))
+        assert restored[name].sharding == template[name].sharding
+    assert resident_mod.lookup(d).device is not None  # the slot is sound
+
+
+def _the_four_intervals_are_recorded_once_a_load(sealed_save, monkeypatch):
+    from tpu_resiliency.telemetry import flight
+
+    tree = device_tree(28)
+    d, _cp = sealed_save(tree)
+    flight.configure(enabled=True, capacity=4096)
+    try:
+        load_checkpoint(d, tree)
+        records = [r for r in flight._records("test") if "ident" in r]
+    finally:
+        flight.configure()
+    loads = [r for r in records if r["event"].startswith("ckpt.load")]
+    (ident,) = {r["ident"] for r in loads}
+    names = [r["event"] for r in loads]
+    assert names == [
+        "ckpt.load_begin",
+        "ckpt.load.plan_begin", "ckpt.load.plan_end",
+        "ckpt.load.start_begin", "ckpt.load.start_end",
+        "ckpt.load.place_begin", "ckpt.load.place_end",
+        "ckpt.load.wait_begin", "ckpt.load.wait_end",
+        "ckpt.load_end",
+    ]
+    assert {r["parent"] for r in loads[1:-1]} == {"ckpt.load"}
+
+
+@pytest.mark.parametrize("case", [
+    _slot_serves_a_committed_save,
+    _resident_false_reads_disk_and_touches_neither_rung,
+    _numpy_leaves_come_from_shm_in_the_same_call,
+    _restored_leaves_share_no_buffer_with_the_slot,
+    _a_swapped_slot_leaf_fails_closed,
+    _another_dtype_or_sharding_takes_the_engine_for_that_leaf,
+    _the_four_intervals_are_recorded_once_a_load,
+], ids=lambda case: case.__name__.lstrip("_"))
+def test_device_rung(case, sealed_save, monkeypatch):
+    case(sealed_save, monkeypatch)
+
+
+@pytest.mark.parametrize("how", ["sync_mode", "ring_of_one", "resident_off"])
+def test_no_slot_no_device_part(how, tmp_path):
+    """Where no sealed slot can exist the save publishes as before: shm alone
+    (or nothing), and the seal is not even dispatched."""
+    kwargs = {
+        "sync_mode": dict(stage_mode="sync", resident=True),
+        "ring_of_one": dict(stage_mode="snapshot", stage_buffers=1, resident=True),
+        "resident_off": dict(stage_mode="snapshot", stage_buffers=2, resident=False),
+    }[how]
+    tree = device_tree(29)
+    d = str(tmp_path / "ck")
+    cp = AsyncCheckpointer(digest=True, **kwargs)
+    try:
+        cp.save(tree, d, extra_metadata={"iteration": 1})
+        assert all(slot["seal"] is None for slot in cp._snap_ring)
+        rc = resident_mod.lookup(d)
+        assert (rc is None) == (how == "resident_off")
+        assert rc is None or rc.device is None
+        stats = {}
+        assert_trees_equal(tree, load_checkpoint(d, tree, stats=stats))
+        assert stats["bytes_device"] == 0
+    finally:
+        cp.close()
+
+
+def test_seal_is_the_chunk_fingerprint_of_a_whole_leaf():
+    """One ``(A, B)`` pair a leaf: the drain's chunk fingerprint over a grid
+    of one chunk, against the host oracle, for every lane width."""
+    from tpu_resiliency.checkpointing.async_ckpt import device_digest
+
+    rng = np.random.default_rng(5)
+    leaves = [
+        rng.standard_normal((33, 17)).astype(np.float32),
+        rng.integers(0, 1 << 16, (5, 7, 3), dtype=np.uint16),
+        rng.integers(0, 255, (1000,), dtype=np.uint8),
+        np.float32(3.5),
+        np.asarray(jax.random.normal(jax.random.PRNGKey(0), (9, 4)).astype("bfloat16")),
+    ]
+    seal = np.asarray(device_digest.seal_leaves([jax.device_put(x) for x in leaves]))
+    for row, leaf in zip(seal, leaves):
+        (want,) = device_digest.host_fingerprints(
+            np.asarray(leaf).tobytes(), leaf.dtype, chunk_bytes=1 << 30,
+            use_direct=False)
+        assert row.tolist() == want.tolist()
+    assert device_digest.seal_leaves([jax.device_put(np.ones(3, np.complex64))]) is None
+
+
 class TestDeltaSaves:
     def test_delta_skips_frozen_chunks_and_restores(self, tmp_path):
         """Save, mutate ONE leaf, delta-save: frozen chunks are recorded by

@@ -10,7 +10,10 @@ Save pipeline per request (default ``stage_mode="snapshot"``):
                        ordering makes this donation-safe: the copy is
                        enqueued before the next step can reuse donated
                        input buffers.  The worker's streamed drain call is
-                       opened here too, before any bytes move.
+                       opened here too, before any bytes move.  The copy is
+                       sealed on the device by a second dispatch (a
+                       fingerprint a leaf): once the save commits, the slot
+                       is the restore's first source (``resident.py``).
   2. (stager thread)   stage_pytree: pipelined D2H of the snapshot into
                        pooled (double-buffered) shm — zero allocation and
                        zero first-touch faults in steady state; each shard
@@ -67,6 +70,7 @@ from .staging import (
     stage_pytree,
 )
 from .writer import (
+    _RESTORE_SOURCE,
     _RestoreEngine,
     is_committed,
     read_metadata,
@@ -101,6 +105,15 @@ _DRAIN_PROGRESS = gauge(
 _SNAP_RING_BYTES = gauge(
     "tpurx_ckpt_snap_ring_bytes",
     "Device bytes held by the live slots of the snapshot ring",
+)
+_DEVICE_REJECTED = counter(
+    "tpurx_ckpt_restore_device_rejected_total",
+    "Times the restore's device rung declined: seal = a restore whose copies' "
+    "fingerprints differ from the slot's seal (served from shm instead), "
+    "template = a leaf whose template differs from the slot's leaf in shape, "
+    "dtype, sharding or committed-ness (that leaf took the engine), deleted = "
+    "a restore that found the slot's arrays deleted",
+    labels=("reason",),
 )
 
 # Flight-recorder intervals of one save (ident = the save ticket).  The call
@@ -299,9 +312,12 @@ class AsyncCheckpointer:
         self.last_stage_stats: Dict[str, int] = {}
         # "snapshot" | "sync": the mode the last async_save really took
         self.last_stage_mode: Optional[str] = None
-        # snapshot ring: {"sig", "leaves" (device arrays), "job"} slots; a
-        # slot is reusable (its buffers donatable) only once its job's
-        # staging has drained — job.done is the D2H-consumed fence
+        # snapshot ring: {"sig", "leaves" (device arrays), "dev_idx" (their
+        # positions in the flattened tree), "seal", "job"} slots; a slot is
+        # reusable (its buffers donatable) only once its job's staging has
+        # drained — job.done is the D2H-consumed fence.  A slot leaves the
+        # ring through _drop_slot alone: a committed generation may be
+        # serving restores from it (resident.py, "device part")
         self._snap_ring: List[Dict[str, Any]] = []
         self._snap_lock = threading.Lock()
         self.snap_ring_stats: Dict[str, int] = {"reused": 0, "fresh": 0}
@@ -371,7 +387,7 @@ class AsyncCheckpointer:
                         self._snap_ring.append(snap_slot)
                         while len(self._snap_ring) > self._ring_cap():
                             # the evicted slot's buffers just drop
-                            self._snap_ring.pop(0)
+                            self._drop_slot(0)
                         self._note_ring_bytes()
                 if digest is None:
                     digest = self.digest
@@ -475,6 +491,24 @@ class AsyncCheckpointer:
             for slot in self._snap_ring for leaf in slot["leaves"]
         ))
 
+    def _drop_slot(self, i: int) -> Dict[str, Any]:
+        """Take slot ``i`` out of the ring (``_snap_lock`` held), unpublishing
+        first whatever generation serves restores from it."""
+        slot = self._snap_ring.pop(i)
+        resident_mod.unpublish_device(slot)
+        return slot
+
+    def _publishes_resident(self) -> bool:
+        return bool(
+            env.CKPT_RESIDENT.get() if self.resident is None else self.resident
+        )
+
+    def _seals_slots(self) -> bool:
+        """Whether a slot of this checkpointer can become a restore source:
+        what governs the shm rung governs this one, and only a generation
+        that holds the whole tree (one process) gets a device part."""
+        return self.world_size == 1 and self._publishes_resident()
+
     def _ring_snapshot(self, tree: Any, sig: str) -> Tuple[Any, Optional[Dict]]:
         """Device snapshot through the double-buffered ring: with
         ``stage_buffers >= 2``, the copy DONATES a previous slot's device
@@ -501,7 +535,7 @@ class AsyncCheckpointer:
                 for i, s in enumerate(self._snap_ring):
                     if (s["sig"] == sig and len(s["leaves"]) == len(dev_idx)
                             and (s["job"] is None or s["job"].done.is_set())):
-                        slot = self._snap_ring.pop(i)
+                        slot = self._drop_slot(i)
                         self._note_ring_bytes()
                         break
         copies: List[Any] = []
@@ -530,7 +564,20 @@ class AsyncCheckpointer:
             l if i in dev_set else (l.copy() if isinstance(l, np.ndarray) else l)
             for i, l in enumerate(leaves)
         ]
-        new_slot = {"sig": sig, "leaves": list(copies), "job": None}
+        seal = None
+        if copies and self._seals_slots():
+            from . import device_digest as device_digest_mod
+
+            try:
+                # its own program, dispatched and never waited for; it reads
+                # the slot before the stager's D2H does, so it vouches for
+                # the bytes the committed index's crcs will
+                seal = device_digest_mod.seal_leaves(copies)
+            except Exception:  # noqa: BLE001 - an unsealed slot serves nothing
+                log.warning("snapshot slot not sealed; restores of this save "
+                            "start at shm", exc_info=True)
+        new_slot = {"sig": sig, "leaves": list(copies), "dev_idx": dev_idx,
+                    "seal": seal, "job": None}
         return jax.tree_util.tree_unflatten(treedef, out), new_slot
 
     # -- staging thread ----------------------------------------------------
@@ -735,11 +782,8 @@ class AsyncCheckpointer:
         self, ckpt_dir: str, job: _StagingJob, save_id: str, sig: str,
         shards_idx: List[Dict],
     ) -> None:
-        enabled = (
-            env.CKPT_RESIDENT.get() if self.resident is None else self.resident
-        )
         staged = job.staged
-        if not enabled or staged is None:
+        if not self._publishes_resident() or staged is None:
             return
         bufs = staged.shm_buffers()
         name_of = {
@@ -766,9 +810,19 @@ class AsyncCheckpointer:
             # can a restore skip the filesystem (metadata included)
             complete=self.world_size == 1,
             tree=staged,
+            device=self._device_part(job),
         )
         resident_mod.publish(rc)
         self._published_dirs.add(os.path.abspath(ckpt_dir))
+
+    def _device_part(self, job: _StagingJob) -> Optional[resident_mod.DevicePart]:
+        """The sealed ring slot ``job`` drained from, if the ring still holds
+        it: the committed generation's device part."""
+        with self._snap_lock:
+            for slot in self._snap_ring:
+                if slot["job"] is job and slot["seal"] is not None:
+                    return resident_mod.DevicePart(slot)
+        return None
 
     def maybe_finalize(self, blocking: bool = False) -> List[int]:
         done = self.queue.maybe_finalize_async_calls(blocking=blocking)
@@ -813,7 +867,8 @@ class AsyncCheckpointer:
                 self._stage_q.put(None)
                 self._stager.join(timeout=10)
             with self._snap_lock:
-                self._snap_ring.clear()  # drop device snapshot references
+                while self._snap_ring:  # drop device snapshot references
+                    self._drop_slot(0)
                 self._note_ring_bytes()
             self._drain_pool()
             self.queue.close()
@@ -964,6 +1019,79 @@ def _owned_copy(arr: np.ndarray) -> np.ndarray:
     return out
 
 
+def _match_slot(rc: Any, leaves: List[Any]) -> Tuple[Any, List[Tuple[int, int]]]:
+    """The generation's device part and ``[(leaf_idx, row)]``: the template
+    leaves it can serve, each with its row in the part.  A leaf is served
+    only where the slot's leaf is what ``_place_leaf`` would make of the
+    template: same shape, dtype, sharding and committed-ness (a jitted step
+    keys its cache on those).  Every other leaf is the engine's, as without
+    a device part."""
+    import jax
+
+    part = rc.device if rc is not None and rc.complete else None
+    if part is None or part.plan_sig != rc.plan_sig:
+        return None, []
+    if any(src.is_deleted() for src in part.leaves):
+        # somebody deleted the slot's arrays without telling the registry
+        resident_mod.unpublish_device(part.slot)
+        _DEVICE_REJECTED.labels(reason="deleted").inc()
+        return None, []
+    matched = []
+    for row, (idx, src) in enumerate(zip(part.dev_idx, part.leaves)):
+        tmpl = leaves[idx]
+        if not isinstance(tmpl, jax.Array):
+            continue
+        if (tuple(tmpl.shape) == tuple(src.shape) and tmpl.dtype == src.dtype
+                and tmpl.committed == src.committed
+                and (tmpl.sharding == src.sharding or
+                     tmpl.sharding.is_equivalent_to(src.sharding, src.ndim))):
+            matched.append((idx, row))
+        else:
+            _DEVICE_REJECTED.labels(reason="template").inc()
+    return part, matched
+
+
+def _copy_from_slot(
+    part: Any, matched: List[Tuple[int, int]], leaves: List[Any], load_id: int
+) -> Dict[int, Any]:
+    """The device rung: ``{leaf_idx: restored array}`` out of ONE program that
+    copies the matched slot leaves into fresh buffers (nothing donated: the
+    slot stays for the next fault and the next save) and fingerprints the
+    copies against the slot's seal on the device.  A mismatch fails closed:
+    the device part is unpublished, the copies are dropped and ``{}`` leaves
+    every leaf to the engine."""
+    import jax
+
+    from . import device_digest as device_digest_mod
+
+    rows = [row for _, row in matched]
+    with flight.span(IV_LOAD_START, load_id, IV_LOAD):
+        seal = part.seal
+        if len(rows) != len(part.leaves):
+            seal = seal[np.asarray(rows)]
+        copies, verdict = device_digest_mod.ckpt_slot_restore(
+            [part.leaves[row] for row in rows], seal
+        )
+    with flight.span(IV_LOAD_PLACE, load_id, IV_LOAD):
+        jax.block_until_ready(copies)
+    with flight.span(IV_LOAD_WAIT, load_id, IV_LOAD):
+        sealed = device_digest_mod.read_verdict(verdict)
+    if not sealed:
+        log.error("snapshot slot fails its seal: restoring from shm")
+        resident_mod.unpublish_device(part.slot)
+        _DEVICE_REJECTED.labels(reason="seal").inc()
+        return {}
+    served = {}
+    for (idx, _), copy in zip(matched, copies):
+        sharding = leaves[idx].sharding
+        if copy.sharding != sharding:
+            # an equivalent sharding under another name (a jit's output
+            # spells its spec its own way): the same buffers, relabelled
+            copy = jax.device_put(copy, sharding)
+        served[idx] = copy
+    return served
+
+
 def load_checkpoint(
     ckpt_dir: str,
     template: Any,
@@ -990,17 +1118,34 @@ def load_checkpoint(
     remaining leaves are still reading, so read, verify, and H2D transfer
     pipeline instead of serializing.
 
-    ``stats``, if given, is filled with the engine's accounting
-    (``bytes_read`` / ``bytes_shm`` / ``bytes_in_place`` / ``chunks`` /
-    ``shards`` / ``leaves`` / ``verify_ns`` / ``restore_ns`` / ``threads``).
+    ``stats``, if given, is filled with the restore's accounting
+    (``bytes_read``, the total delivered into the tree, / ``bytes_device`` /
+    ``bytes_shm`` / ``bytes_in_place`` / ``chunks`` / ``shards`` / ``leaves``
+    / ``verify_ns`` / ``restore_ns`` / ``threads``).
 
     **Warm restore**: when the committed generation for ``ckpt_dir`` is
-    still shm-resident (published at finalize, see ``resident.py``) and
-    ``resident`` is not False (None = ``TPURX_CKPT_RESIDENT``), shards are
-    sourced from memory instead of disk — for a complete (single-process)
-    generation no checkpoint file is opened at all, metadata included.
-    Every chunk is still verified against the committed index crcs;
-    ``stats["bytes_shm"]`` reports how much of the restore came warm.
+    still resident (published at finalize, see ``resident.py``) and
+    ``resident`` is not False (None = ``TPURX_CKPT_RESIDENT``; False means
+    "from disk" and bypasses both warm rungs), shards are sourced from
+    memory instead of disk — for a complete (single-process) generation no
+    checkpoint file is opened at all, metadata included.  Every chunk is
+    still verified against the committed index crcs; ``stats["bytes_shm"]``
+    reports how much of the restore came from shm.
+
+    **Device rung**: a complete generation whose snapshot-ring slot is still
+    live (no later save has reused it, the backends were not cleared) is
+    served from the chip first.  Every template leaf whose slot leaf has its
+    shape, dtype, sharding and committed-ness is copied device to device by
+    one jitted program that also fingerprints the copies against the seal the
+    save took of the slot; the verdict is read before this function returns,
+    and a mismatch fails closed (the device part is unpublished, the call
+    restores from shm).  The returned tree never aliases the slot.  Leaves
+    the slot cannot serve (numpy leaves, another dtype or placement) take
+    the engine below, in the same call.  ``stats["bytes_device"]`` and
+    ``tpurx_ckpt_restore_source_total{source="device"}`` account it.  The
+    rung records the restore's own intervals: ``ckpt.load.plan`` (lookup and
+    match), ``.start`` (dispatch), ``.place`` (until the copies are on
+    hand), ``.wait`` (the verdict's fetch).
 
     A resident shard that is the whole of its leaf is not copied on the
     host at all: its spans are verified **where they lie** and, once all of
@@ -1063,7 +1208,28 @@ def load_checkpoint(
                     f"template has {len(leaves)} leaves, checkpoint has "
                     f"{len(meta['leaf_paths'])}"
                 )
+            part, matched = _match_slot(rc, leaves)
+        t0_ns = time.monotonic_ns()
         out_leaves: List[Any] = [None] * len(leaves)
+        from_slot = (
+            _copy_from_slot(part, matched, leaves, load_id) if matched else {}
+        )
+        for idx, copy in from_slot.items():
+            out_leaves[idx] = copy
+        bytes_device = sum(int(copy.nbytes) for copy in from_slot.values())
+        if from_slot:
+            _RESTORE_SOURCE.labels(source="device").inc(bytes_device)
+        if stats is not None:
+            stats["bytes_device"] = bytes_device
+        if len(from_slot) == len(leaves):
+            # no engine: nothing to read, verify or release
+            if stats is not None:
+                stats.update(
+                    bytes_read=bytes_device, bytes_shm=0, bytes_in_place=0,
+                    chunks=0, shards=0, leaves=len(leaves), verify_ns=0,
+                    restore_ns=time.monotonic_ns() - t0_ns, threads=0,
+                )
+            return jtu.tree_unflatten(treedef, out_leaves)
         # placed arrays whose transfer may still be reading a resident view
         in_flight: List[Any] = []
 
@@ -1085,7 +1251,10 @@ def load_checkpoint(
         with flight.span(IV_LOAD_START, load_id, IV_LOAD):
             engine = _RestoreEngine(
                 ckpt_dir, meta, num_threads=resolve_restore_threads(threads),
-                leaf_indices=range(len(leaves)), resident=res_bufs,
+                leaf_indices=[
+                    i for i in range(len(leaves)) if i not in from_slot
+                ],
+                resident=res_bufs,
             )
         try:
             while True:
@@ -1106,4 +1275,7 @@ def load_checkpoint(
                 engine.close()
         if stats is not None:
             stats.update(engine.stats())
+            # bytes_read stays the total delivered into the tree
+            stats["bytes_read"] += bytes_device
+            stats["leaves"] += len(from_slot)
         return jtu.tree_unflatten(treedef, out_leaves)

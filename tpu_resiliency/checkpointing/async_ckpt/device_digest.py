@@ -105,6 +105,12 @@ def _supported(dtype: Any) -> bool:
 
 def _as_lanes(x):
     """Flatten a device array to its uint32 lane stream (see module doc)."""
+    return _lanes(x).reshape(-1)
+
+
+def _lanes(x):
+    """The uint32 lanes of a device array, in its own shape (8-byte dtypes
+    gain a trailing axis of 2): row-major order is byte order."""
     dt = np.dtype(x.dtype)
     if dt == np.bool_:
         lanes = x.astype(jnp.uint8).astype(jnp.uint32)
@@ -116,7 +122,7 @@ def _as_lanes(x):
         lanes = lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
     else:
         lanes = lax.bitcast_convert_type(x, jnp.uint8).astype(jnp.uint32)
-    return lanes.reshape(-1)
+    return lanes
 
 
 # murmur3 fmix32 constants; the position multiplier is the golden-ratio
@@ -126,19 +132,23 @@ _MIX1 = 0x85EBCA6B
 _MIX2 = 0xC2B2AE35
 
 
+def _mix(lanes, idx):
+    """``fmix32(lane ^ (index * PHI))``, lane by lane (see module doc)."""
+    h = lanes ^ (idx * jnp.uint32(_PHI))
+    h = h ^ (h >> 16)
+    h = h * jnp.uint32(_MIX1)
+    h = h ^ (h >> 13)
+    h = h * jnp.uint32(_MIX2)
+    return h ^ (h >> 16)
+
+
 def _build_fp_fn(shape: Tuple[int, ...], dtype: np.dtype, grid: Grid):
     lb = _lane_bytes(dtype)
     bounds = [(off // lb, (off + length) // lb) for off, length in grid]
 
     def fp(x):
         lanes = _as_lanes(x)
-        idx = jnp.arange(lanes.shape[0], dtype=jnp.uint32)
-        h = lanes ^ (idx * jnp.uint32(_PHI))
-        h = h ^ (h >> 16)
-        h = h * jnp.uint32(_MIX1)
-        h = h ^ (h >> 13)
-        h = h * jnp.uint32(_MIX2)
-        h = h ^ (h >> 16)
+        h = _mix(lanes, jnp.arange(lanes.shape[0], dtype=jnp.uint32))
         rows = []
         for s, e in bounds:
             seg = h[s:e]
@@ -177,6 +187,82 @@ def shard_fingerprints(
     if fn is None:
         fn = _FP_CACHE[key] = _build_fp_fn(shape, dt, grid)
     return fn(data)
+
+
+# -- whole-leaf seals: the snapshot ring's slots as a restore source ----------
+#
+# A ring slot that a committed generation is bound to (``resident.py``,
+# "device part") is sealed with one ``(A, B)`` pair a leaf: the chunk
+# fingerprint above over a grid of one chunk, the whole leaf.  Two programs a
+# tree, each compiled once per tree signature by its ``jax.jit``: the seal
+# (dispatched by the save right after the snapshot copy, never waited for)
+# and the sealed copy (the restore's device rung).  One program a tree because
+# a dispatch costs 0.4 ms on the chip whatever the program's size (447 of them
+# 0.17 s); a leaf's part traced through a jit of its own, because tracing is
+# paid at every process start, compile cache or not: 10 ms a leaf when the
+# tree's program spells out every leaf, once a leaf SIGNATURE through the
+# inner jit, of which a tree of 447 leaves has 46 (PERF.md, PR 34).
+
+
+def _leaf_fingerprint(x):
+    """``uint32[2]``: ``(A, B)`` over all of ``x``, as one chunk.  The lane's
+    position comes from an iota a dimension, never from a flattened copy, so
+    the pass is one fused read of the leaf whatever its tiling, and a leaf
+    sharded over devices is reduced where its shards lie."""
+    lanes = _lanes(x)
+    idx, stride = jnp.zeros(lanes.shape, jnp.uint32), 1
+    for axis in reversed(range(lanes.ndim)):
+        idx = idx + lax.broadcasted_iota(
+            jnp.uint32, lanes.shape, axis) * jnp.uint32(stride & 0xFFFFFFFF)
+        stride *= lanes.shape[axis]
+    h = _mix(lanes, idx)
+    return jnp.stack([jnp.sum(h, dtype=jnp.uint32),
+                      jnp.sum(h * (idx + jnp.uint32(1)), dtype=jnp.uint32)])
+
+
+def _jit(fn):
+    return jax.jit(fn) if _HAVE_JAX else fn
+
+
+_leaf_seal = _jit(_leaf_fingerprint)
+
+
+@_jit
+def _leaf_sealed_copy(x, seal):
+    # the barrier keeps the fingerprint on the copy: without it XLA reads the
+    # slot twice and vouches for bytes it never handed out
+    copy = lax.optimization_barrier(jnp.copy(x))
+    return copy, jnp.all(_leaf_seal(copy) == seal)
+
+
+@_jit
+def ckpt_slot_seal(leaves):
+    """``(n_leaves, 2) uint32``: the seal of a slot's device leaves."""
+    return jnp.stack([_leaf_seal(x) for x in leaves])
+
+
+@_jit
+def ckpt_slot_restore(leaves, seal):
+    """ONE program: ``leaves`` copied into fresh buffers (nothing donated) and
+    the copies fingerprinted against ``seal`` on the device.  Returns
+    ``(copies, verdict)``, a bool a leaf: hand the verdict to
+    :func:`read_verdict` before the copies are trusted."""
+    pairs = [_leaf_sealed_copy(x, seal[row]) for row, x in enumerate(leaves)]
+    return [copy for copy, _ in pairs], jnp.stack([ok for _, ok in pairs])
+
+
+def seal_leaves(leaves: Sequence[Any]) -> Optional[Any]:
+    """Dispatch the seal of a slot's device leaves: the DEVICE
+    ``(n_leaves, 2) uint32`` result (no host sync), or None where a leaf has
+    no lane bitcast — such a slot is simply never a restore source."""
+    if not _HAVE_JAX or not all(_supported(x.dtype) for x in leaves):
+        return None
+    return ckpt_slot_seal(list(leaves))
+
+
+def read_verdict(verdict: Any) -> bool:
+    """The sealed copy's one small readback: a bool a leaf."""
+    return bool(np.all(jax.device_get(verdict)))
 
 
 def read_fingerprints(fps: Sequence[Optional[Any]]) -> List[Optional[np.ndarray]]:

@@ -25,6 +25,27 @@ Lifecycle (the registry is the single source of truth for validity):
   segments are closed when the generation is invalidated instead of
   immediately, keeping the warm source alive across pool churn.
 
+A generation may have a **device part** as it has an shm part: the snapshot
+ring slot its staging drained from (``checkpointer._ring_snapshot``) is a
+device copy of exactly the committed bytes, and stays live until the ring
+reuses it.  ``load_checkpoint`` serves from it ahead of shm (device slot ->
+shm -> peers -> disk).  The same three steps, applied to slots:
+
+- **publish**: the save that publishes the shm generation binds the slot
+  whose job it was (:class:`DevicePart`) — single-process generations
+  only, and only a slot that carries a seal.
+- **seal**: right after the snapshot copy is dispatched, the save
+  dispatches one fingerprint program over the slot's leaves
+  (``device_digest.seal_leaves``) and keeps the small device result in the
+  slot.  It is taken before the stager's D2H reads the slot, so it vouches
+  for the bytes the committed index's crcs vouch for; a restore
+  fingerprints its copies against it on the device and fails closed.
+- **invalidate-on-reuse**: the moment the ring pops a slot to donate it,
+  evicts one, or is cleared (``close()``), and wherever the process's
+  backends are really cleared (``ShrinkMeshStage``: every device array is
+  gone), :func:`unpublish_device` drops the device part first.  The shm
+  part is untouched by any of these.
+
 This publish/invalidate protocol is also the ordering backbone of the
 device-digest D2H-skip path: ``StagedTree.content_id`` records which
 committed save's bytes a pooled tree holds, and a delta save may skip a
@@ -60,6 +81,24 @@ _LOCK = threading.Lock()
 _BY_DIR: Dict[str, "ResidentCheckpoint"] = {}
 
 
+class DevicePart:
+    """The device half of a committed generation: a sealed snapshot-ring slot.
+
+    ``slot`` is the ring's own slot (the identity invalidate-on-reuse goes
+    by), ``leaves`` its device arrays, ``dev_idx`` their positions in the
+    flattened tree, ``seal`` the device ``(n_leaves, 2) uint32`` fingerprint
+    taken when the snapshot was."""
+
+    __slots__ = ("slot", "leaves", "dev_idx", "plan_sig", "seal")
+
+    def __init__(self, slot: Dict[str, Any]):
+        self.slot = slot
+        self.leaves: List[Any] = list(slot["leaves"])
+        self.dev_idx: List[int] = list(slot["dev_idx"])
+        self.plan_sig: str = slot["sig"]
+        self.seal = slot["seal"]
+
+
 class ResidentCheckpoint:
     """One committed generation's shm-resident read source.
 
@@ -68,12 +107,12 @@ class ResidentCheckpoint:
     index recorded) plus a ``buf`` memoryview over the staged shm segment.
     ``complete`` marks a generation that covers the WHOLE tree (single
     process); partial generations still serve their own shards, overlaid on
-    the disk metadata.
+    the disk metadata.  ``device`` is the generation's device part, or None.
     """
 
     __slots__ = (
         "ckpt_dir", "save_id", "plan_sig", "process_index", "shards",
-        "leaf_paths", "treedef_repr", "complete", "tree", "retired",
+        "leaf_paths", "treedef_repr", "complete", "tree", "retired", "device",
     )
 
     def __init__(
@@ -87,6 +126,7 @@ class ResidentCheckpoint:
         treedef_repr: str,
         complete: bool,
         tree: Any,
+        device: Optional[DevicePart] = None,
     ):
         self.ckpt_dir = os.path.abspath(ckpt_dir)
         self.save_id = save_id
@@ -98,6 +138,7 @@ class ResidentCheckpoint:
         self.complete = complete
         self.tree = tree            # backing StagedTree (keeps shm mapped)
         self.retired = False        # True -> registry owns the tree's close
+        self.device = device
 
     def as_meta(self) -> Dict[str, Any]:
         """A ``metadata.json``-shaped dict synthesized from the resident
@@ -166,6 +207,16 @@ def invalidate_tree(tree: Any) -> None:
             _BY_DIR.pop(d)
 
 
+def unpublish_device(slot: Optional[Dict[str, Any]] = None) -> None:
+    """Drop the device part of every generation bound to ``slot`` (of every
+    generation, without one) — the slot's buffers are about to be donated,
+    dropped or lost.  The shm part stays published."""
+    with _LOCK:
+        for rc in _BY_DIR.values():
+            if rc.device is not None and (slot is None or rc.device.slot is slot):
+                rc.device = None
+
+
 def retire_tree(tree: Any) -> bool:
     """The staging pool is letting go of ``tree``.  If a resident
     generation still reads from it, take ownership (close at invalidate)
@@ -188,3 +239,4 @@ def _close_if_retired(rc: ResidentCheckpoint) -> None:
                       exc_info=True)
     rc.tree = None
     rc.shards = {}
+    rc.device = None

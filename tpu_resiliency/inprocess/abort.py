@@ -384,6 +384,7 @@ class ShrinkMeshStage(AbortStage):
         import jax
         import jax.extend.backend as jeb  # lazy submodule
 
+        from ..checkpointing.async_ckpt import resident as resident_mod
         from ..parallel import distributed as dist_mod
 
         detail = []
@@ -395,7 +396,10 @@ class ShrinkMeshStage(AbortStage):
         jax.clear_caches()
         # the full reset: clearing compiled caches is NOT enough —
         # jax.distributed refuses re-init while backends are live, so the
-        # backends must go too
+        # backends must go too.  Every device array of the process goes with
+        # them: a committed checkpoint generation must stop serving restores
+        # from its snapshot slot first (its shm part stays)
+        resident_mod.unpublish_device()
         jeb.clear_backends()
         detail.append("caches+backends cleared")
         # reset the bootstrap helper so the next iteration's initialize
