@@ -1,16 +1,27 @@
 """Reference workloads the resiliency layer wraps and benchmarks against."""
 
-from . import kimi_linear, kimi_linear_reference, qwen3_next, qwen3_next_reference
+from . import (
+    keye_vl2,
+    keye_vl2_reference,
+    kimi_linear,
+    kimi_linear_reference,
+    qwen3_next,
+    qwen3_next_reference,
+)
+from .keye_vl2 import KeyeVL2Config
 from .kimi_linear import KimiLinearConfig
 from .qwen3_next import Qwen3NextConfig
 from .transformer import TransformerConfig, init_params, forward, loss_fn, make_train_step
 
 __all__ = [
+    "KeyeVL2Config",
     "KimiLinearConfig",
     "Qwen3NextConfig",
     "TransformerConfig",
     "forward",
     "init_params",
+    "keye_vl2",
+    "keye_vl2_reference",
     "kimi_linear",
     "kimi_linear_reference",
     "loss_fn",

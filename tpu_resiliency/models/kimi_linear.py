@@ -381,15 +381,20 @@ def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig):
     # The backward pass computes the taken size again and differentiates that
     # (a custom rule: differentiated as written, ``switch`` would keep every
     # size's intermediates, and a layer's buffers would outlive the layer).
+    # ``order``, ``sizes`` and ``rung`` are arguments and not closed over, so
+    # that the rule can be staged: inside a ``scan`` or a ``checkpoint`` the
+    # backward rule is traced after the forward trace's values are gone.
     @jax.custom_vjp
-    def run(x, experts, mine):
+    def run(x, experts, mine, order, sizes, rung):
         return jax.lax.switch(rung, [with_rows(rows) for rows in ladder],
                               x, experts, order, sizes, mine)
 
-    def run_fwd(x, experts, mine):
-        return run(x, experts, mine), (x, experts, mine)
+    def run_fwd(x, experts, mine, order, sizes, rung):
+        return run(x, experts, mine, order, sizes, rung), (x, experts, mine, order, sizes, rung)
 
     def run_bwd(saved, ct):
+        *diff, order, sizes, rung = saved
+
         def back(rows):
             def pull(x, experts, mine, ct):
                 return jax.vjp(lambda x, experts, mine: with_rows(rows)(
@@ -397,10 +402,11 @@ def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig):
 
             return pull
 
-        return jax.lax.switch(rung, [back(rows) for rows in ladder], *saved, ct)
+        return (*jax.lax.switch(rung, [back(rows) for rows in ladder], *diff, ct),
+                None, None, None)
 
     run.defvjp(run_fwd, run_bwd)
-    return run(x, experts, mine)
+    return run(x, experts, mine, order, sizes, rung)
 
 
 def moe_block(x, p, bias, cfg: KimiLinearConfig):
