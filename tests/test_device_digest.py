@@ -21,6 +21,7 @@ Four properties anchor the zero-stall save path:
 """
 
 import contextlib
+import gc
 import glob
 import json
 import os
@@ -50,6 +51,7 @@ from tpu_resiliency.checkpointing.async_ckpt.peer_source import (
 )
 from tpu_resiliency.checkpointing.local.replication import PeerExchange
 from tpu_resiliency.store import StoreClient
+from tpu_resiliency.telemetry import get_registry
 
 
 @pytest.fixture(autouse=True)
@@ -293,11 +295,125 @@ class TestDigestDisagreement:
 # -- double-buffered snapshot ring -------------------------------------------
 
 
+def _slots_taken(since=None):
+    """``tpurx_ckpt_snap_slot_total`` by outcome, less an earlier reading."""
+    now = {
+        outcome: get_registry().value_of(
+            "tpurx_ckpt_snap_slot_total", {"outcome": outcome})
+        for outcome in ("reused", "fresh")
+    }
+    return now if since is None else {k: now[k] - since[k] for k in now}
+
+
+def _copy_sees_the_slot_released(monkeypatch, stale):
+    """Wrap the snapshot copy: when it is dispatched, ``stale`` (a list the
+    test fills) must hold deleted arrays only.  Returns the dispatch count."""
+    real, calls = ckpt_mod._SNAP_FN, []
+
+    def checked(xs):
+        assert all(leaf.is_deleted() for leaf in stale)
+        calls.append(len(stale))
+        return real(xs)
+
+    monkeypatch.setattr(ckpt_mod, "_SNAP_FN", checked)
+    return calls
+
+
+def _live_bytes(known):
+    """Bytes of the live device buffers under arrays that ``known`` (ids)
+    does not name; a leaf and its ``addressable_shards[0].data`` are two
+    arrays over one buffer."""
+    gc.collect()
+    return sum({
+        a.unsafe_buffer_pointer(): a.nbytes
+        for a in jax.live_arrays() if id(a) not in known
+    }.values())
+
+
+def _tree_bytes(tree):
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def _dense_tree(i):
+    k = jax.random.PRNGKey(i)
+    w = jax.random.normal(k, (96, 128), jnp.float32)
+    return {"params": {"w": w.astype(jnp.bfloat16)},
+            "opt": {"master": w, "mu": w * 0.1, "nu": w * w}}
+
+
+def _routed_tree(i):
+    """The routed families' leaf kinds at CPU size: a float32-only leaf, an
+    int32 buffer no gradient touches and a scalar count beside the usual
+    bfloat16 parameter with its float32 shadow."""
+    tree = _dense_tree(i)
+    tree["params"]["A_log"] = jnp.linspace(0.0, 1.0, 16) + i
+    tree["opt"]["load"] = jnp.arange(4 * 64, dtype=jnp.int32).reshape(4, 64) + i
+    tree["opt"]["count"] = jnp.asarray(i, jnp.int32)
+    return tree
+
+
 class TestSnapshotRing:
+    @pytest.mark.parametrize("make", [_dense_tree, _routed_tree],
+                             ids=["dense", "routed"])
+    def test_reusing_save_takes_over_the_drained_slot(
+            self, make, tmp_path, monkeypatch):
+        """A later save's copy gets the memory of the drained slot it took:
+        the stale leaves are released before the copy is dispatched, so the
+        process holds the live tree and ONE slot at every instant, not
+        two."""
+        known = {id(a) for a in jax.live_arrays()}
+        trees = [make(i) for i in range(2)]
+        d = str(tmp_path)
+        # no digest, no resident copy: the slot's leaves are then the only
+        # device arrays the checkpointer makes
+        ck = AsyncCheckpointer(digest=False, resident=False,
+                               stage_mode="snapshot", stage_buffers=2)
+        try:
+            before = _slots_taken()
+            ck.save(trees[0], d + "/g0", {"iteration": 0})
+            taken = list(ck._snap_ring[0]["leaves"])
+            at_rest = _live_bytes(known)
+            assert at_rest == _tree_bytes(trees) + _tree_bytes(trees[0])
+            calls = _copy_sees_the_slot_released(monkeypatch, taken)
+            ck.save(trees[1], d + "/g1", {"iteration": 1})
+            assert calls == [len(taken)]  # one program, the first save's
+            assert ck.snap_ring_stats == {"reused": 1, "fresh": 1}
+            (slot,) = ck._snap_ring
+            assert not any(leaf.is_deleted() for leaf in slot["leaves"])
+            assert _live_bytes(known) == at_rest
+            assert _slots_taken(before) == {"reused": 1, "fresh": 1}
+        finally:
+            ck.close()
+        for i in range(2):
+            out = load_checkpoint(d + f"/g{i}", trees[0], resident=False)
+            assert_trees_equal(out, trees[i])
+
+    def test_a_host_view_of_a_released_slot_leaf_stays_valid(self, tmp_path):
+        """Somebody still holds a host view of a stale leaf (the CPU
+        backend's ``np.asarray`` is zero-copy) when the next save releases
+        the slot: the view keeps its bytes, and the save is right."""
+        trees = [_routed_tree(i) for i in range(2)]
+        d = str(tmp_path)
+        ck = AsyncCheckpointer(digest=False, resident=False,
+                               stage_mode="snapshot", stage_buffers=2)
+        try:
+            ck.save(trees[0], d + "/g0", {"iteration": 0})
+            taken = ck._snap_ring[0]["leaves"][0]
+            held = np.asarray(taken)  # an outside reference to its buffer
+            ck.save(trees[1], d + "/g1", {"iteration": 1})
+            assert ck.snap_ring_stats == {"reused": 1, "fresh": 1}
+            assert taken.is_deleted()
+            assert held.tobytes() == np.asarray(
+                jax.tree_util.tree_leaves(trees[0])[0]).tobytes()
+        finally:
+            ck.close()
+        out = load_checkpoint(d + "/g1", trees[0], resident=False)
+        assert_trees_equal(out, trees[1])
+
     def test_slow_drain_never_reuses_a_live_slot(self, tmp_path, monkeypatch):
         """Inject a slow D2H: with staging stalled, a rapid second save must
         take a FRESH buffer set (the fence holds); once drained, the next
-        save donates a slot. Every generation restores byte-identically —
+        save takes a slot over. Every generation restores byte-identically —
         the second snapshot never clobbered the first's device buffers."""
         real_stage = ckpt_mod.stage_pytree
         release = threading.Event()
@@ -308,24 +424,35 @@ class TestSnapshotRing:
 
         monkeypatch.setattr(ckpt_mod, "stage_pytree", slow_stage)
         d = str(tmp_path)
-        ck = AsyncCheckpointer(digest=True, stage_mode="snapshot",
-                               stage_buffers=2)
+        known = {id(a) for a in jax.live_arrays()}
+        # no resident copy: no seal, so tree and slots are all that is live
+        ck = AsyncCheckpointer(digest=True, resident=False,
+                               stage_mode="snapshot", stage_buffers=2)
         try:
             trees = [
                 {"w": jnp.full((512,), float(i), jnp.float32),
                  "b": jnp.arange(64, dtype=jnp.int32) + i}
                 for i in range(3)
             ]
+            before = _slots_taken()
             ck.async_save(trees[0], d + "/g0", {"iteration": 0})
             ck.async_save(trees[1], d + "/g1", {"iteration": 1})
             # both issued while staging was stalled: no slot was donatable
             assert ck.snap_ring_stats == {"reused": 0, "fresh": 2}
             release.set()
             ck.finalize_all()
+            taken = list(ck._snap_ring[0]["leaves"])
+            calls = _copy_sees_the_slot_released(monkeypatch, taken)
             ck.async_save(trees[2], d + "/g2", {"iteration": 2})
             ck.finalize_all()
-            # drained ring: the third save donated a slot instead
+            # drained ring: the third save took a slot over instead, ...
             assert ck.snap_ring_stats["reused"] == 1
+            # ... for real: its leaves were released before the copy was
+            # dispatched, so the ring still holds two slots' bytes, not three
+            assert calls == [len(taken)]
+            assert _live_bytes(known) == (
+                _tree_bytes(trees) + 2 * _tree_bytes(trees[0]))
+            assert _slots_taken(before) == {"reused": 1, "fresh": 2}
         finally:
             release.set()
             ck.close()

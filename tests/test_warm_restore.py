@@ -483,20 +483,33 @@ def _the_next_save_takes_the_slot(d, cp, tree, sealed_save, monkeypatch):
     the one pooled staging tree too: the old generation keeps neither part,
     and the new one is served from the slot it was bound to."""
     real = cp._ring_snapshot
+    taken = list(resident_mod.lookup(d).device.leaves)
 
     def popped_first(*args):
         out = real(*args)
         # the call has the slot; the stager has not yet taken the shm tree
         assert resident_mod.lookup(d).device is None
         assert resident_mod.lookup(d).buffers()
+        # the slot's memory was released to the new save's copy: a restore
+        # of the committed generation between this call and its commit
+        # starts at shm, and was never shown the deleted arrays
+        assert all(leaf.is_deleted() for leaf in taken)
+        before = _rejected("deleted"), _source_bytes("shm")
+        stats = {}
+        assert_trees_equal(tree, load_checkpoint(d, tree, stats=stats))
+        assert stats["bytes_device"] == 0
+        assert stats["bytes_shm"] == stats["bytes_read"] == _device_bytes(tree)
+        assert _rejected("deleted") == before[0]
+        assert _source_bytes("shm") - before[1] == _device_bytes(tree)
         return out
 
     monkeypatch.setattr(cp, "_ring_snapshot", popped_first)
-    d2, _ = sealed_save(tree, name="next", cp=cp)
+    later = jax.tree_util.tree_map(lambda x: x + 1, tree)  # other bytes
+    d2, _ = sealed_save(later, name="next", cp=cp)
     assert cp.snap_ring_stats["reused"] == 1
     assert resident_mod.lookup(d2).device.slot is cp._snap_ring[-1]
     stats = {}
-    assert_trees_equal(tree, load_checkpoint(d2, tree, stats=stats))
+    assert_trees_equal(later, load_checkpoint(d2, tree, stats=stats))
     assert stats["bytes_device"] == _device_bytes(tree)
     return "disk"
 

@@ -106,6 +106,13 @@ _SNAP_RING_BYTES = gauge(
     "tpurx_ckpt_snap_ring_bytes",
     "Device bytes held by the live slots of the snapshot ring",
 )
+_SNAP_SLOT = counter(
+    "tpurx_ckpt_snap_slot_total",
+    "Snapshot ring slots taken by a save: reused = a drained slot's memory "
+    "was released to this save's copy (the ring did not grow), fresh = no "
+    "drained slot of the plan signature, so the copy allocated a new one",
+    labels=("outcome",),
+)
 _DEVICE_REJECTED = counter(
     "tpurx_ckpt_restore_device_rejected_total",
     "Times the restore's device rung declined: seal = a restore whose copies' "
@@ -151,7 +158,30 @@ _LOAD_SEQ = itertools.count(1)
 
 
 _SNAP_FN = None
-_SNAP_DONATE_FN = None
+
+
+def _copy_leaves(
+    leaves: List[Any], dev_idx: List[int]
+) -> Tuple[List[Any], List[Any]]:
+    """``leaves`` with every jax.Array (positions ``dev_idx``) copied into
+    fresh device buffers by one jitted dispatch and every host ndarray
+    np.copy'd; also the device copies alone, in ``dev_idx`` order."""
+    import jax
+    import jax.numpy as jnp
+
+    global _SNAP_FN
+    copies: List[Any] = []
+    if dev_idx:
+        if _SNAP_FN is None:
+            _SNAP_FN = jax.jit(lambda xs: [jnp.copy(x) for x in xs])
+        copies = list(_SNAP_FN([leaves[i] for i in dev_idx]))
+    by_pos = dict(zip(dev_idx, copies))
+    out = [
+        by_pos[i] if i in by_pos
+        else (l.copy() if isinstance(l, np.ndarray) else l)
+        for i, l in enumerate(leaves)
+    ]
+    return out, copies
 
 
 def device_snapshot(tree: Any) -> Any:
@@ -160,23 +190,10 @@ def device_snapshot(tree: Any) -> Any:
     execute on the device stream ahead of any later-dispatched step, so the
     snapshot is consistent even when the training step donates its inputs."""
     import jax
-    import jax.numpy as jnp
 
-    global _SNAP_FN
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     dev_idx = [i for i, l in enumerate(leaves) if isinstance(l, jax.Array)]
-    if dev_idx:
-        if _SNAP_FN is None:
-            _SNAP_FN = jax.jit(lambda xs: [jnp.copy(x) for x in xs])
-        copies = _SNAP_FN([leaves[i] for i in dev_idx])
-        for slot, c in zip(dev_idx, copies):
-            leaves[slot] = c
-    dev_set = set(dev_idx)
-    out = [
-        l if i in dev_set else (l.copy() if isinstance(l, np.ndarray) else l)
-        for i, l in enumerate(leaves)
-    ]
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return jax.tree_util.tree_unflatten(treedef, _copy_leaves(leaves, dev_idx)[0])
 
 
 @dataclasses.dataclass
@@ -285,7 +302,7 @@ class AsyncCheckpointer:
         self.device_digest = device_digest
         # device-side snapshot ring depth (None = env TPURX_CKPT_STAGE_BUFFERS,
         # default 2): snapshot-mode saves rotate through this many device
-        # buffer sets, donating a slot back only once its staging drained
+        # buffer sets, taking a slot over only once its staging drained
         self.stage_buffers = stage_buffers
         # previous committed generation's chunk index, for delta matching:
         # {"sig": plan_sig, "chunks": {(leaf, shard): {(off, len):
@@ -314,7 +331,7 @@ class AsyncCheckpointer:
         self.last_stage_mode: Optional[str] = None
         # snapshot ring: {"sig", "leaves" (device arrays), "dev_idx" (their
         # positions in the flattened tree), "seal", "job"} slots; a slot is
-        # reusable (its buffers donatable) only once its job's staging has
+        # reusable (its buffers releasable) only once its job's staging has
         # drained — job.done is the D2H-consumed fence.  A slot leaves the
         # ring through _drop_slot alone: a committed generation may be
         # serving restores from it (resident.py, "device part")
@@ -511,12 +528,15 @@ class AsyncCheckpointer:
 
     def _ring_snapshot(self, tree: Any, sig: str) -> Tuple[Any, Optional[Dict]]:
         """Device snapshot through the double-buffered ring: with
-        ``stage_buffers >= 2``, the copy DONATES a previous slot's device
-        buffers (same plan signature) instead of allocating fresh ones — but
-        only a slot whose staging job already drained, so the next step's
+        ``stage_buffers >= 2``, the copy takes over the memory of a previous
+        slot (same plan signature) instead of growing the ring — but only a
+        slot whose staging job already drained, so the next step's
         compute/snapshot overlaps the previous slice's D2H without ever
         overwriting bytes still in flight (``job.done`` is the fence,
         sequenced by the committed-generation protocol in ``resident.py``).
+        The drained slot's buffers are released BEFORE the copy is
+        dispatched, so at no point of a save more than the live state and
+        one slot per ring position in use are allocated.
 
         Returns ``(snapshot_tree, slot)``; the caller binds the new slot to
         its staging job and appends it to the ring.  ``stage_buffers <= 1``
@@ -524,9 +544,7 @@ class AsyncCheckpointer:
         if self._ring_cap() <= 1:
             return device_snapshot(tree), None
         import jax
-        import jax.numpy as jnp
 
-        global _SNAP_FN, _SNAP_DONATE_FN
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         dev_idx = [i for i, l in enumerate(leaves) if isinstance(l, jax.Array)]
         slot = None
@@ -538,32 +556,17 @@ class AsyncCheckpointer:
                         slot = self._drop_slot(i)
                         self._note_ring_bytes()
                         break
-        copies: List[Any] = []
-        if dev_idx:
-            new_dev = [leaves[i] for i in dev_idx]
             if slot is not None:
-                if _SNAP_DONATE_FN is None:
-                    # donating the stale slot lets XLA alias the copy's
-                    # outputs into those buffers: steady state allocates
-                    # zero new device memory per snapshot
-                    _SNAP_DONATE_FN = jax.jit(
-                        lambda old, new: [jnp.copy(x) for x in new],
-                        donate_argnums=(0,),
-                    )
-                copies = _SNAP_DONATE_FN(slot["leaves"], new_dev)
-                self.snap_ring_stats["reused"] += 1
-            else:
-                if _SNAP_FN is None:
-                    _SNAP_FN = jax.jit(lambda xs: [jnp.copy(x) for x in xs])
-                copies = _SNAP_FN(new_dev)
-                self.snap_ring_stats["fresh"] += 1
-            for i, c in zip(dev_idx, copies):
-                leaves[i] = c
-        dev_set = set(dev_idx)
-        out = [
-            l if i in dev_set else (l.copy() if isinstance(l, np.ndarray) else l)
-            for i, l in enumerate(leaves)
-        ]
+                # hand the stale slot's memory back first: the allocator
+                # gives it to the copy's outputs.  (Donating it to the copy
+                # instead makes the TPU compiler write the largest leaves
+                # twice, through HBM temporaries: PERF.md, PR 38.)
+                for leaf in slot["leaves"]:
+                    leaf.delete()
+            outcome = "fresh" if slot is None else "reused"
+            self.snap_ring_stats[outcome] += 1
+            _SNAP_SLOT.labels(outcome=outcome).inc()
+        out, copies = _copy_leaves(leaves, dev_idx)
         seal = None
         if copies and self._seals_slots():
             from . import device_digest as device_digest_mod

@@ -40,7 +40,7 @@ shm -> peers -> disk).  The same three steps, applied to slots:
   slot.  It is taken before the stager's D2H reads the slot, so it vouches
   for the bytes the committed index's crcs vouch for; a restore
   fingerprints its copies against it on the device and fails closed.
-- **invalidate-on-reuse**: the moment the ring pops a slot to donate it,
+- **invalidate-on-reuse**: the moment the ring pops a slot to reuse it,
   evicts one, or is cleared (``close()``), and wherever the process's
   backends are really cleared (``ShrinkMeshStage``: every device array is
   gone), :func:`unpublish_device` drops the device part first.  The shm
@@ -209,7 +209,7 @@ def invalidate_tree(tree: Any) -> None:
 
 def unpublish_device(slot: Optional[Dict[str, Any]] = None) -> None:
     """Drop the device part of every generation bound to ``slot`` (of every
-    generation, without one) — the slot's buffers are about to be donated,
+    generation, without one) — the slot's buffers are about to be released,
     dropped or lost.  The shm part stays published."""
     with _LOCK:
         for rc in _BY_DIR.values():

@@ -362,8 +362,9 @@ CKPT_STAGE_BUFFERS = Knob(
     "TPURX_CKPT_STAGE_BUFFERS", int, 2,
     "Device-side snapshot slots of the async-save ring (snapshot stage "
     "mode): with >=2, the next step's snapshot reuses a slot whose staging "
-    "already drained (donated buffers) so compute overlaps the previous "
-    "slice's D2H; 1 restores the single-copy behavior.",
+    "already drained (its memory released to the new copy) so compute "
+    "overlaps the previous slice's D2H; 1 restores the single-copy "
+    "behavior.",
     group="checkpoint")
 CKPT_PEER_STREAMS = Knob(
     "TPURX_CKPT_PEER_STREAMS", int, 4,
