@@ -27,6 +27,14 @@ SAVE_INTERVALS = ("ckpt.save", "ckpt.save.prepare", "ckpt.save.snapshot",
                   "ckpt.drain")
 LOAD_INTERVALS = ("ckpt.load", "ckpt.load.plan", "ckpt.load.start",
                   "ckpt.load.wait", "ckpt.load.place", "ckpt.load.release")
+INNER_RING_INTERVALS = (
+    "inproc.coalesce", "inproc.abort", "inproc.abort.on_trip",
+    "inproc.abort.ladder", "inproc.abort.stage", "flight.dump.write",
+    "flight.dump.hooks", "inproc.raise", "inproc.restart",
+    "inproc.restart.abort_wait", "inproc.restart.finalize",
+    "inproc.restart.health_check", "inproc.restart.iteration_barrier",
+    "inproc.restart.reassign", "inproc.restart.collect",
+    "inproc.restart.rearm", "inproc.restart.initialize")
 PARENT_OF = {
     "ckpt.save.prepare": "ckpt.save", "ckpt.save.snapshot": "ckpt.save",
     "ckpt.save.handoff": "ckpt.save", "ckpt.stage.d2h": "ckpt.stage",
@@ -178,11 +186,11 @@ def test_every_interval_is_a_span_pair_of_the_trace_cli():
     """``telemetry/trace.py`` renders an interval only if ``SPAN_PAIRS``
     names its pair; the span carries the interval's own name."""
     import tpu_resiliency.checkpointing.async_ckpt.checkpointer  # noqa: F401
-    import tpu_resiliency.inprocess.monitor_thread  # noqa: F401
+    import tpu_resiliency.inprocess.wrap  # noqa: F401  (monitor_thread, abort)
 
     product = [iv for iv in flight.intervals() if not iv.name.startswith("test.")]
     assert {iv.name for iv in product} >= set(
-        SAVE_INTERVALS + LOAD_INTERVALS + ("inproc.coalesce",))
+        SAVE_INTERVALS + LOAD_INTERVALS + INNER_RING_INTERVALS)
     for iv in product:
         end, name, _cat = trace.SPAN_PAIRS[iv.begin_event]
         assert end == iv.end_event
@@ -223,8 +231,9 @@ def test_exit_dump_only_where_a_directory_is_named(tmp_path, env, dumped):
         assert records[0]["event"] == "_flight_meta"
         assert records[0]["reason"] == "exit"
         assert {"mono_ns", "ts", "events", "capacity"} <= set(records[0])
+        # the dump's own write has begun and not ended: the next dump has it
         assert [r["event"] for r in records[1:] if "ident" in r] == [
-            "child.work_begin", "child.work_end"]
+            "child.work_begin", "child.work_end", "flight.dump.write_begin"]
 
 
 # ---- the checkpoint's intervals ----------------------------------------------
@@ -411,7 +420,9 @@ def test_trace_cli_renders_the_new_pairs_as_complete_spans(tmp_path):
     assert {s["args"]["ident"] for s in by_name["ckpt_drain"]} == {1, 2}
     for name in LOAD_INTERVALS:
         assert by_name[name], name
-    assert not [e for e in events if "(unfinished)" in e.get("name", "")]
+    # nothing is left open but the write of the very dump that was rendered
+    assert [e["name"] for e in events if "(unfinished)" in e.get("name", "")] == [
+        "flight.dump.write (unfinished)"]
     save = by_name["ckpt.save"][0]
     child = next(s for s in by_name["ckpt.save.snapshot"]
                  if s["args"]["ident"] == save["args"]["ident"])
@@ -424,5 +435,6 @@ def test_a_begin_without_its_end_shows_where_it_was_stuck(tmp_path):
     flight.begin(IV_OUTER, 9)
     dump = flight.dump("stuck", path=str(tmp_path / "dump.jsonl"))
     records = [json.loads(line) for line in open(dump)]
-    assert [r["event"] for r in records if r.get("ident") == 9] == [
+    assert [r["event"] for r in records
+            if r.get("ident") == 9 and r["event"].startswith("test.")] == [
         "test.outer_begin"]

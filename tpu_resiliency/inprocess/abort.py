@@ -56,6 +56,18 @@ log = get_logger("inproc.abort")
 
 EV_LADDER = flight.declare_event("abort.ladder", "name")
 EV_STAGE = flight.declare_event("abort.stage", "stage", "outcome", "dur_ms")
+# the ladder on the clock of every other interval.  ladder: around the
+# monitor thread's abort_fn (the wait for the wrapper's atomic lock included),
+# recorded in monitor_thread.py; stage: a rung's worker thread started ->
+# joined or left at its deadline, one per rung that ran.  ident = the
+# wrapper's (faulted) iteration, or the ladder's name and run number where it
+# runs for no wrapper (the degrade ladder)
+IV_LADDER = flight.declare_interval(
+    "inproc.abort.ladder_begin", "inproc.abort.ladder_end"
+)
+IV_STAGE = flight.declare_interval(
+    "inproc.abort.stage_begin", "inproc.abort.stage_end", "stage"
+)
 
 _STAGE_OUTCOMES = counter(
     "tpurx_abort_stage_outcomes_total",
@@ -167,6 +179,11 @@ class AbortLadder:
         self.name = name
         self.last_results: List[StageResult] = []
         self._lock = threading.Lock()
+        self._runs = 0
+
+    def _ident(self, state):
+        iteration = getattr(state, "iteration", None)
+        return f"{self.name}.{self._runs}" if iteration is None else iteration
 
     def _run_stage(self, stage: AbortStage, state) -> StageResult:
         box = {}
@@ -183,8 +200,9 @@ class AbortLadder:
         worker = threading.Thread(
             target=body, name=f"tpurx-abort-{stage.name}", daemon=True
         )
-        worker.start()
-        worker.join(timeout=stage.timeout)
+        with flight.span(IV_STAGE, self._ident(state), IV_LADDER, stage.name):
+            worker.start()
+            worker.join(timeout=stage.timeout)
         dur_ms = (time.monotonic_ns() - t0) / 1e6
         if worker.is_alive():
             return StageResult(stage.name, TIMED_OUT, dur_ms,
@@ -199,6 +217,7 @@ class AbortLadder:
     def __call__(self, state=None):
         with self._lock:  # one abort episode at a time per wrapper
             _LADDER_RUNS.inc()
+            self._runs += 1
             flight.record(EV_LADDER, self.name)
             # entering the ladder: mark the live episode's abort phase (the
             # degrade ladder runs outside any episode — phase() is a no-op

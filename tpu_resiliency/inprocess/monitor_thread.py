@@ -25,6 +25,7 @@ from typing import Callable, Iterable, List, Optional
 
 from ..telemetry import counter, flight, histogram
 from ..utils.logging import get_logger
+from .abort import IV_LADDER
 from .attribution import InterruptionRecord
 from .exceptions import RankShouldRestart
 from .store_ops import InprocStore
@@ -32,10 +33,20 @@ from .store_ops import InprocStore
 log = get_logger("monitor_thread")
 
 EV_TRIP = flight.declare_event("monitor.trip", "iteration", "interruptions")
-# the coalescing window; ident = the iteration
+# the monitor thread from the wake to the raise's landing; ident = the
+# (faulted) iteration on every one.  coalesce: the coalescing window
 IV_COALESCE = flight.declare_interval(
     "inproc.coalesce_begin", "inproc.coalesce_end"
 )
+# the window closed -> abort_done set; its children are on_trip (episode mint
+# through the store, the trip's black box) and abort.IV_LADDER (abort_fn)
+IV_ABORT = flight.declare_interval("inproc.abort_begin", "inproc.abort_end")
+IV_ON_TRIP = flight.declare_interval(
+    "inproc.abort.on_trip_begin", "inproc.abort.on_trip_end"
+)
+# the first async raise scheduled (this thread) -> the wrapper's mark_caught
+# (the main thread): begin/end across threads, so no TraceAnnotation
+IV_RAISE = flight.declare_interval("inproc.raise_begin", "inproc.raise_end")
 
 _TRIPS = counter(
     "tpurx_monitor_trips_total",
@@ -118,6 +129,7 @@ class MonitorThread:
         # async-exc slot — quiesce_raises() cancels that one)
         self._raise_lock = threading.Lock()
         self._trip_ns: Optional[int] = None
+        self._raise_begun = False  # under _raise_lock, like _trip_ns
         self.tripped = threading.Event()
         # set once the abort ladder/plugin has RUN (tripped only means the
         # trip was observed — with staged abort the duties take real time,
@@ -138,28 +150,31 @@ class MonitorThread:
         if self._stop.is_set():
             return
         records = self._coalesce()
-        log.warning(
-            "iteration %s interrupted: %s",
-            self.iteration,
-            [(r.rank, r.interruption.value) for r in records],
-        )
-        _TRIPS.inc()
-        flight.record(
-            EV_TRIP, self.iteration,
-            ",".join(f"{r.rank}:{r.interruption.value}" for r in records),
-        )
-        self._trip_ns = time.monotonic_ns()
-        self.tripped.set()
-        if self.on_trip:
-            try:
-                self.on_trip()
-            except Exception:  # noqa: BLE001
-                log.exception("on_trip callback failed")
-        if self.abort_fn is not None:
-            try:
-                self.abort_fn()
-            except Exception:  # noqa: BLE001
-                log.exception("abort plugin failed")
+        with flight.span(IV_ABORT, self.iteration):
+            log.warning(
+                "iteration %s interrupted: %s",
+                self.iteration,
+                [(r.rank, r.interruption.value) for r in records],
+            )
+            _TRIPS.inc()
+            flight.record(
+                EV_TRIP, self.iteration,
+                ",".join(f"{r.rank}:{r.interruption.value}" for r in records),
+            )
+            self._trip_ns = time.monotonic_ns()
+            self.tripped.set()
+            if self.on_trip:
+                with flight.span(IV_ON_TRIP, self.iteration, IV_ABORT):
+                    try:
+                        self.on_trip()
+                    except Exception:  # noqa: BLE001
+                        log.exception("on_trip callback failed")
+            if self.abort_fn is not None:
+                with flight.span(IV_LADDER, self.iteration, IV_ABORT):
+                    try:
+                        self.abort_fn()
+                    except Exception:  # noqa: BLE001
+                        log.exception("abort plugin failed")
         self.abort_done.set()
         # raise into the main thread until the wrapper acknowledges — first
         # raise immediately (a 0.5s pre-wait would put a flat half-second on
@@ -171,6 +186,9 @@ class MonitorThread:
             with self._raise_lock:
                 if self._caught.is_set():
                     return
+                if not self._raise_begun:
+                    self._raise_begun = True
+                    flight.begin(IV_RAISE, self.iteration)
                 async_raise(self.main_tid, RankShouldRestart)
             if self._caught.wait(timeout=0.5):
                 return
@@ -204,8 +222,11 @@ class MonitorThread:
         with self._raise_lock:
             self._caught.set()
             trip_ns, self._trip_ns = self._trip_ns, None
+            raised, self._raise_begun = self._raise_begun, False
         if trip_ns is not None:
             _TRIP_TO_CAUGHT_NS.observe(time.monotonic_ns() - trip_ns)
+            if raised:
+                flight.end(IV_RAISE, self.iteration)
 
     def quiesce_raises(self) -> None:
         """Deterministically absorb any async raise still in flight.

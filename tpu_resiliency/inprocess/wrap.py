@@ -29,6 +29,7 @@ from ..store.client import StoreClient, StoreError, StoreTimeout, store_from_env
 from ..policy.ledger import ledger
 from ..telemetry import counter, flight, histogram
 from ..telemetry import episode as episode_mod
+from ..telemetry.clock import mono_ns
 from ..utils import env
 from ..utils.logging import get_logger
 from ..utils.profiling import ProfilingEvent, record_event
@@ -94,11 +95,102 @@ _RESTART_NS = histogram(
 )
 
 
-def _observe_phase(phase: str, t0_ns: int) -> int:
-    """Record one restart phase; returns a fresh stamp for the next one."""
-    now = time.monotonic_ns()
-    _PHASE_NS.labels(phase).observe(now - t0_ns)
-    return now
+# the restart path on the main thread, from the fault caught to the wrapped fn
+# about to be called again; ident = the faulted iteration (kept after
+# state.advance()).  Its eight children follow one another without a gap:
+# _RestartClock ends one and begins the next from one stamp
+IV_RESTART = flight.declare_interval(
+    "inproc.restart_begin", "inproc.restart_end"
+)
+_IV_PHASE = {
+    "abort_wait": flight.declare_interval(
+        "inproc.restart.abort_wait_begin", "inproc.restart.abort_wait_end"
+    ),
+    "finalize": flight.declare_interval(
+        "inproc.restart.finalize_begin", "inproc.restart.finalize_end"
+    ),
+    "health_check": flight.declare_interval(
+        "inproc.restart.health_check_begin", "inproc.restart.health_check_end"
+    ),
+    "iteration_barrier": flight.declare_interval(
+        "inproc.restart.iteration_barrier_begin",
+        "inproc.restart.iteration_barrier_end",
+    ),
+    "reassign": flight.declare_interval(
+        "inproc.restart.reassign_begin", "inproc.restart.reassign_end"
+    ),
+    "collect": flight.declare_interval(
+        "inproc.restart.collect_begin", "inproc.restart.collect_end"
+    ),
+    "rearm": flight.declare_interval(
+        "inproc.restart.rearm_begin", "inproc.restart.rearm_end"
+    ),
+    "initialize": flight.declare_interval(
+        "inproc.restart.initialize_begin", "inproc.restart.initialize_end"
+    ),
+}
+
+
+class _RestartClock:
+    """The restart path's one set of stamps.  Each boundary reads the
+    recorder's clock once: that stamp closes the phase in
+    ``tpurx_restart_phase_latency_ns``, ends its ``inproc.restart.<phase>``
+    interval and begins the next one's, so histogram and ring cannot disagree;
+    the last one also ends ``inproc.restart`` and feeds
+    ``tpurx_restart_total_latency_ns``.  Main thread only.  A restart that
+    leaves the path early (job completed, ``RestartAbort``) leaves its begins
+    open in the ring: where it stopped."""
+
+    def __init__(self) -> None:
+        self.ident: Optional[int] = None
+        self.started_ns = 0
+        self._phase: Optional[str] = None
+        self._phase_ns = 0
+        self._annotations: list = []  # IV_RESTART's, then the open phase's
+
+    @property
+    def running(self) -> bool:
+        return self._phase is not None
+
+    def _open(self, iv, parent, now: int) -> None:
+        flight.begin(iv, self.ident, parent, at_ns=now)
+        self._annotations.append(flight.annotation(iv))
+
+    def _close(self, iv, parent, now: int) -> None:
+        entered = self._annotations.pop()
+        if entered is not None:
+            entered.__exit__(None, None, None)
+        flight.end(iv, self.ident, parent, at_ns=now)
+
+    def start(self, ident: int) -> int:
+        """Open ``inproc.restart`` and its first phase; returns the stamp."""
+        self.abandon()
+        now = self.started_ns = self._phase_ns = mono_ns()
+        self.ident, self._phase = ident, "abort_wait"
+        self._open(IV_RESTART, None, now)
+        self._open(_IV_PHASE[self._phase], IV_RESTART, now)
+        return now
+
+    def next(self, phase: Optional[str]) -> None:
+        """End the open phase and begin ``phase``; with None, end
+        ``inproc.restart`` too: the wrapped fn is about to be called."""
+        now = mono_ns()
+        _PHASE_NS.labels(self._phase).observe(now - self._phase_ns)
+        self._close(_IV_PHASE[self._phase], IV_RESTART, now)
+        self._phase, self._phase_ns = phase, now
+        if phase is not None:
+            self._open(_IV_PHASE[phase], IV_RESTART, now)
+        else:
+            self._close(IV_RESTART, None, now)
+            _RESTART_NS.observe(now - self.started_ns)
+
+    def abandon(self) -> None:
+        """Leave whatever is open as it is in the ring; exit its annotations."""
+        while self._annotations:
+            entered = self._annotations.pop()
+            if entered is not None:
+                entered.__exit__(None, None, None)
+        self._phase = None
 
 
 class Wrapper:
@@ -221,6 +313,7 @@ class CallWrapper:
         self._accepts_cw = "call_wrapper" in inspect.signature(fn).parameters
         # stamp of the last fault, cleared when the restarted fn re-enters
         self._restart_started_ns: Optional[int] = None
+        self._restart_clock = _RestartClock()
         # (fault_class, rung, episode_id) of the restart episode in flight;
         # closed into the policy rung ledger when the restarted fn re-enters
         self._episode: Optional[tuple] = None
@@ -371,6 +464,7 @@ class CallWrapper:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        self._restart_clock.abandon()
         flight.remove_dump_hook(self._analyze_dump_hook)
         if self._clock_ref is not None:
             self._clock_ref.stop()
@@ -398,6 +492,7 @@ class CallWrapper:
         w = self.w
         state = self.state
         main_tid = threading.get_ident()
+        restart_clock = self._restart_clock
         # initial assignment
         self._assign()
         if w.quorum_mesh is not None and self.quorum is None:
@@ -469,15 +564,14 @@ class CallWrapper:
                     monitor.start()
                     if sibling:
                         sibling.start()
+                    if restart_clock.running:
+                        restart_clock.next("initialize")
                     if w.initialize:
                         w.initialize(state.freeze())
                     state.set_distributed_vars()
                     self.watchdog.ping()
                     if self._restart_started_ns is not None:
-                        recovery_ns = (
-                            time.monotonic_ns() - self._restart_started_ns
-                        )
-                        _RESTART_NS.observe(recovery_ns)
+                        recovery_ns = mono_ns() - self._restart_started_ns
                         self._restart_started_ns = None
                         if self._episode is not None:
                             # re-entering fn closes the episode: the rung
@@ -499,6 +593,8 @@ class CallWrapper:
                         else ProfilingEvent.WORKER_STARTED,
                         iteration=iteration, rank=state.initial_rank,
                     )
+                    if restart_clock.running:
+                        restart_clock.next(None)
                     if state.mode == Mode.ACTIVE:
                         if self._accepts_cw:
                             kwargs = {**kwargs, "call_wrapper": self}
@@ -556,7 +652,7 @@ class CallWrapper:
                 return ret
 
             # ---- restart path ---- (async-exc slot empty from here on)
-            phase_t0 = self._restart_started_ns = time.monotonic_ns()
+            self._restart_started_ns = restart_clock.start(iteration)
             if self.quorum:
                 self.quorum.suspend()  # re-armed by set_iteration at loop top
             _RESTARTS.inc()
@@ -652,7 +748,7 @@ class CallWrapper:
                 # the launcher ring (in-job restart) immediately
                 ledger().record(
                     fault_class, "in_process", False,
-                    (time.monotonic_ns() - self._restart_started_ns) / 1e9,
+                    (mono_ns() - self._restart_started_ns) / 1e9,
                     episode_id=ep.id,
                 )
                 self._episode = None
@@ -663,7 +759,7 @@ class CallWrapper:
             monitor.stop()
             if sibling:
                 sibling.stop()
-            phase_t0 = _observe_phase("abort_wait", phase_t0)
+            restart_clock.next("finalize")
             if self.ops.any_completed(iteration):
                 # a peer finished fn in the same iteration our restart
                 # signal fired: the job is DONE — restarting (or joining the
@@ -680,11 +776,11 @@ class CallWrapper:
             ep.phase("rendezvous")
             if w.finalize:
                 w.finalize(state.freeze())
-            phase_t0 = _observe_phase("finalize", phase_t0)
+            restart_clock.next("health_check")
             try:
                 if w.health_check:
                     w.health_check(state.freeze())
-                phase_t0 = _observe_phase("health_check", phase_t0)
+                restart_clock.next("iteration_barrier")
             except HealthCheckError as exc:
                 if self._episode is not None:
                     # episode escalates out of the process: the in-process
@@ -693,8 +789,7 @@ class CallWrapper:
                     self._episode = None
                     ledger().record(
                         cls, rung, False,
-                        (time.monotonic_ns() - self._restart_started_ns)
-                        / 1e9,
+                        (mono_ns() - self._restart_started_ns) / 1e9,
                         episode_id=eid,
                     )
                 ep.close()
@@ -718,7 +813,7 @@ class CallWrapper:
                 )
                 ep.close()
                 return JOB_COMPLETED
-            phase_t0 = _observe_phase("iteration_barrier", phase_t0)
+            restart_clock.next("reassign")
             # survivors regrouped: restoring this rank's place in the job
             ep.phase("restore")
             # the iteration-i barrier closing means every survivor advanced
@@ -734,12 +829,13 @@ class CallWrapper:
             state.rank = state.initial_rank
             state.world_size = state.initial_world_size
             self._assign()
-            _observe_phase("reassign", phase_t0)
+            restart_clock.next("collect")
             # last leg: initialize + loop re-entry, closed when fn restarts
             ep.phase("resume")
             state.advance()
             self.watchdog.ping()
             gc.collect()
+            restart_clock.next("rearm")
 
     # -- helpers -----------------------------------------------------------
 

@@ -31,10 +31,14 @@ Design:
 
 - **Intervals are event pairs.**  :func:`declare_interval` declares a
   ``<name>_begin`` / ``<name>_end`` pair; :func:`span` (one thread) or
-  :func:`begin` / :func:`end` (begin on one thread, end on another) record
-  them with the ``ident`` every interval of one operation shares (a save
-  ticket, a load number) and the ``parent`` interval's name.  A begin with
-  no end in a fault dump says where the process was stuck.
+  :func:`begin` / :func:`end` (begin on one thread, end on another; with
+  ``at_ns`` a stamp the caller took, so that one reading of the clock can end
+  an interval, begin the next and feed a histogram) record them with the
+  ``ident`` every interval of one operation shares (a save ticket, a load
+  number, the faulted wrapper iteration) and the ``parent`` interval's name.
+  A begin with no end in a fault dump says where the process was stuck.  A
+  dump past its throttle records two of its own, ``flight.dump.write`` and
+  ``flight.dump.hooks``.
 
 Dump triggers wired across the repo: monitor trip, abort-ladder entry,
 ``CollectiveTimeout``, unhandled wrapper exceptions, ``GET /flight`` on
@@ -126,6 +130,16 @@ def intervals() -> List[Interval]:
 
 
 EV_DUMP = declare_event("flight.dump", "reason")
+# a dump past its throttle, on the thread that asked for it; ident = the
+# dump's sequence number.  write: the ring snapshotted -> the file written
+# and the stale dumps unlinked (a dump holds its own write_begin and no end:
+# the next one does).  hooks: the loop over the dump hooks
+IV_DUMP_WRITE = declare_interval(
+    "flight.dump.write_begin", "flight.dump.write_end", "reason"
+)
+IV_DUMP_HOOKS = declare_interval(
+    "flight.dump.hooks_begin", "flight.dump.hooks_end", "reason"
+)
 # mirror of every utils/profiling.py record, so the ring alone tells the
 # restart-pipeline story even when no profiling sink file is configured
 EV_PROFILING = declare_event("profiling.event", "name", "cycle")
@@ -168,6 +182,12 @@ class FlightRecorder:
         # order — fine, the dump sorts by timestamp.
         self._ring[next(self._counter) & self._mask] = (
             mono_ns(), name, _EPISODE_CELL[0], args,
+        )
+
+    def record_at(self, t_ns: int, name: str, *args: Any) -> None:
+        """:meth:`record` under a ``mono_ns()`` stamp the caller took."""
+        self._ring[next(self._counter) & self._mask] = (
+            t_ns, name, _EPISODE_CELL[0], args,
         )
 
     def __len__(self) -> int:
@@ -215,16 +235,36 @@ def _parent_name(parent: Optional[Interval]) -> Optional[str]:
     return None if parent is None else parent.name
 
 
+def _record_edge(event: str, at_ns: Optional[int], *args: Any) -> None:
+    if at_ns is None:
+        record(event, *args)
+    else:
+        _recorder.record_at(at_ns, event, *args)
+
+
 def _begin(
-    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any
+    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any,
+    at_ns: Optional[int] = None,
 ) -> None:
-    record(iv.begin_event, ident, _parent_name(parent), *extra)
+    _record_edge(iv.begin_event, at_ns, ident, _parent_name(parent), *extra)
 
 
 def _end(
-    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any
+    iv: Interval, ident: Any, parent: Optional[Interval] = None, *extra: Any,
+    at_ns: Optional[int] = None,
 ) -> None:
-    record(iv.end_event, ident, _parent_name(parent), *extra)
+    _record_edge(iv.end_event, at_ns, ident, _parent_name(parent), *extra)
+
+
+def _annotation(iv: Interval) -> Any:
+    """An entered ``TraceAnnotation`` of the interval's name where jax is
+    already loaded (never imported here), else None; the caller exits it."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    entered = profiler.TraceAnnotation(iv.name)
+    entered.__enter__()
+    return entered
 
 
 class _Span:
@@ -232,26 +272,24 @@ class _Span:
     already loaded, a ``TraceAnnotation`` of the same name, so an operator's
     own profiler capture shows the interval above the device's timeline."""
 
-    __slots__ = ("_iv", "_ident", "_parent", "_annotation")
+    __slots__ = ("_iv", "_args", "_annotation")
 
     def __init__(
-        self, iv: Interval, ident: Any, parent: Optional[Interval] = None
+        self, iv: Interval, ident: Any, parent: Optional[Interval] = None,
+        *extra: Any,
     ):
-        self._iv, self._ident, self._parent = iv, ident, _parent_name(parent)
+        self._iv, self._args = iv, (ident, _parent_name(parent), *extra)
         self._annotation = None
 
     def __enter__(self) -> "_Span":
-        record(self._iv.begin_event, self._ident, self._parent)
-        profiler = sys.modules.get("jax.profiler")  # never imported here
-        if profiler is not None:
-            self._annotation = profiler.TraceAnnotation(self._iv.name)
-            self._annotation.__enter__()
+        record(self._iv.begin_event, *self._args)
+        self._annotation = _annotation(self._iv)
         return self
 
     def __exit__(self, *exc: Any) -> None:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
-        record(self._iv.end_event, self._ident, self._parent)
+        record(self._iv.end_event, *self._args)
 
 
 _NOOP_SPAN = contextlib.nullcontext()
@@ -270,18 +308,21 @@ def configure(
 ) -> None:
     """(Re)build the process recorder and rebind :func:`record`,
     :func:`span`, :func:`begin` and :func:`end`."""
-    global _recorder, record, span, begin, end
+    global _recorder, record, span, begin, end, annotation
     if enabled is None:
         enabled = flight_enabled()
     if capacity is None:
         capacity = env.FLIGHT_RING.get()
     _recorder = FlightRecorder(capacity) if enabled else NOOP
     record = _recorder.record
-    # span(iv, ident, parent=None): context manager around an interval of
-    # one thread; begin/end(iv, ident, parent=None, *extra): the same pair
-    # for an interval that starts on one thread and ends on another
+    # span(iv, ident, parent=None, *extra): context manager around an
+    # interval of one thread; begin/end(iv, ident, parent=None, *extra,
+    # at_ns=None): the same pair for an interval that starts on one thread and
+    # ends on another, or whose edges share a stamp with their neighbours';
+    # annotation(iv): the TraceAnnotation a span enters, for such an interval
     span = _Span if enabled else _noop_span
     begin, end = (_begin, _end) if enabled else (_noop, _noop)
+    annotation = _annotation if enabled else _noop
 
 
 def get_flight() -> Any:
@@ -377,39 +418,43 @@ def dump(
             return None
         _last_dump_ns[reason] = now
     record(EV_DUMP, reason)
+    seq = next(_dump_seq)
     try:
-        records = _records(reason)
-        if path is None:
-            base = env.FLIGHT_DIR.get() or tempfile.gettempdir()
-            os.makedirs(base, exist_ok=True)
-            path = os.path.join(
-                base,
-                f"flight-{_host()}-{os.getpid()}"
-                f"-{next(_dump_seq):04d}-{reason}.jsonl",
-            )
-        with open(path, "w") as f:
-            for rec in records:
-                f.write(json.dumps(rec, default=repr) + "\n")
-        with _dump_lock:
-            _dump_paths.append(path)
-            keep = max(1, env.FLIGHT_DUMP_KEEP.get())
-            stale, _dump_paths[:] = _dump_paths[:-keep], _dump_paths[-keep:]
-        for old in stale:
-            try:
-                os.unlink(old)
-            except OSError:
-                pass
+        with span(IV_DUMP_WRITE, seq, None, reason):
+            records = _records(reason)
+            if path is None:
+                base = env.FLIGHT_DIR.get() or tempfile.gettempdir()
+                os.makedirs(base, exist_ok=True)
+                path = os.path.join(
+                    base,
+                    f"flight-{_host()}-{os.getpid()}-{seq:04d}-{reason}.jsonl",
+                )
+            with open(path, "w") as f:
+                for rec in records:
+                    f.write(json.dumps(rec, default=repr) + "\n")
+            with _dump_lock:
+                _dump_paths.append(path)
+                keep = max(1, env.FLIGHT_DUMP_KEEP.get())
+                stale, _dump_paths[:] = (
+                    _dump_paths[:-keep], _dump_paths[-keep:]
+                )
+            for old in stale:
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass
         # the funnel-forwarded announcement: one line through the root
         # logger so the node's RootLogServer archive names every dump
         log.warning(
             "flight dump (%s): %s (%d events, episode=%s)",
             reason, path, len(records) - 1, current_episode_id() or "-",
         )
-        for hook in list(_DUMP_HOOKS):
-            try:
-                hook(records)
-            except Exception:  # noqa: BLE001 - hooks never worsen a fault
-                log.exception("flight dump hook failed")
+        with span(IV_DUMP_HOOKS, seq, None, reason):
+            for hook in list(_DUMP_HOOKS):
+                try:
+                    hook(records)
+                except Exception:  # noqa: BLE001 - hooks never worsen a fault
+                    log.exception("flight dump hook failed")
         return path
     except Exception:  # noqa: BLE001 - dumping must never worsen a fault
         log.exception("flight dump (%s) failed", reason)

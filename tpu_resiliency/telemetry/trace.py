@@ -98,6 +98,29 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     # render as instants inside it)
     "evac.risk_cross": ("evac.join", "evacuation", "evac"),
 }
+# from the trip to the wrapped fn's re-entry (docs/observability.md): the
+# monitor thread's abort, the two dumps, the raise's delivery, and the main
+# thread's restart path with its eight phases; ident = the faulted iteration
+# (a dump's: its sequence number)
+for _name, _cat in (
+    ("inproc.abort", "inprocess"),
+    ("inproc.abort.on_trip", "inprocess"),
+    ("inproc.abort.ladder", "inprocess"),
+    ("inproc.abort.stage", "inprocess"),
+    ("inproc.raise", "inprocess"),
+    ("inproc.restart", "inprocess"),
+    ("inproc.restart.abort_wait", "inprocess"),
+    ("inproc.restart.finalize", "inprocess"),
+    ("inproc.restart.health_check", "inprocess"),
+    ("inproc.restart.iteration_barrier", "inprocess"),
+    ("inproc.restart.reassign", "inprocess"),
+    ("inproc.restart.collect", "inprocess"),
+    ("inproc.restart.rearm", "inprocess"),
+    ("inproc.restart.initialize", "inprocess"),
+    ("flight.dump.write", "flight"),
+    ("flight.dump.hooks", "flight"),
+):
+    SPAN_PAIRS[f"{_name}_begin"] = (f"{_name}_end", _name, _cat)
 _END_TO_START = {end: start for start, (end, _, _) in SPAN_PAIRS.items()}
 
 INSTANT_CATEGORIES = {
