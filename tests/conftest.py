@@ -15,7 +15,18 @@ if "xla_force_host_platform_device_count" not in xla_flags:
         xla_flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import gc  # noqa: E402
+
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _unfreeze_after_test():
+    """A wrapper's restart freezes the heap that survived it
+    (``inprocess/wrap.py``); without this the stores, sockets and threads one
+    test left in cycles would stay permanent for the rest of its worker's run."""
+    yield
+    gc.unfreeze()
 
 
 @pytest.fixture

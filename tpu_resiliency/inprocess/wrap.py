@@ -27,7 +27,7 @@ from typing import Any, Callable, Optional
 from ..store.barrier import BarrierTimeout
 from ..store.client import StoreClient, StoreError, StoreTimeout, store_from_env
 from ..policy.ledger import ledger
-from ..telemetry import counter, flight, histogram
+from ..telemetry import counter, flight, gauge, histogram
 from ..telemetry import episode as episode_mod
 from ..telemetry.clock import mono_ns
 from ..utils import env
@@ -93,6 +93,14 @@ _RESTART_NS = histogram(
     "tpurx_restart_total_latency_ns",
     "Fault observed to wrapped fn re-entered, end to end",
 )
+_COLLECTED = counter(
+    "tpurx_restart_gc_collected_total",
+    "Unreachable objects the restart path's gc.collect() found",
+)
+_FROZEN = gauge(
+    "tpurx_restart_gc_frozen_objects",
+    "Survivors the last restart's gc.freeze() moved to the permanent generation",
+)
 
 
 # the restart path on the main thread, from the fault caught to the wrapped fn
@@ -119,8 +127,11 @@ _IV_PHASE = {
     "reassign": flight.declare_interval(
         "inproc.restart.reassign_begin", "inproc.restart.reassign_end"
     ),
+    # the end alone carries the two fields: what gc.collect() returned and
+    # how many survivors gc.freeze() then moved to the permanent generation
     "collect": flight.declare_interval(
-        "inproc.restart.collect_begin", "inproc.restart.collect_end"
+        "inproc.restart.collect_begin", "inproc.restart.collect_end",
+        "collected", "frozen",
     ),
     "rearm": flight.declare_interval(
         "inproc.restart.rearm_begin", "inproc.restart.rearm_end"
@@ -156,11 +167,11 @@ class _RestartClock:
         flight.begin(iv, self.ident, parent, at_ns=now)
         self._annotations.append(flight.annotation(iv))
 
-    def _close(self, iv, parent, now: int) -> None:
+    def _close(self, iv, parent, now: int, *extra: Any) -> None:
         entered = self._annotations.pop()
         if entered is not None:
             entered.__exit__(None, None, None)
-        flight.end(iv, self.ident, parent, at_ns=now)
+        flight.end(iv, self.ident, parent, *extra, at_ns=now)
 
     def start(self, ident: int) -> int:
         """Open ``inproc.restart`` and its first phase; returns the stamp."""
@@ -171,12 +182,13 @@ class _RestartClock:
         self._open(_IV_PHASE[self._phase], IV_RESTART, now)
         return now
 
-    def next(self, phase: Optional[str]) -> None:
-        """End the open phase and begin ``phase``; with None, end
-        ``inproc.restart`` too: the wrapped fn is about to be called."""
+    def next(self, phase: Optional[str], *extra: Any) -> None:
+        """End the open phase (``extra``: its end event's fields) and begin
+        ``phase``; with None, end ``inproc.restart`` too: the wrapped fn is
+        about to be called."""
         now = mono_ns()
         _PHASE_NS.labels(self._phase).observe(now - self._phase_ns)
-        self._close(_IV_PHASE[self._phase], IV_RESTART, now)
+        self._close(_IV_PHASE[self._phase], IV_RESTART, now, *extra)
         self._phase, self._phase_ns = phase, now
         if phase is not None:
             self._open(_IV_PHASE[phase], IV_RESTART, now)
@@ -834,8 +846,25 @@ class CallWrapper:
             ep.phase("resume")
             state.advance()
             self.watchdog.ping()
-            gc.collect()
-            restart_clock.next("rearm")
+            # what this frame still holds of the dead iteration goes before
+            # the collection (on the exception path fault_exc's traceback
+            # holds fn's frames and their locals; the loop assigns each of
+            # these anew before it reads it): whatever survives the
+            # collection is frozen, and a frozen cycle is never examined again
+            fault_exc = ret = ep = ladder_results = monitor = sibling = None
+            collected = gc.collect()
+            # the survivors are reachable from outside fn and outlive every
+            # restart (modules, jax's traced and lowered programs): in the
+            # permanent generation the next restart's collection, still a
+            # full one, walks what was allocated since this one and no more.
+            # They are counted as they go (generations 0-2 are listed, the
+            # permanent one is not): gc.get_freeze_count() would walk the
+            # permanent generation itself, every restart, for their sum
+            frozen = len(gc.get_objects())
+            gc.freeze()
+            _COLLECTED.inc(collected)
+            _FROZEN.set(frozen)
+            restart_clock.next("rearm", collected, frozen)
 
     # -- helpers -----------------------------------------------------------
 
