@@ -30,7 +30,7 @@ METRICS = (
     "restart_main_ms", "restart_collect_ms", "reenter_unattributed_pct")
 STALL_CELLS = [f"{config}.stall-inproc" for config in (
     "cerebras-gpt-1.3b-1chip", "kimi-linear-48b-a3b-1chip",
-    "qwen3-next-80b-a3b-1chip", "keye-vl-2.0-30b-a3b-1chip")]
+    "qwen3-next-80b-a3b-1chip", "keye-vl-2.0-30b-a3b-1chip", "lfm2-8b-a1b-1chip")]
 # what PR 40 added to the program's ring: a program without them is the parent
 NEW_EVENTS = ("inproc.abort", "inproc.raise", "inproc.restart", "flight.dump.")
 
@@ -361,7 +361,7 @@ def test_a_steady_save_cell_is_on_no_new_metrics_list():
 
 
 @pytest.mark.parametrize("name", METRICS)
-def test_the_benchmark_lists_the_metric_for_the_four_stall_cells(name):
+def test_the_benchmark_lists_the_metric_for_every_stall_cell(name):
     bench = _bench()
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     with open(os.path.join(ROOT, "chipbench", "layer_metrics", f"{name}.json")) as f:

@@ -5,17 +5,21 @@ from . import (
     keye_vl2_reference,
     kimi_linear,
     kimi_linear_reference,
+    lfm2_moe,
+    lfm2_moe_reference,
     qwen3_next,
     qwen3_next_reference,
 )
 from .keye_vl2 import KeyeVL2Config
 from .kimi_linear import KimiLinearConfig
+from .lfm2_moe import Lfm2MoeConfig
 from .qwen3_next import Qwen3NextConfig
 from .transformer import TransformerConfig, init_params, forward, loss_fn, make_train_step
 
 __all__ = [
     "KeyeVL2Config",
     "KimiLinearConfig",
+    "Lfm2MoeConfig",
     "Qwen3NextConfig",
     "TransformerConfig",
     "forward",
@@ -24,6 +28,8 @@ __all__ = [
     "keye_vl2_reference",
     "kimi_linear",
     "kimi_linear_reference",
+    "lfm2_moe",
+    "lfm2_moe_reference",
     "loss_fn",
     "make_train_step",
     "qwen3_next",

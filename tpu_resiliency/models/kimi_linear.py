@@ -316,17 +316,19 @@ def mla_block(x, p, cfg: KimiLinearConfig):
 
 # -- the expert layer -----------------------------------------------------------
 
-def route(x, router, bias, cfg: KimiLinearConfig):
+def route(x, router, bias, cfg: KimiLinearConfig, eps: float = 0.0):
     """``(chosen [tokens, 8], weights [tokens, 8] float32, load [experts]
     int32)``: sigmoid scores of all experts in float32, the top 8 of score +
-    bias, weights renormalised over the chosen and scaled."""
+    bias, weights renormalised over the chosen (``eps`` added to their sum
+    where a family's public code has one) and scaled."""
     import jax
     import jax.numpy as jnp
 
     scores = jax.nn.sigmoid(jnp.matmul(x, router, preferred_element_type=jnp.float32))
     _, chosen = jax.lax.top_k(scores + bias, cfg.num_experts_per_token)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    weights = picked / (total + eps if eps else total) * cfg.routed_scaling_factor
     load = jnp.zeros((cfg.num_experts,), jnp.int32).at[chosen.reshape(-1)].add(1)
     return chosen, weights, load
 
@@ -334,7 +336,7 @@ def route(x, router, bias, cfg: KimiLinearConfig):
 BUFFER_LADDER = (16, 4, 1)  # the pair buffer's rows: a 16th, a quarter, all of tokens x 8
 
 
-def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig):
+def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig, ladder=BUFFER_LADDER):
     """The held experts' part of the layer's output for ``x`` [tokens, d].
 
     Every (token, choice) pair whose expert is held here is one row of a
@@ -346,7 +348,9 @@ def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig):
     scattering that many rows would cost more than the experts.  So the
     buffer comes in three sizes (``BUFFER_LADDER``) and ``lax.switch`` takes
     the smallest that holds this step's pairs: the work follows the load in
-    three strides."""
+    three strides.  A model whose held share sits on a rung (a quarter of the
+    experts held: the expected load IS the middle size) gives its own
+    ``ladder``, whose last entry is 1: every pair has to fit."""
     import jax
     import jax.numpy as jnp
 
@@ -375,7 +379,7 @@ def held_experts(x, chosen, weights, experts, cfg: KimiLinearConfig):
 
         return compute
 
-    ladder = sorted({max(1, pairs // part) for part in BUFFER_LADDER})
+    ladder = sorted({max(1, pairs // part) for part in ladder})
     rung = sum((jnp.sum(sizes) > rows).astype(jnp.int32) for rows in ladder[:-1])
 
     # The backward pass computes the taken size again and differentiates that
@@ -492,21 +496,29 @@ def init_opt_state(params, cfg: KimiLinearConfig):
     }
 
 
+def moved_bias(bias, load, rate: float):
+    """The router's bias after a step: each expert's moved by ``rate`` towards
+    the mean load, up where this step's tokens chose it less often than the
+    mean and down where more (the auxiliary-loss-free rule)."""
+    import jax.numpy as jnp
+
+    spread = jnp.mean(load.astype(jnp.float32), axis=-1, keepdims=True) - load
+    return bias + rate * jnp.sign(spread)
+
+
 def make_train_step(cfg: KimiLinearConfig, lr: float = 1e-3):
     """Fused jitted train step: ``(params, opt, (tokens, targets)) -> (params,
     opt, loss)``: forward, backward, AdamW on every trained leaf, then the
     router's bias moved by ``bias_update_rate`` towards the experts that this
-    step's tokens chose less often than the mean."""
+    step's tokens chose less often than the mean (``moved_bias``)."""
     import jax
-    import jax.numpy as jnp
 
     def step(params, opt, batch):
         (loss, load), grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch, cfg, opt["router_bias"]), has_aux=True)(params)
         params, new_opt = adamw_tree(params, grads, opt, lr)
-        spread = jnp.mean(load.astype(jnp.float32), axis=-1, keepdims=True) - load
         new_opt.update(
-            router_bias=opt["router_bias"] + cfg.bias_update_rate * jnp.sign(spread),
+            router_bias=moved_bias(opt["router_bias"], load, cfg.bias_update_rate),
             router_load=load)
         return params, new_opt, loss
 
