@@ -24,7 +24,7 @@ IV_INNER = flight.declare_interval(
 
 SAVE_INTERVALS = ("ckpt.save", "ckpt.save.prepare", "ckpt.save.snapshot",
                   "ckpt.save.handoff", "ckpt.stage", "ckpt.stage.d2h",
-                  "ckpt.drain")
+                  "ckpt.stage.d2h.first", "ckpt.drain")
 LOAD_INTERVALS = ("ckpt.load", "ckpt.load.plan", "ckpt.load.start",
                   "ckpt.load.wait", "ckpt.load.place", "ckpt.load.release")
 INNER_RING_INTERVALS = (
@@ -38,6 +38,7 @@ INNER_RING_INTERVALS = (
 PARENT_OF = {
     "ckpt.save.prepare": "ckpt.save", "ckpt.save.snapshot": "ckpt.save",
     "ckpt.save.handoff": "ckpt.save", "ckpt.stage.d2h": "ckpt.stage",
+    "ckpt.stage.d2h.first": "ckpt.stage.d2h",
     "ckpt.load.plan": "ckpt.load", "ckpt.load.start": "ckpt.load",
     "ckpt.load.wait": "ckpt.load", "ckpt.load.place": "ckpt.load",
     "ckpt.load.release": "ckpt.load",

@@ -98,6 +98,14 @@ _STAGE_BYTES = counter(
 _STAGE_OVERLAP = gauge(
     "tpurx_ckpt_stage_overlap_pct", "Last staging's D2H/shm-copy overlap (%)"
 )
+_STAGE_WINDOW_PEAK = gauge(
+    "tpurx_ckpt_stage_d2h_window_peak_bytes",
+    "Last staging's most D2H bytes issued and not yet landed",
+)
+_STAGE_WINDOW_WAITS = gauge(
+    "tpurx_ckpt_stage_d2h_window_waits",
+    "Last staging's top-ups of the D2H window that left a shard waiting",
+)
 _DRAIN_PROGRESS = gauge(
     "tpurx_ckpt_drain_progress",
     "Fraction (0-1) of in-flight save bytes the worker has written",
@@ -678,11 +686,15 @@ class AsyncCheckpointer:
                 "stage_wait_s": staged.stage_wait_s,
                 "stage_copy_s": staged.stage_copy_s,
                 "stage_overlap_pct": staged.stage_overlap_pct,
+                "d2h_window_peak_bytes": staged.d2h_window_peak_bytes,
+                "d2h_window_waits": staged.d2h_window_waits,
                 "device_digest_s": staged.device_digest_s,
                 "d2h_skipped_bytes": staged.d2h_skipped_bytes,
             }
             _STAGE_BYTES.inc(staged.bytes_allocated + staged.bytes_reused)
             _STAGE_OVERLAP.set(staged.stage_overlap_pct)
+            _STAGE_WINDOW_PEAK.set(staged.d2h_window_peak_bytes)
+            _STAGE_WINDOW_WAITS.set(staged.d2h_window_waits)
             with job.lock:
                 if job.cleaned:
                     # cleanup (abort) already ran: nobody else will release

@@ -8,11 +8,17 @@ buffers.  The training step only pays for the D2H DMA + one memcpy into shm;
 file writes happen in the worker process reading the same shm — zero copies
 across the process boundary.
 
-Staging is **pipelined per shard**: the full shm plan (every shard's size and
-segment) is computed up-front from metadata alone, all owned D2H copies are
-kicked off asynchronously, and then each shard is memcpy'd into shm as soon
-as *its* transfer lands — the memcpy of shard *i* overlaps the in-flight DMA
-of shards *i+1..n* instead of the old stage-everything-then-copy sequence.
+Staging is **pipelined per shard under a bounded window**: the full shm plan
+(every shard's size and segment) is computed up-front from metadata alone,
+owned D2H copies are kicked off in plan order while the bytes issued and not
+yet landed stay within ``D2H_WINDOW_BYTES`` (:func:`issue_upto`; a shard
+larger than the window goes alone), and each shard is memcpy'd into shm as
+soon as *its* transfer lands, the window topped up first — the memcpy of
+shard *i* overlaps the in-flight DMA of the shards behind it in the window.
+The window is there for the rest of the process: whatever it launches on the
+device after a transfer is issued waits behind that transfer, so a whole
+state issued at once held every step and quorum tick for as long as the
+runtime's queue of transfers took to drain.
 Because the plan precedes the bytes, a streaming consumer (``writer.py``'s
 chunked multi-writer engine) can start persisting the first shards while
 later leaves are still in flight: ``on_plan`` fires once with the total
@@ -61,12 +67,13 @@ paths (``local/state_dict.py``) kick their transfers through
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import time
 from multiprocessing import shared_memory
 
 from ...utils.shm import create_shm, unlink_shm
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -81,6 +88,28 @@ IV_STAGE = flight.declare_interval("ckpt.stage_begin", "ckpt.stage_end")
 IV_STAGE_D2H = flight.declare_interval(
     "ckpt.stage.d2h_begin", "ckpt.stage.d2h_end"
 )
+# ``ckpt.stage.d2h``'s begin to the first transferring shard landed on the
+# host: what the first window of transfers costs before anything streams
+IV_STAGE_D2H_FIRST = flight.declare_interval(
+    "ckpt.stage.d2h.first_begin", "ckpt.stage.d2h.first_end"
+)
+
+# The most bytes of device-to-host transfer the stager keeps issued and not
+# yet landed.  Whatever the process launches on the device after a transfer
+# is issued waits behind it, and once the runtime's queue is full the issuing
+# call itself blocks, so this bounds what a step or a quorum tick can wait
+# for.  A probe on a v5e chip (PR 43: a jitted step in a loop one step ahead,
+# a second thread moving a copy of the whole 4.59 GB state, 269 arrays, to the
+# host; docs/checkpointing.md has the table): everything issued at once cost
+# the steps beside it 1.057 s (periods of 572 and 732 ms where 101.8 is
+# normal; one copy_to_host_async call blocked 0.869 s); 64 / 128 / 256 MiB
+# outstanding cost 5.2 / 4.6 / 6.0 ms with no period over 126 ms; 512 MiB
+# left a 102 ms step alone but cost a 63 ms step 160 ms; 1 GiB cost 122 ms.
+# The state moved as fast at 128 MiB as all at once (2.72 s both; 3.23 s at
+# 64 MiB).  128 MiB is half the largest window the shorter step bore.  Bytes,
+# not a count of transfers: a count bounds nothing that the bytes do not.  A
+# constant, no knob: the stager reads shard sizes and nothing else.
+D2H_WINDOW_BYTES = 128 << 20
 
 log = get_logger("ckpt.staging")
 
@@ -100,8 +129,10 @@ def async_d2h(datas: Iterable[Any]) -> int:
     lint rule TPURX015 bans raw ``copy_to_host_async``/``jax.device_get``
     on checkpoint bytes elsewhere, so every capture path funnels through
     here (or through the staging pipeline itself) and inherits whatever
-    scheduling/accounting this layer grows.  Returns the number of
-    transfers started; host-backed arrays are skipped."""
+    scheduling/accounting this layer grows.  Whatever it is handed is issued
+    at once: the stager hands it a window's worth at a time
+    (:func:`issue_upto`).  Returns the number of transfers started;
+    host-backed arrays are skipped."""
     n = 0
     for d in datas:
         fn = getattr(d, "copy_to_host_async", None)
@@ -109,6 +140,27 @@ def async_d2h(datas: Iterable[Any]) -> int:
             fn()
             n += 1
     return n
+
+
+def issue_upto(cum: Sequence[int], issued: int, landed: int, window: int) -> int:
+    """The stager's issue policy, device-free.  Transfers are numbered in plan
+    order; ``cum[i]`` is the bytes of transfers ``0..i-1`` (``cum[0] == 0``),
+    ``issued`` of them have been kicked off and ``landed`` of those are on the
+    host.  Returns how many may be issued by now: the next one goes while the
+    bytes issued and not landed, its own included, stay within ``window``;
+    with nothing outstanding the next one always goes, so a transfer larger
+    than the window travels alone and the count never stalls."""
+    n = len(cum) - 1
+    while issued < n and (
+        issued == landed or cum[issued + 1] - cum[landed] <= window
+    ):
+        issued += 1
+    return issued
+
+
+def _await_d2h(data: Any) -> np.ndarray:
+    """Block until THIS shard's transfer has landed; the host copy."""
+    return np.asarray(data)
 
 
 @dataclasses.dataclass
@@ -142,6 +194,8 @@ class StagedTree:
     stage_wait_s: float = 0.0             # summed per-shard D2H completion waits
     stage_copy_s: float = 0.0             # summed memcpy-into-shm time
     stage_overlap_pct: float = 0.0        # % of memcpy overlapped with live D2H
+    d2h_window_peak_bytes: int = 0        # most bytes issued and not landed
+    d2h_window_waits: int = 0             # top-ups that left a shard waiting
     # which save's bytes these shm segments hold (the committed-generation
     # identity the D2H-skip gate compares against the delta baseline)
     content_id: str = ""
@@ -351,7 +405,9 @@ def stage_pytree(
     bytes for the resident publish).
 
     ``ident`` (the save ticket) tags the ``ckpt.stage.d2h`` flight
-    interval: first transfer issued to last byte landed in shm."""
+    interval: first transfer issued to last byte landed in shm; its child
+    ``ckpt.stage.d2h.first`` ends when the first transferring shard is on
+    the host (left open by a staging that fails before that)."""
     treedef, paths, leaves = _leaf_paths(tree)
     pidx = process_index
     if pidx is None:
@@ -484,15 +540,26 @@ def _stage_pipelined(
             elif unchanged is not None:
                 w.info.dev_unchanged = unchanged
 
+    # Transferring shards in plan order, and the window over them: a shard is
+    # issued only while the bytes issued and not yet landed stay within
+    # D2H_WINDOW_BYTES (``issue_upto``).  Skipped and host-backed shards never
+    # transfer and count for nothing.
+    xfers = [w for w in work if w.is_jax and not w.info.d2h_skipped]
+    cum = [0, *itertools.accumulate(w.info.nbytes for w in xfers)]
+    issued = landed = peak = waits = 0
+
+    def top_up() -> None:
+        nonlocal issued, peak, waits
+        upto = issue_upto(cum, issued, landed, D2H_WINDOW_BYTES)
+        async_d2h(w.source.data for w in xfers[issued:upto])
+        issued = upto
+        peak = max(peak, cum[issued] - cum[landed])
+        waits += issued < len(xfers)
+
     with flight.span(IV_STAGE_D2H, ident, IV_STAGE):
-        # Kick off async D2H for every owned jax shard that transfers, before
-        # copying anything: all DMAs are in flight while shard-by-shard memcpys
-        # land below.  Skipped shards never transfer.
-        jax_pending = 0
-        for w in work:
-            if w.is_jax and not w.info.d2h_skipped:
-                w.source.data.copy_to_host_async()
-                jax_pending += 1
+        if xfers:
+            flight.begin(IV_STAGE_D2H_FIRST, ident, IV_STAGE_D2H)
+        top_up()
 
         # skipped shards complete instantly: stream their provenance-only
         # payloads first so the drain credits their bytes before any wait
@@ -507,11 +574,13 @@ def _stage_pipelined(
             if w.info.d2h_skipped:
                 continue  # slot k's shm keeps the (identical) baseline bytes
             t0 = time.perf_counter()
+            arr = _await_d2h(w.source.data) if w.is_jax else np.asarray(w.source)
+            wait_s += time.perf_counter() - t0
             if w.is_jax:
-                arr = np.asarray(w.source.data)  # completes THIS shard's D2H only
-                jax_pending -= 1
-            else:
-                arr = np.asarray(w.source)
+                landed += 1
+                if landed == 1:
+                    flight.end(IV_STAGE_D2H_FIRST, ident, IV_STAGE_D2H)
+                top_up()  # the next transfers run under this shard's memcpy
             t1 = time.perf_counter()
             if reusing:
                 shm = shms[k]
@@ -528,9 +597,8 @@ def _stage_pipelined(
             dst = np.ndarray(arr.shape, dtype=arr.dtype, buffer=shm.buf)
             np.copyto(dst, arr, casting="no")
             t2 = time.perf_counter()
-            wait_s += t1 - t0
             copy_s += t2 - t1
-            if jax_pending > 0:  # this memcpy ran under at least one live DMA
+            if issued > landed:  # this memcpy ran under at least one live DMA
                 hidden_copy_s += t2 - t1
             if on_shard_staged is not None:
                 on_shard_staged(w.info)
@@ -541,6 +609,8 @@ def _stage_pipelined(
     staged.stage_wait_s = wait_s
     staged.stage_copy_s = copy_s
     staged.stage_overlap_pct = 100.0 * hidden_copy_s / copy_s if copy_s > 0 else 0.0
+    staged.d2h_window_peak_bytes = peak
+    staged.d2h_window_waits = waits
     return staged
 
 

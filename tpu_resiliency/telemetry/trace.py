@@ -56,9 +56,9 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     "ckpt.drain_begin": ("ckpt.drain_end", "ckpt_drain", "checkpointing"),
     "ckpt.restore_begin": ("ckpt.restore_end", "ckpt_restore", "checkpointing"),
     # flight intervals (flight.interval): one save is ckpt.save (prepare,
-    # snapshot, handoff) + ckpt.stage (d2h) + ckpt.drain, sharing the save
-    # ticket as ``ident``; one restore is ckpt.load and its children,
-    # sharing a load number
+    # snapshot, handoff) + ckpt.stage (d2h, d2h.first) + ckpt.drain, sharing
+    # the save ticket as ``ident``; one restore is ckpt.load and its
+    # children, sharing a load number
     "ckpt.save_begin": ("ckpt.save_end", "ckpt.save", "checkpointing"),
     "ckpt.save.prepare_begin": (
         "ckpt.save.prepare_end", "ckpt.save.prepare", "checkpointing",
@@ -72,6 +72,9 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     "ckpt.stage_begin": ("ckpt.stage_end", "ckpt.stage", "checkpointing"),
     "ckpt.stage.d2h_begin": (
         "ckpt.stage.d2h_end", "ckpt.stage.d2h", "checkpointing",
+    ),
+    "ckpt.stage.d2h.first_begin": (
+        "ckpt.stage.d2h.first_end", "ckpt.stage.d2h.first", "checkpointing",
     ),
     "ckpt.load_begin": ("ckpt.load_end", "ckpt.load", "checkpointing"),
     "ckpt.load.plan_begin": (
