@@ -16,6 +16,7 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     ).strip()
 
 import gc  # noqa: E402
+import sys  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -27,6 +28,18 @@ def _unfreeze_after_test():
     test left in cycles would stay permanent for the rest of its worker's run."""
     yield
     gc.unfreeze()
+
+
+@pytest.fixture(autouse=True)
+def _land_deferred_dumps_after_test():
+    """A trip's and a ladder's black boxes are captured where they are asked
+    for and written up to two seconds later (``telemetry/flight.py``); without
+    this the writer thread would record one test's ``flight.dump.write`` in the
+    ring the next test reads."""
+    yield
+    flight = sys.modules.get("tpu_resiliency.telemetry.flight")
+    if flight is not None:
+        flight.flush()
 
 
 @pytest.fixture

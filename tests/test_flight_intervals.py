@@ -29,8 +29,8 @@ LOAD_INTERVALS = ("ckpt.load", "ckpt.load.plan", "ckpt.load.start",
                   "ckpt.load.wait", "ckpt.load.place", "ckpt.load.release")
 INNER_RING_INTERVALS = (
     "inproc.coalesce", "inproc.abort", "inproc.abort.on_trip",
-    "inproc.abort.ladder", "inproc.abort.stage", "flight.dump.write",
-    "flight.dump.hooks", "inproc.raise", "inproc.restart",
+    "inproc.abort.ladder", "inproc.abort.stage", "flight.dump.capture",
+    "flight.dump.write", "flight.dump.hooks", "inproc.raise", "inproc.restart",
     "inproc.restart.abort_wait", "inproc.restart.finalize",
     "inproc.restart.health_check", "inproc.restart.iteration_barrier",
     "inproc.restart.reassign", "inproc.restart.collect",
@@ -232,9 +232,9 @@ def test_exit_dump_only_where_a_directory_is_named(tmp_path, env, dumped):
         assert records[0]["event"] == "_flight_meta"
         assert records[0]["reason"] == "exit"
         assert {"mono_ns", "ts", "events", "capacity"} <= set(records[0])
-        # the dump's own write has begun and not ended: the next dump has it
+        # the dump's own capture has begun and not ended: the next dump has it
         assert [r["event"] for r in records[1:] if "ident" in r] == [
-            "child.work_begin", "child.work_end", "flight.dump.write_begin"]
+            "child.work_begin", "child.work_end", "flight.dump.capture_begin"]
 
 
 # ---- the checkpoint's intervals ----------------------------------------------
@@ -421,9 +421,9 @@ def test_trace_cli_renders_the_new_pairs_as_complete_spans(tmp_path):
     assert {s["args"]["ident"] for s in by_name["ckpt_drain"]} == {1, 2}
     for name in LOAD_INTERVALS:
         assert by_name[name], name
-    # nothing is left open but the write of the very dump that was rendered
+    # nothing is left open but the capture of the very dump that was rendered
     assert [e["name"] for e in events if "(unfinished)" in e.get("name", "")] == [
-        "flight.dump.write (unfinished)"]
+        "flight.dump.capture (unfinished)"]
     save = by_name["ckpt.save"][0]
     child = next(s for s in by_name["ckpt.save.snapshot"]
                  if s["args"]["ident"] == save["args"]["ident"])

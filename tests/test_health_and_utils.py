@@ -141,6 +141,14 @@ def test_log_funnel_gap_detection(tmp_path):
         s.close()
 
     send({"source": "n1", "seq": 1, "lines": ["a"]})
+    # each batch has a connection and a handler thread of its own: the second
+    # goes once the first is in the file, or a loaded host may take them in
+    # the other order and see no gap
+    agg = tmp_path / "agg.log"
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline and not (
+            agg.exists() and "[n1] a" in agg.read_text()):
+        time.sleep(0.02)
     send({"source": "n1", "seq": 4, "lines": ["b"], "dropped": 2})
     time.sleep(0.4)
     root.close()

@@ -2,9 +2,10 @@
 (``docs/observability.md``): one injected fault on the one-rank harness of
 ``tests/test_monitor_coalesce.py`` records each of them once, on the monitor
 thread (``inproc.coalesce``, ``inproc.abort`` and its children, the two dumps'
-``flight.dump.write`` / ``hooks``, ``inproc.raise``) and on the main thread
+``flight.dump.capture``, ``inproc.raise``) and on the main thread
 (``inproc.restart`` and its eight children), with the faulted iteration as
-ident.
+ident; the dumps' ``flight.dump.write`` / ``hooks`` follow on the recorder's
+writer thread, after the wrapped fn's re-entry.
 
 No test here compares a duration with a constant but for a rung held at its
 deadline, which is compared with that deadline.
@@ -51,7 +52,8 @@ EXPECTED = {
     "inproc.abort.ladder": ("inproc.abort", 1),
     # fingerprint and the abort= callable; shrink_mesh is gated off
     "inproc.abort.stage": ("inproc.abort.ladder", 2),
-    "flight.dump.write": (None, 2),  # monitor_trip, abort_ladder
+    "flight.dump.capture": (None, 2),  # monitor_trip, abort_ladder
+    "flight.dump.write": (None, 2),  # the writer thread's, behind the re-entry
     "flight.dump.hooks": (None, 2),
     "inproc.raise": (None, 1),
     "inproc.restart": (None, 1),
@@ -129,6 +131,7 @@ def _recover_once(port, group, fault, tmp_path, monkeypatch, **plugins):
         last_call_wait=LONG, **plugins,
     )
     assert wrapper(train)() == "recovered"
+    flight.flush()  # the two dumps' writes, if the writer has not got there yet
     (stamp,) = reentered
     return stamp
 
@@ -214,14 +217,25 @@ def test_the_monitor_threads_intervals_follow_one_another(episode):
     assert on_trip_end <= ladder_begin <= ladder_end <= abort_end <= raise_begin
     # the raise ends on the main thread, before it stamps the restart
     assert raise_begin <= raise_end <= restart_begin
-    # the trip's dump lies in on_trip, the ladder's in the ladder, before its rungs
-    (trip_dump, ladder_dump) = paired["flight.dump.write"]
-    (_, trip_hooks_end, _), (_, ladder_hooks_end, _) = paired["flight.dump.hooks"]
-    assert on_trip_begin <= trip_dump[0] <= trip_hooks_end <= on_trip_end
-    assert ladder_begin <= ladder_dump[0] <= ladder_hooks_end
+    # the trip's capture lies in on_trip, the ladder's in the ladder, before
+    # its rungs
+    (trip_capture, ladder_capture) = paired["flight.dump.capture"]
+    assert on_trip_begin <= trip_capture[0] <= trip_capture[1] <= on_trip_end
+    assert ladder_begin <= ladder_capture[0] <= ladder_capture[1]
     stages = paired["inproc.abort.stage"]
-    assert ladder_hooks_end <= stages[0][0] <= stages[0][1] <= stages[1][0]
+    assert ladder_capture[1] <= stages[0][0] <= stages[0][1] <= stages[1][0]
     assert stages[1][1] <= ladder_end
+
+
+def test_the_dumps_are_written_in_order_after_the_restart(episode):
+    """No write inside ``inproc.abort`` nor before the restart's end: the
+    monitor thread only captures."""
+    paired = episode["paired"]
+    (_, restart_end, _), = paired["inproc.restart"]
+    (trip_write, ladder_write) = paired["flight.dump.write"]
+    (trip_hooks, ladder_hooks) = paired["flight.dump.hooks"]
+    assert restart_end <= trip_write[0] <= trip_write[1] <= trip_hooks[0]
+    assert trip_hooks[1] <= ladder_write[0] <= ladder_write[1] <= ladder_hooks[0]
 
 
 def test_the_restarts_eight_children_are_adjacent_in_order_and_cover_it(episode):
@@ -343,7 +357,7 @@ def test_a_rung_held_past_its_deadline_ends_there_and_the_next_follows():
 
 
 @pytest.mark.parametrize("throttled", [False, True])
-def test_a_dump_records_its_write_and_its_hooks_unless_throttled(
+def test_a_dump_records_its_capture_write_and_hooks_unless_throttled(
         tmp_path, monkeypatch, throttled):
     monkeypatch.setenv("TPURX_FLIGHT_DIR", str(tmp_path))
     _fresh_ring()
@@ -360,17 +374,20 @@ def test_a_dump_records_its_write_and_its_hooks_unless_throttled(
     finally:
         flight.remove_dump_hook(seen.append)
     paired = _paired(_interval_events())
+    (capture_begin, capture_end, capture), = paired["flight.dump.capture"]
     (write_begin, write_end, write), = paired["flight.dump.write"]
     (hooks_begin, hooks_end, hooks), = paired["flight.dump.hooks"]
-    assert write["ident"] == hooks["ident"] and isinstance(write["ident"], int)
+    assert capture["ident"] == write["ident"] == hooks["ident"]
+    assert isinstance(write["ident"], int)
     assert f"-{write['ident']:04d}-inner_ring_test.jsonl" in path
-    assert write["reason"] == hooks["reason"] == "inner_ring_test"
-    assert write_begin <= write_end <= hooks_begin <= hooks_end
-    # the hook was fed the dump, which holds its own write's begin and no end
+    assert capture["reason"] == write["reason"] == hooks["reason"] == "inner_ring_test"
+    assert capture_begin <= capture_end <= write_begin <= write_end
+    assert write_end <= hooks_begin <= hooks_end
+    # the hook was fed the dump, which holds its own capture's begin and no end
     (records,) = seen
     own = [r["event"] for r in records if r.get("ident") == write["ident"]
            and r["event"].startswith("flight.dump.")]
-    assert own == ["flight.dump.write_begin"]
+    assert own == ["flight.dump.capture_begin"]
 
 
 def test_the_new_intervals_are_declared_where_they_are_recorded():

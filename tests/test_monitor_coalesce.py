@@ -217,6 +217,7 @@ def test_the_trip_dump_holds_one_paired_coalesce_interval(
         return "recovered"
 
     assert _one_rank_wrapper(store_server, "coalesce-dump")(train)() == "recovered"
+    flight.flush()  # captured at the trip, written behind the re-entry
     (dump,) = glob.glob(str(tmp_path / "flight-*-monitor_trip.jsonl"))
     records = [json.loads(line) for line in open(dump)]
     window = [

@@ -66,6 +66,10 @@ def _three_restarts(port, fault):
 
     def train(call_wrapper=None):
         entry = call_wrapper.iteration
+        # the first trip's two dumps are written behind its re-entry, beside
+        # fn: here, so that the writer thread's allocations start no automatic
+        # collection that finds this entry's cycle before the restart path's
+        flight.flush()
         seen.append({
             "entry": entry,
             "holder_dead": dead.get("holder") is None or dead["holder"]() is None,

@@ -221,14 +221,16 @@ class AbortLadder:
             flight.record(EV_LADDER, self.name)
             # entering the ladder: mark the live episode's abort phase (the
             # degrade ladder runs outside any episode — phase() is a no-op
-            # guarded by the episode's own lifecycle) and drop a black box
-            # before teardown overwrites the pre-fault ring tail
+            # guarded by the episode's own lifecycle) and capture a black
+            # box before teardown overwrites the pre-fault ring tail: the
+            # snapshot is taken here, the file written once the wrapper has
+            # re-entered fn or, outside any restart, after flight's bound
             from ..telemetry import episode as episode_mod
 
             ep = episode_mod.current()
             if ep is not None:
                 ep.phase("abort")
-            flight.dump("abort_ladder")
+            flight.dump_deferred("abort_ladder")
             results: List[StageResult] = []
             escalated = False
             for stage in self.stages:

@@ -607,6 +607,11 @@ class CallWrapper:
                     )
                     if restart_clock.running:
                         restart_clock.next(None)
+                        # the restart is over: the trip's and the ladder's
+                        # black boxes, captured on the monitor thread, may
+                        # be encoded and written now.  Last before fn, so the
+                        # writer's first slice cannot begin ahead of it
+                        flight.release_deferred()
                     if state.mode == Mode.ACTIVE:
                         if self._accepts_cw:
                             kwargs = {**kwargs, "call_wrapper": self}
@@ -913,8 +918,11 @@ class CallWrapper:
 
     def _on_trip(self) -> None:
         """Runs on the monitor thread at the detection instant: mint the
-        fault episode (first detector job-wide wins the id) and drop the
-        black box while the ring still holds the pre-fault picture."""
+        fault episode (first detector job-wide wins the id) and capture the
+        black box while the ring still holds the pre-fault picture.  The
+        capture is the ring's snapshot, taken here; its file is written
+        behind the restart (``flight.dump_deferred``), once ``run`` has
+        re-entered fn."""
         iteration = self.state.iteration
         try:
             episode_mod.begin(
@@ -925,7 +933,7 @@ class CallWrapper:
             )
         except (OSError, StoreError):
             log.debug("episode mint at trip failed", exc_info=True)
-        flight.dump("monitor_trip")
+        flight.dump_deferred("monitor_trip")
 
     def _analyze_dump_hook(self, records) -> None:
         try:

@@ -37,19 +37,33 @@ Design:
   ``ident`` every interval of one operation shares (a save ticket, a load
   number, the faulted wrapper iteration) and the ``parent`` interval's name.
   A begin with no end in a fault dump says where the process was stuck.  A
-  dump past its throttle records two of its own, ``flight.dump.write`` and
-  ``flight.dump.hooks``.
+  dump past its throttle records three of its own, ``flight.dump.capture``,
+  ``flight.dump.write`` and ``flight.dump.hooks``.
+- **A dump is a capture and a write.**  The capture (throttle, sequence
+  number, meta record, ``snapshot()`` of the ring's immutable tuples, the
+  file's name) is always made on the calling thread, where the dump was asked
+  for: nothing recorded later can enter it.  The write (the dicts, the JSON
+  lines, the file, the retention, the funnel's line, the hooks) follows at
+  once on the same thread for a process that is ending or answering
+  (:func:`dump`), and for a process that is recovering
+  (:func:`dump_deferred`: the trip path's two) on one daemon writer thread,
+  once the wrapper has re-entered the wrapped fn (:func:`release_deferred`)
+  or :data:`DEFERRED_WRITE_BOUND_S` has passed, in slices that give the
+  interpreter lock up between them.  :func:`flush` lands what is queued.
 
-Dump triggers wired across the repo: monitor trip, abort-ladder entry,
-``CollectiveTimeout``, unhandled wrapper exceptions, ``GET /flight`` on
-the metrics exporter, SIGUSR2, and — only where ``TPURX_FLIGHT_DIR`` names
-a directory — process exit (reason ``exit``).
+Dump triggers wired across the repo: monitor trip and abort-ladder entry
+(deferred), ``CollectiveTimeout``, a restart the wrapper gave up on, unhandled
+wrapper exceptions, ``GET /flight`` on the metrics exporter, SIGUSR2, and —
+only where ``TPURX_FLIGHT_DIR`` names a directory — process exit (reason
+``exit``).
 """
 
 from __future__ import annotations
 
 import atexit
+import collections
 import contextlib
+import dataclasses
 import itertools
 import json
 import os
@@ -65,6 +79,7 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 from ..utils import env
 from ..utils.logging import get_logger
 from .clock import mono_ns, offset
+from .registry import counter, histogram
 
 log = get_logger("telemetry.flight")
 
@@ -130,10 +145,16 @@ def intervals() -> List[Interval]:
 
 
 EV_DUMP = declare_event("flight.dump", "reason")
-# a dump past its throttle, on the thread that asked for it; ident = the
-# dump's sequence number.  write: the ring snapshotted -> the file written
-# and the stale dumps unlinked (a dump holds its own write_begin and no end:
-# the next one does).  hooks: the loop over the dump hooks
+# a dump past its throttle; ident = the dump's sequence number.  capture, on
+# the thread that asked for it: the ring snapshotted and the meta record
+# stamped (a dump holds its own capture_begin and no end: the next one does).
+# write: the snapshot's encoding begun -> the file written and the stale
+# dumps unlinked.  hooks: the loop over the dump hooks.  Both on the thread
+# that asked where the dump is synchronous, on the writer thread and after
+# the release where it is deferred
+IV_DUMP_CAPTURE = declare_interval(
+    "flight.dump.capture_begin", "flight.dump.capture_end", "reason"
+)
 IV_DUMP_WRITE = declare_interval(
     "flight.dump.write_begin", "flight.dump.write_end", "reason"
 )
@@ -220,6 +241,7 @@ _recorder: Any = NOOP
 _dump_lock = threading.Lock()
 _dump_seq = itertools.count()
 _dump_paths: List[str] = []       # files this process wrote, oldest first
+_last_path: Optional[str] = None  # the newest capture's file, written or not
 _last_dump_ns: Dict[str, int] = {}  # reason -> mono_ns of last dump
 _DUMP_HOOKS: List[Callable[[List[dict]], None]] = []
 
@@ -336,7 +358,7 @@ def _host() -> str:
     return socket.gethostname().split(".")[0]
 
 
-def _meta(reason: str) -> Dict[str, Any]:
+def _meta(reason: str, events: Optional[int] = None) -> Dict[str, Any]:
     off = offset()
     meta: Dict[str, Any] = {
         "event": "_flight_meta",
@@ -349,7 +371,7 @@ def _meta(reason: str) -> Dict[str, Any]:
         "rank": env.RANK.get(),
         "reason": reason,
         "episode": current_episode_id(),
-        "events": len(_recorder),
+        "events": len(_recorder) if events is None else events,
         "capacity": getattr(_recorder, "capacity", 0),
     }
     if off is not None:
@@ -359,12 +381,24 @@ def _meta(reason: str) -> Dict[str, Any]:
     return meta
 
 
-def _records(reason: str) -> List[Dict[str, Any]]:
-    host = _host()
-    pid = os.getpid()
-    rank = env.RANK.get()
-    out = [_meta(reason)]
-    for t_ns, name, episode, args in _recorder.snapshot():
+def _snapshot(reason: str) -> Tuple[Dict[str, Any], List[tuple]]:
+    """The ring as it stands and the meta record stamped right after: no
+    event of the snapshot is younger than the meta's ``mono_ns``."""
+    slots = _recorder.snapshot()
+    return _meta(reason, len(slots)), slots
+
+
+def _encode(
+    meta: Dict[str, Any], slots: List[tuple], pause_s: float = 0.0
+) -> Tuple[List[Dict[str, Any]], List[str]]:
+    """``(records, lines)`` of a snapshot: a dict and a JSON line an event,
+    the meta first.  With ``pause_s`` the thread sleeps that long after every
+    ``_SLICE_EVENTS`` events, so whoever waits for the interpreter lock gets
+    it after about a millisecond and not after the switch interval."""
+    host, pid, rank = meta["host"], meta["pid"], meta["rank"]
+    records = [meta]
+    lines = [json.dumps(meta, default=repr)]
+    for i, (t_ns, name, episode, args) in enumerate(slots, 1):
         rec: Dict[str, Any] = {
             "mono_ns": t_ns, "event": name, "host": host, "pid": pid,
             "rank": rank,
@@ -372,15 +406,22 @@ def _records(reason: str) -> List[Dict[str, Any]]:
         if episode:
             rec["episode"] = episode
         fields = _EVENT_FIELDS.get(name, ())
-        for i, val in enumerate(args):
-            rec[fields[i] if i < len(fields) else f"arg{i}"] = val
-        out.append(rec)
-    return out
+        for j, val in enumerate(args):
+            rec[fields[j] if j < len(fields) else f"arg{j}"] = val
+        records.append(rec)
+        lines.append(json.dumps(rec, default=repr))
+        if pause_s and i % _SLICE_EVENTS == 0:
+            time.sleep(pause_s)
+    return records, lines
+
+
+def _records(reason: str) -> List[Dict[str, Any]]:
+    return _encode(*_snapshot(reason))[0]
 
 
 def render_jsonl(reason: str = "request") -> str:
     """The ring as JSONL text (the ``GET /flight`` body)."""
-    return "\n".join(json.dumps(r, default=repr) for r in _records(reason)) + "\n"
+    return "\n".join(_encode(*_snapshot(reason))[1]) + "\n"
 
 
 def add_dump_hook(hook: Callable[[List[dict]], None]) -> None:
@@ -397,15 +438,63 @@ def remove_dump_hook(hook: Callable[[List[dict]], None]) -> None:
         pass
 
 
-def dump(
-    reason: str, path: Optional[str] = None, min_interval_s: float = 2.0
-) -> Optional[str]:
-    """Write the ring to a JSONL black-box file; returns the path.
+# -- a dump: the capture, then the write ---------------------------------------
 
-    Per-reason throttled (``min_interval_s``) so a trip→ladder→timeout
-    cascade produces one dump per distinct trigger, not one per retry.
-    Never raises: a dump failing must not worsen the fault being dumped.
-    """
+# How long a deferred capture waits for its restart's re-entry before the
+# writer encodes it anyway (a restart that hangs, a ladder outside any
+# episode).  Chosen against two readings: the slowest trip -> re-entry a cell
+# has shown is 0.36 s (the fifth cell under a 112.8 MB executable, PR 37;
+# 0.05-0.08 s in every cell since PR 41), so 2 s clears it more than five
+# times over and a healthy restart is always released by its re-entry
+# (`tpurx_flight_dump_released_total{by="bound"}` rising in a healthy job
+# says this is too short); and `Wrapper`'s soft and hard timeouts default to
+# 60 and 90 s, so the box is on disk long before `monitor_process` sends its
+# SIGTERM, let alone its SIGKILL.  It is also the throttle's default interval:
+# no reason has two captures waiting.
+DEFERRED_WRITE_BOUND_S = 2.0
+# the deferred encoding's slice: about a millisecond of work at the 13 us an
+# event the chip's host takes, then a sleep long enough for a waiting thread
+# to be scheduled and take the interpreter lock
+_SLICE_EVENTS = 64
+_SLICE_PAUSE_S = 0.0005
+
+_DUMPS = counter(
+    "tpurx_flight_dump_total",
+    "Dumps past their throttle, by where the write ran (deferred: the writer"
+    " thread, behind the restart; sync: the thread that asked)",
+    labels=("path",),
+)
+_DUMP_LAND_NS = histogram(
+    "tpurx_flight_dump_land_ns",
+    "A dump's capture to its file closed: how long the evidence was in"
+    " memory only",
+)
+_DUMP_RELEASED = counter(
+    "tpurx_flight_dump_released_total",
+    "Deferred dumps, by what let the writer start: the wrapper re-entered"
+    " the wrapped fn, the bound passed, or a flush (a synchronous dump, the"
+    " exit)",
+    labels=("by",),
+)
+
+
+@dataclasses.dataclass
+class _Capture:
+    """What a dump is made of, taken where it was asked for."""
+
+    seq: int
+    reason: str
+    path: str
+    meta: Dict[str, Any]
+    slots: List[tuple]
+    released: bool = False  # by the re-entry: the writer need not wait
+
+
+def _capture(
+    reason: str, path: Optional[str], min_interval_s: float
+) -> Optional[_Capture]:
+    """The synchronous half of every dump, None inside the throttle."""
+    global _last_path
     if _recorder is NOOP:
         return None
     now = mono_ns()
@@ -419,19 +508,28 @@ def dump(
         _last_dump_ns[reason] = now
     record(EV_DUMP, reason)
     seq = next(_dump_seq)
+    with span(IV_DUMP_CAPTURE, seq, None, reason):
+        meta, slots = _snapshot(reason)
+        if path is None:
+            path = os.path.join(
+                env.FLIGHT_DIR.get() or tempfile.gettempdir(),
+                f"flight-{meta['host']}-{meta['pid']}-{seq:04d}-{reason}.jsonl",
+            )
+        _last_path = path
+    return _Capture(seq, reason, path, meta, slots)
+
+
+def _write(cap: _Capture, pause_s: float = 0.0) -> None:
+    """The other half: encode, write, retire stale files, announce, feed the
+    hooks.  Never raises."""
+    seq, reason, path = cap.seq, cap.reason, cap.path
     try:
         with span(IV_DUMP_WRITE, seq, None, reason):
-            records = _records(reason)
-            if path is None:
-                base = env.FLIGHT_DIR.get() or tempfile.gettempdir()
-                os.makedirs(base, exist_ok=True)
-                path = os.path.join(
-                    base,
-                    f"flight-{_host()}-{os.getpid()}-{seq:04d}-{reason}.jsonl",
-                )
+            records, lines = _encode(cap.meta, cap.slots, pause_s)
+            os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
             with open(path, "w") as f:
-                for rec in records:
-                    f.write(json.dumps(rec, default=repr) + "\n")
+                f.write("\n".join(lines) + "\n")
+            _DUMP_LAND_NS.observe(mono_ns() - cap.meta["mono_ns"])
             with _dump_lock:
                 _dump_paths.append(path)
                 keep = max(1, env.FLIGHT_DUMP_KEEP.get())
@@ -447,7 +545,7 @@ def dump(
         # logger so the node's RootLogServer archive names every dump
         log.warning(
             "flight dump (%s): %s (%d events, episode=%s)",
-            reason, path, len(records) - 1, current_episode_id() or "-",
+            reason, path, len(records) - 1, cap.meta["episode"] or "-",
         )
         with span(IV_DUMP_HOOKS, seq, None, reason):
             for hook in list(_DUMP_HOOKS):
@@ -455,20 +553,153 @@ def dump(
                     hook(records)
                 except Exception:  # noqa: BLE001 - hooks never worsen a fault
                     log.exception("flight dump hook failed")
-        return path
+    except Exception:  # noqa: BLE001 - dumping must never worsen a fault
+        log.exception("flight dump (%s) failed", reason)
+
+
+class _DumpWriter:
+    """The deferred captures in order, and the one daemon thread that writes
+    them behind the restart that made them.  A capture is due once it is
+    released (:meth:`release`: the re-entry) or ``bound_s`` old."""
+
+    def __init__(self, bound_s: float = DEFERRED_WRITE_BOUND_S):
+        self._bound_ns = int(bound_s * 1e9)
+        self._cond = threading.Condition()  # guards _queue and _thread
+        self._queue: collections.deque = collections.deque()
+        # held around one capture's take-and-write, by the thread or by a
+        # flush: files land in sequence order.  Reentrant for SIGUSR2's
+        # handler, which may find the main thread inside a flush
+        self._write_lock = threading.RLock()
+        self._thread: Optional[threading.Thread] = None
+
+    def submit(self, cap: _Capture) -> None:
+        with self._cond:
+            self._queue.append(cap)
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._run, name="tpurx-flight-writer", daemon=True
+                )
+                self._thread.start()
+            self._cond.notify_all()
+
+    def release(self) -> None:
+        """Everything queued may be written now."""
+        with self._cond:
+            for cap in self._queue:
+                cap.released = True
+            self._cond.notify_all()
+
+    def _wait_s(self, now_ns: int) -> Optional[float]:
+        """Seconds until the head capture is due: 0 now, None with none."""
+        if not self._queue:
+            return None
+        head = self._queue[0]
+        if head.released:
+            return 0.0
+        return max(0.0, (head.meta["mono_ns"] + self._bound_ns - now_ns) / 1e9)
+
+    def write_next(self, now_ns: int, pause_s: float = 0.0) -> bool:
+        """Write the head capture if it is due at ``now_ns``; whether one was
+        written."""
+        with self._write_lock:
+            with self._cond:
+                if self._wait_s(now_ns) != 0.0:
+                    return False
+                cap = self._queue.popleft()
+            _DUMP_RELEASED.labels("reentry" if cap.released else "bound").inc()
+            _write(cap, pause_s)
+            return True
+
+    def flush(self) -> None:
+        """Write everything queued on the calling thread, without the
+        slices' pauses: a process that is ending does not wait for them."""
+        with self._write_lock:
+            while True:
+                with self._cond:
+                    try:
+                        # one atomic take: SIGUSR2's handler may flush on
+                        # this very thread between any two bytecodes
+                        cap = self._queue.popleft()
+                    except IndexError:
+                        return
+                _DUMP_RELEASED.labels("reentry" if cap.released else "flush").inc()
+                _write(cap)
+
+    def _run(self) -> None:
+        while True:
+            with self._cond:
+                wait_s = self._wait_s(mono_ns())
+                if wait_s != 0.0:
+                    self._cond.wait(wait_s)
+                    continue
+            self.write_next(mono_ns(), _SLICE_PAUSE_S)
+
+
+_writer = _DumpWriter()
+
+
+def dump(
+    reason: str, path: Optional[str] = None, min_interval_s: float = 2.0
+) -> Optional[str]:
+    """Write the ring to a JSONL black-box file, on this thread, after
+    whatever :func:`dump_deferred` left queued; returns the path.
+
+    Per-reason throttled (``min_interval_s``) so a trip→ladder→timeout
+    cascade produces one dump per distinct trigger, not one per retry.
+    Never raises: a dump failing must not worsen the fault being dumped.
+    """
+    try:
+        cap = _capture(reason, path, min_interval_s)
+        if cap is None:
+            return None
+        _DUMPS.labels("sync").inc()
+        _writer.flush()
+        _write(cap)
+        return cap.path
     except Exception:  # noqa: BLE001 - dumping must never worsen a fault
         log.exception("flight dump (%s) failed", reason)
         return None
 
 
+def dump_deferred(reason: str, min_interval_s: float = 2.0) -> Optional[str]:
+    """:func:`dump` for a thread on a restart's critical path: the capture
+    here and now, the write on the writer thread once :func:`release_deferred`
+    says the restart is over or ``DEFERRED_WRITE_BOUND_S`` has passed.
+    Returns the path the file will have.  A kill inside that window loses the
+    box; everything that ends the process in order flushes it."""
+    try:
+        cap = _capture(reason, None, min_interval_s)
+        if cap is None:
+            return None
+        _DUMPS.labels("deferred").inc()
+        _writer.submit(cap)
+        return cap.path
+    except Exception:  # noqa: BLE001 - dumping must never worsen a fault
+        log.exception("flight dump (%s) failed", reason)
+        return None
+
+
+def release_deferred() -> None:
+    """The restart is over (the wrapper is about to re-enter the wrapped fn):
+    the writer may start on what :func:`dump_deferred` queued."""
+    _writer.release()
+
+
+def flush() -> None:
+    """Land every queued capture, in sequence order, on this thread."""
+    _writer.flush()
+
+
 def last_dump_path() -> Optional[str]:
-    with _dump_lock:
-        return _dump_paths[-1] if _dump_paths else None
+    """The newest dump's path: named at its capture, so a deferred dump's
+    file may not be there yet (:func:`flush`)."""
+    return _last_path
 
 
 def _dump_at_exit() -> None:
     """One last black box with the whole ring — only where the operator
     named a directory for dumps: a job never sprays the temp directory."""
+    flush()
     if env.FLIGHT_DIR.get():
         dump("exit", min_interval_s=0.0)
 

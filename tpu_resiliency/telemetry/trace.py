@@ -120,6 +120,7 @@ for _name, _cat in (
     ("inproc.restart.collect", "inprocess"),
     ("inproc.restart.rearm", "inprocess"),
     ("inproc.restart.initialize", "inprocess"),
+    ("flight.dump.capture", "flight"),
     ("flight.dump.write", "flight"),
     ("flight.dump.hooks", "flight"),
 ):
