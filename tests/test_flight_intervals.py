@@ -39,6 +39,7 @@ PARENT_OF = {
     "ckpt.save.prepare": "ckpt.save", "ckpt.save.snapshot": "ckpt.save",
     "ckpt.save.handoff": "ckpt.save", "ckpt.stage.d2h": "ckpt.stage",
     "ckpt.stage.d2h.first": "ckpt.stage.d2h",
+    "ckpt.stage.populate": "ckpt.stage",
     "ckpt.load.plan": "ckpt.load", "ckpt.load.start": "ckpt.load",
     "ckpt.load.wait": "ckpt.load", "ckpt.load.place": "ckpt.load",
     "ckpt.load.release": "ckpt.load",

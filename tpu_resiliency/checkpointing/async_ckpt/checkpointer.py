@@ -690,7 +690,20 @@ class AsyncCheckpointer:
                 "d2h_window_waits": staged.d2h_window_waits,
                 "device_digest_s": staged.device_digest_s,
                 "d2h_skipped_bytes": staged.d2h_skipped_bytes,
+                "populate_s": staged.populate_s,
+                "populate_wait_s": staged.populate_wait_s,
+                "populated_bytes": staged.populated_bytes,
+                "populate_fallbacks": staged.populate_fallbacks,
             }
+            if staged.bytes_allocated:  # a first save, or a layout change
+                log.info(
+                    "staged %d bytes into fresh segments: %d made resident "
+                    "ahead of the copy in %.3f s (%d segments fell back), the "
+                    "copy waited %.3f s for them",
+                    staged.bytes_allocated, staged.populated_bytes,
+                    staged.populate_s, staged.populate_fallbacks,
+                    staged.populate_wait_s,
+                )
             _STAGE_BYTES.inc(staged.bytes_allocated + staged.bytes_reused)
             _STAGE_OVERLAP.set(staged.stage_overlap_pct)
             _STAGE_WINDOW_PEAK.set(staged.d2h_window_peak_bytes)

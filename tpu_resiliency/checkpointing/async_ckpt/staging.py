@@ -27,8 +27,19 @@ are in shm.
 
 Shm segments are pooled and **reused across saves** (double-buffered by the
 checkpointer): a steady-state save of an unchanged layout allocates zero new
-shm bytes and — critically on Linux — pays zero first-touch page-fault cost,
-which dominates fresh-segment staging at GiB scale.
+shm bytes and copies into pages that are already resident.  A page of a fresh
+tmpfs segment is a write fault when first touched, and a copy that takes them
+one 4 KiB page at a time on the stager's thread ran a first save at a sixth of
+a later one's rate.  So **a save that stages into fresh segments** (a job's
+first, one that finds every pooled set still in use, the first after a layout
+change) creates all of them from the plan before the first byte lands and has
+a helper thread make their pages resident in bulk, in plan order, ahead of the
+copy loop (:class:`_Populator`, :func:`_populate`: one call a segment that
+gives up the interpreter lock); the copy loop waits on a segment's event only
+if its turn comes before the helper got there, and then copies into resident
+pages as every later save does.  A bulk call that fails is counted and the
+copy faults that segment in itself, as before: it never fails a save.  The
+reusing path runs none of it.
 
 **Save planning is derived from the sharding itself**: for every jax leaf
 the global ``device -> index`` map (``NamedSharding.devices_indices_map``)
@@ -66,9 +77,13 @@ paths (``local/state_dict.py``) kick their transfers through
 
 from __future__ import annotations
 
+import ctypes
 import dataclasses
+import errno
 import itertools
 import math
+import os
+import threading
 import time
 from multiprocessing import shared_memory
 
@@ -77,7 +92,8 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tupl
 
 import numpy as np
 
-from ...telemetry import flight
+from ...telemetry import counter, flight
+from ...telemetry.clock import mono_ns
 from ...utils.logging import get_logger
 from ..coverage import covers
 
@@ -92,6 +108,27 @@ IV_STAGE_D2H = flight.declare_interval(
 # host: what the first window of transfers costs before anything streams
 IV_STAGE_D2H_FIRST = flight.declare_interval(
     "ckpt.stage.d2h.first_begin", "ckpt.stage.d2h.first_end"
+)
+
+# A fresh staging's segments made resident ahead of the copy loop: the first
+# segment created -> the last one resident (begun on the stager's thread,
+# ended on the helper's).  The begin says how many segments
+# and bytes the plan has, the end for how many the bulk call succeeded.  No
+# reusing save records one.
+IV_STAGE_POPULATE = flight.declare_interval(
+    "ckpt.stage.populate_begin", "ckpt.stage.populate_end", "segments", "bytes"
+)
+
+_POPULATED_BYTES = counter(
+    "tpurx_ckpt_stage_populated_bytes_total",
+    "Bytes of fresh staging segments made resident by the bulk call, ahead "
+    "of the copy loop (0 on a save that reuses pooled segments)",
+)
+_POPULATE_FALLBACK = counter(
+    "tpurx_ckpt_stage_populate_fallback_total",
+    "Fresh staging segments whose bulk call failed and whose pages the copy "
+    "itself faulted in, as before; reason = the errno's name",
+    labels=("reason",),
 )
 
 # The most bytes of device-to-host transfer the stager keeps issued and not
@@ -163,6 +200,115 @@ def _await_d2h(data: Any) -> np.ndarray:
     return np.asarray(data)
 
 
+# How a fresh segment's pages are made resident: ``mlock`` then ``munlock`` over
+# the mapping, from ONE helper thread.  Chosen by a probe on the chip's host
+# (PR 48; gVisor, a 102 ms jitted step running beside it, 1.21 GB of fresh
+# segments of 14-322 MB; docs/checkpointing.md has the table): ``np.copyto``
+# into a fresh segment ran at 0.41 GB/s; ``madvise(MADV_POPULATE_WRITE)`` is
+# ``EINVAL`` there; writing zeros through the descriptor or
+# ``posix_fallocate`` made the pages at 1.6 and 3.5 GB/s but left the copy
+# after them at 0.50-0.52 (the mapping still faults a page at a time); a
+# thread storing a byte a page ran at the copy's own 0.41 and needed four
+# threads to reach 1.36; ``mlock`` made 1.37 GB/s resident from one thread
+# and left the copy after it at 10.8 GB/s.  Two and four ``mlock`` helpers
+# moved nothing faster (1.36, 1.35 GB/s end to end against 1.31) and held
+# the step beside them for 196-407 ms at a time; one left every period at
+# 102.  A constant form and no knob: the stager reads ``reusing`` and the
+# plan's sizes, nothing else.
+_libc = ctypes.CDLL(None, use_errno=True)
+_libc.mlock.argtypes = (ctypes.c_void_p, ctypes.c_size_t)
+_libc.mlock.restype = ctypes.c_int
+_libc.munlock.argtypes = (ctypes.c_void_p, ctypes.c_size_t)
+_libc.munlock.restype = ctypes.c_int
+
+
+def _populate(shm: shared_memory.SharedMemory) -> None:
+    """Make every page of a fresh segment resident in this process's mapping
+    by one call that gives up the interpreter lock: ``mlock`` faults the whole
+    range in inside the kernel, ``munlock`` lets it go again at once (the
+    pages stay; nothing is left pinned).  Raises ``OSError`` where the kernel
+    will not (``ENOMEM`` / ``EPERM``: ``RLIMIT_MEMLOCK`` without
+    ``CAP_IPC_LOCK``, or a full ``/dev/shm``)."""
+    anchor = ctypes.c_char.from_buffer(shm.buf)
+    try:
+        addr = ctypes.addressof(anchor)
+        if _libc.mlock(addr, shm.size):
+            err = ctypes.get_errno()
+            raise OSError(err, os.strerror(err))
+        _libc.munlock(addr, shm.size)
+    finally:
+        del anchor  # the export would hold the segment open past its close
+
+
+class _Populator:
+    """A fresh staging's segments made resident ahead of the copy loop.
+
+    One helper thread takes the segments in plan order and :func:`_populate`s
+    each; ``ready[k]`` is set once segment ``k`` needs no more from it,
+    whether the call succeeded or not: a segment whose call failed is counted
+    and left for the copy to fault in, as every fresh segment was before.  The
+    copy loop waits on a segment's event only if its turn comes first
+    (:meth:`wait`).  :meth:`close` stops the helper and joins it; no segment
+    may be unmapped before that."""
+
+    def __init__(
+        self, staged: "StagedTree", nbytes: List[int], ident: Optional[int],
+        t0: float, t0_ns: int,
+    ):
+        """``t0`` / ``t0_ns``: taken before the first segment was created."""
+        self._staged, self._nbytes, self._ident = staged, nbytes, ident
+        self._shms = list(staged._shms)
+        self.ready = [threading.Event() for _ in self._shms]
+        self._stop = False
+        self._t0 = t0
+        flight.begin(IV_STAGE_POPULATE, ident, IV_STAGE, len(nbytes), sum(nbytes),
+                     at_ns=t0_ns)
+        self._thread = threading.Thread(
+            target=self._run, name="tpurx-ckpt-populate", daemon=True
+        )
+        self._thread.start()
+
+    def _run(self) -> None:
+        staged, resident = self._staged, 0
+        for k, shm in enumerate(self._shms):
+            if self._stop:
+                self._finish(resident)  # stopped short: the interval still ends
+                return
+            try:
+                _populate(shm)
+                resident += 1
+                staged.populated_bytes += self._nbytes[k]
+            except Exception as exc:  # noqa: BLE001 - fail open, never a save
+                reason = errno.errorcode.get(getattr(exc, "errno", None), "other")
+                staged.populate_fallbacks += 1
+                _POPULATE_FALLBACK.labels(reason=reason).inc()
+                log.debug("populate of %s failed (%r): the copy faults it in",
+                          shm.name, exc)
+            if k == len(self._shms) - 1:
+                self._finish(resident)  # before the event the copy loop ends on
+            self.ready[k].set()
+
+    def _finish(self, resident: int) -> None:
+        self._staged.populate_s = time.perf_counter() - self._t0
+        _POPULATED_BYTES.inc(self._staged.populated_bytes)
+        flight.end(IV_STAGE_POPULATE, self._ident, IV_STAGE, resident,
+                   self._staged.populated_bytes)
+
+    def wait(self, k: int) -> float:
+        """Block until segment ``k`` is ready; the seconds that took."""
+        if self.ready[k].is_set():
+            return 0.0
+        t0 = time.perf_counter()
+        # tpurx: disable=TPURX005 -- the helper sets it after one local call, whether that succeeded or not
+        self.ready[k].wait()
+        return time.perf_counter() - t0
+
+    def close(self) -> None:
+        self._stop = True
+        # tpurx: disable=TPURX005 -- _stop is set; the helper ends after the one local call it is in
+        self._thread.join()
+
+
 @dataclasses.dataclass
 class ShardInfo:
     leaf_idx: int
@@ -206,9 +352,19 @@ class StagedTree:
     )
     device_digest_s: float = 0.0          # fingerprint dispatch + mask readback
     d2h_skipped_bytes: int = 0            # bytes that never left the device
+    # a fresh staging's segments made resident ahead of the copy loop (all 0
+    # for a pass that reused pooled segments)
+    populate_s: float = 0.0               # first segment created -> last resident
+    populate_wait_s: float = 0.0          # the copy loop's waits for a segment
+    populated_bytes: int = 0              # segment bytes the bulk call made resident
+    populate_fallbacks: int = 0           # segments left to the copy's own faults
+    _populator: Optional["_Populator"] = None
     _shms: List[shared_memory.SharedMemory] = dataclasses.field(default_factory=list)
 
     def close(self, unlink: bool = True) -> None:
+        if self._populator is not None:  # no helper may outlive a mapping
+            self._populator.close()
+            self._populator = None
         for shm in self._shms:
             try:
                 shm.close()
@@ -388,8 +544,11 @@ def stage_pytree(
     With ``reuse`` (a previously staged tree whose ``plan_sig`` matches this
     tree's), existing shm segments are rewritten in place instead of
     allocated: a steady-state save of an unchanged layout creates zero new
-    shm bytes (and skips first-touch page faults, the dominant cost of fresh
-    GiB-scale segments).
+    shm bytes and copies into resident pages.  Without it every owned
+    shard's segment is created from the plan's sizes up front and made
+    resident by a helper thread ahead of the copy loop (``populate_s``,
+    ``populate_wait_s``, ``populated_bytes`` and ``populate_fallbacks`` on
+    the result say how that went; all 0 after a reusing pass).
 
     ``on_plan(total_owned_bytes)`` fires once, before any bytes move, as soon
     as the full shard plan is known.  ``on_shard_staged(info)`` fires per
@@ -407,7 +566,9 @@ def stage_pytree(
     ``ident`` (the save ticket) tags the ``ckpt.stage.d2h`` flight
     interval: first transfer issued to last byte landed in shm; its child
     ``ckpt.stage.d2h.first`` ends when the first transferring shard is on
-    the host (left open by a staging that fails before that)."""
+    the host (left open by a staging that fails before that).  A fresh
+    staging also records ``ckpt.stage.populate`` (child of ``ckpt.stage``):
+    first segment created to last one resident."""
     treedef, paths, leaves = _leaf_paths(tree)
     pidx = process_index
     if pidx is None:
@@ -485,6 +646,25 @@ def _build_plan(
     return work
 
 
+def _fresh_segments(
+    staged: StagedTree, work: List[_OwnedWork], ident: Optional[int]
+) -> List[shared_memory.SharedMemory]:
+    """A fresh staging's segments, one an owned shard in plan order, created
+    from the plan's sizes before a byte has landed — each in ``staged._shms``
+    the moment it exists, so a staging that fails leaks none — and handed to
+    the helper that makes their pages resident ahead of the copy loop."""
+    t0, t0_ns = time.perf_counter(), mono_ns()
+    for w in work:
+        shm = create_shm(max(1, w.info.nbytes))
+        staged._shms.append(shm)
+        w.info.shm_name = shm.name
+    if work:
+        staged._populator = _Populator(
+            staged, [w.info.nbytes for w in work], ident, t0, t0_ns
+        )
+    return staged._shms
+
+
 def _stage_pipelined(
     staged: StagedTree,
     leaves: List[Any],
@@ -508,6 +688,8 @@ def _stage_pipelined(
     staged.device_fps = {}
     staged.device_digest_s = 0.0
     staged.d2h_skipped_bytes = 0
+    staged.populate_s = staged.populate_wait_s = 0.0
+    staged.populated_bytes = staged.populate_fallbacks = 0
 
     if digest_ctx is not None:
         # On-device change mask BEFORE any transfer is issued: fingerprint
@@ -568,7 +750,7 @@ def _stage_pipelined(
                 if w.info.d2h_skipped:
                     on_shard_staged(w.info)
 
-        shms = staged._shms if reusing else []
+        shms = staged._shms if reusing else _fresh_segments(staged, work, ident)
         wait_s = copy_s = hidden_copy_s = 0.0
         for k, w in enumerate(work):
             if w.info.d2h_skipped:
@@ -590,10 +772,13 @@ def _stage_pipelined(
                         f"{arr.nbytes} != {w.info.nbytes} (stale plan signature?)"
                     )
             else:
-                shm = create_shm(max(1, arr.nbytes))
-                staged._shms.append(shm)
-                w.info.shm_name = shm.name
-                w.info.nbytes = arr.nbytes
+                shm = shms[k]
+                if arr.nbytes != w.info.nbytes:
+                    raise ValueError(
+                        f"stage size mismatch on leaf {w.info.leaf_idx}: "
+                        f"{arr.nbytes} != {w.info.nbytes} (the plan's metadata)"
+                    )
+                staged.populate_wait_s += staged._populator.wait(k)
             dst = np.ndarray(arr.shape, dtype=arr.dtype, buffer=shm.buf)
             np.copyto(dst, arr, casting="no")
             t2 = time.perf_counter()

@@ -56,9 +56,9 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     "ckpt.drain_begin": ("ckpt.drain_end", "ckpt_drain", "checkpointing"),
     "ckpt.restore_begin": ("ckpt.restore_end", "ckpt_restore", "checkpointing"),
     # flight intervals (flight.interval): one save is ckpt.save (prepare,
-    # snapshot, handoff) + ckpt.stage (d2h, d2h.first) + ckpt.drain, sharing
-    # the save ticket as ``ident``; one restore is ckpt.load and its
-    # children, sharing a load number
+    # snapshot, handoff) + ckpt.stage (d2h, d2h.first, and a fresh staging's
+    # populate) + ckpt.drain, sharing the save ticket as ``ident``; one
+    # restore is ckpt.load and its children, sharing a load number
     "ckpt.save_begin": ("ckpt.save_end", "ckpt.save", "checkpointing"),
     "ckpt.save.prepare_begin": (
         "ckpt.save.prepare_end", "ckpt.save.prepare", "checkpointing",
@@ -75,6 +75,9 @@ SPAN_PAIRS: Dict[str, Tuple[str, str, str]] = {
     ),
     "ckpt.stage.d2h.first_begin": (
         "ckpt.stage.d2h.first_end", "ckpt.stage.d2h.first", "checkpointing",
+    ),
+    "ckpt.stage.populate_begin": (
+        "ckpt.stage.populate_end", "ckpt.stage.populate", "checkpointing",
     ),
     "ckpt.load_begin": ("ckpt.load_end", "ckpt.load", "checkpointing"),
     "ckpt.load.plan_begin": (
