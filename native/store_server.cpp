@@ -67,8 +67,7 @@ enum Op : uint8_t {
   OP_APPEND_CHECK = 16,
   OP_ADD_SET = 17,
   OP_WAIT_GE = 18,
-  OP_MUX = 19,
-  OP__LAST = 19,
+  OP__LAST = 18,
 };
 // END GENERATED OP TABLE
 
@@ -91,7 +90,6 @@ struct Waiter {
   uint8_t op;                       // OP_GET, OP_WAIT, or OP_WAIT_GE
   std::string get_key;              // for OP_GET / OP_WAIT_GE
   long long threshold = 0;          // for OP_WAIT_GE
-  std::string corr;                 // MUX correlation id ("" = plain op)
   uint64_t id;
 };
 
@@ -100,9 +98,6 @@ struct Conn {
   std::string in;                   // read buffer
   std::string out;                  // pending writes
   std::unordered_set<uint64_t> waiting_ids;
-  // correlation id of the MUX envelope currently being handled; reply()
-  // prepends it so subscription replies carry their id (out-of-order safe)
-  std::string cur_corr;
   bool closed = false;
 };
 
@@ -346,15 +341,7 @@ void arm_write(Conn* c) {
 
 void reply(Conn* c, uint8_t status, const std::vector<std::string>& args) {
   if (g_brownout) return;  // test mode: read everything, answer nothing
-  if (!c->cur_corr.empty()) {
-    std::vector<std::string> wrapped;
-    wrapped.reserve(args.size() + 1);
-    wrapped.push_back(c->cur_corr);
-    wrapped.insert(wrapped.end(), args.begin(), args.end());
-    encode_response(&c->out, status, wrapped);
-  } else {
-    encode_response(&c->out, status, args);
-  }
+  encode_response(&c->out, status, args);
   arm_write(c);
 }
 
@@ -397,17 +384,6 @@ void complete_waiter(uint64_t id, bool timed_out) {
   }
   if (!w.conn || w.conn->closed) return;
   w.conn->waiting_ids.erase(id);
-  // restore the waiter's envelope: a parked MUX long-poll may complete from
-  // inside another request's notify (possibly on the same connection), so
-  // the corr in force at park time — not the current one — must frame it
-  struct CorrScope {
-    Conn* c;
-    std::string saved;
-    CorrScope(Conn* conn, const std::string& corr) : c(conn), saved(conn->cur_corr) {
-      c->cur_corr = corr;
-    }
-    ~CorrScope() { c->cur_corr = saved; }
-  } scope(w.conn, w.corr);
   if (timed_out) {
     reply(w.conn, ST_TIMEOUT, {});
   } else if (w.op == OP_GET) {
@@ -468,7 +444,6 @@ void park_waiter(Conn* c, uint8_t op, std::vector<std::string> missing,
   w.op = op;
   w.get_key = get_key;
   w.threshold = threshold;
-  w.corr = c->cur_corr;
   w.id = id;
   g_store.key_waiters[w.keys.front()].push_back(id);
   g_store.deadlines.emplace(w.deadline, id);
@@ -695,25 +670,6 @@ void handle_request(Conn* c, uint8_t op, std::vector<std::string> args) {
       park_waiter(c, OP_WAIT_GE, {args[0]}, args[0], timeout_ms, threshold);
       return;
     }
-    case OP_MUX: {
-      // correlated envelope: args[0]=corr id (ASCII decimal), args[1]=one
-      // inner opcode byte, args[2:] the inner args.  The inner op runs with
-      // cur_corr set, so its reply — immediate or from a parked waiter —
-      // carries the corr id as its first arg and may be answered out of
-      // order relative to other requests on this connection.
-      if (args.size() < 2 || args[1].size() != 1)
-        return reply(c, ST_ERROR, {"MUX wants corr,op,args..."});
-      uint8_t inner = static_cast<uint8_t>(args[1][0]);
-      std::string saved = c->cur_corr;
-      c->cur_corr = args[0];
-      if (inner < OP_SET || inner > OP__LAST || inner == OP_MUX)
-        reply(c, ST_ERROR, {"bad inner op"});
-      else
-        handle_request(c, inner,
-                       std::vector<std::string>(args.begin() + 2, args.end()));
-      c->cur_corr = saved;
-      return;
-    }
     default:
       return reply(c, ST_ERROR, {"unknown op"});
   }
@@ -748,10 +704,11 @@ bool try_parse_frame(Conn* c) {
   }
   c->in.erase(0, off);
   if (op < OP_SET || op > OP__LAST) {
-    // unparseable stream from here on: drop the connection (matches the
-    // Python server's behavior)
-    c->closed = true;
-    return false;
+    // a well-framed request with an opcode this server does not serve (a
+    // retired one, a newer client's): refused, connection kept, as the
+    // Python server does.  Garbage trips the caps above.
+    reply(c, ST_ERROR, {"unknown op"});
+    return true;
   }
   handle_request(c, op, std::move(args));
   return true;

@@ -4,6 +4,8 @@ Conformance reuses the semantics covered in test_store.py, executed against
 the epoll C++ server — one protocol, two implementations.
 """
 
+import socket
+import struct
 import threading
 import time
 
@@ -15,6 +17,12 @@ from tpu_resiliency.store import (
     StoreTimeout,
     barrier,
     reentrant_barrier,
+)
+from tpu_resiliency.store.protocol import (
+    Op,
+    Status,
+    encode_frame,
+    encode_request,
 )
 
 
@@ -119,14 +127,48 @@ def test_barriers_on_native(native_store_server):
 
 
 def test_garbage_opcode_drops_conn_server_survives(nstore, native_store_server):
-    import socket
-
     s = socket.create_connection(("127.0.0.1", native_store_server.port))
     s.sendall(b"\xff\x00\x00\x00\x00garbage")
     time.sleep(0.1)
     s.close()
     nstore.set("after", b"ok")
     assert nstore.get("after") == b"ok"
+
+
+def _read_response(sock):
+    def exact(n):
+        buf = b""
+        while len(buf) < n:
+            chunk = sock.recv(n - len(buf))
+            assert chunk, "server closed the connection"
+            buf += chunk
+        return buf
+
+    status = exact(1)[0]
+    (nargs,) = struct.unpack("<I", exact(4))
+    return status, [
+        exact(struct.unpack("<I", exact(4))[0]) for _ in range(nargs)
+    ]
+
+
+@pytest.mark.parametrize("fixture", ["store_server", "native_store_server"])
+def test_retired_opcode_is_refused_and_conn_kept(fixture, request):
+    """Opcode 19 is retired: a well-framed request that carries it is
+    answered ERROR by either server, and the same socket then serves a
+    SET and a GET."""
+    server = request.getfixturevalue(fixture)
+    assert 19 not in set(Op)
+    s = socket.create_connection(("127.0.0.1", server.port), timeout=10.0)
+    try:
+        s.sendall(encode_frame(19, [b"7", bytes([Op.PING])]))
+        status, _args = _read_response(s)
+        assert status == Status.ERROR
+        s.sendall(encode_request(Op.SET, b"after/19", b"ok"))
+        assert _read_response(s)[0] == Status.OK
+        s.sendall(encode_request(Op.GET, b"after/19", b"1000"))
+        assert _read_response(s) == (Status.OK, [b"ok"])
+    finally:
+        s.close()
 
 
 def test_native_faster_than_python_roundtrips(native_store_server, store_server):

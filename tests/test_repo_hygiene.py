@@ -118,6 +118,35 @@ def test_retired_cpu_benchmark_stays_gone():
     )
 
 
+def test_retired_store_transport_stays_gone():
+    """The multiplexed store client and its flag, and the flag that switched
+    key affinity off, were deleted in PR 49: the store has one client
+    transport and affinity is not an option.  Nothing the package ships,
+    documents or deploys names them."""
+    assert not os.path.exists(
+        os.path.join(REPO, "tpu_resiliency", "store", "mux.py"))
+    retired = (
+        "TPURX_STORE_MUX", "TPURX_STORE_AFFINITY", "MuxStoreClient",
+        "store/mux.py",
+    )
+    shipped = (
+        "tpu_resiliency", "native", "tpurx_lint", "docs", "examples", "deploy",
+    )
+    offenders = []
+    for top in shipped:
+        for dirpath, dirnames, filenames in os.walk(os.path.join(REPO, top)):
+            dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+            for fn in filenames:
+                path = os.path.join(dirpath, fn)
+                with open(path, errors="replace") as f:
+                    text = f.read()
+                offenders += [
+                    f"{os.path.relpath(path, REPO)}: {name}"
+                    for name in retired if name in text
+                ]
+    assert not offenders, offenders
+
+
 # -- framework-backed shims (rule IDs TPURX001-004, see docs/lint.md) --------
 
 

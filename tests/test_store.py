@@ -321,8 +321,15 @@ def test_journal_compaction_bounds_size(tmp_path):
     for i in range(500):
         c.set("hot", b"x" * 64 + str(i).encode())  # same key rewritten
     c.close()
+    # An ordering, not a sleep: the compaction the writes set off swaps the
+    # file off the loop, and a stop() that cancels it in flight keeps the
+    # old journal, every record of it, as the authority on purpose.
+    journal = tmp_path / "store.journal"
+    deadline = time.monotonic() + 30.0
+    while journal.stat().st_size >= 8192 and time.monotonic() < deadline:
+        time.sleep(0.01)
     s1.stop()
-    size = (tmp_path / "store.journal").stat().st_size
+    size = journal.stat().st_size
     assert size < 8192, size  # compacted: not 500 * ~80 bytes
     s2 = _journal_server(tmp_path)
     c2 = StoreClient("127.0.0.1", s2.port)

@@ -6,7 +6,7 @@ pending async raise (in-process restart abort, monitor-triggered teardown,
 shutdown) lands between slices — never parked behind one uninterruptible
 ``recv``.  Each test parks a worker thread at a different point of the I/O
 state machine (connect, send, recv-mid-frame, server-held long poll,
-cross-shard fan-out, mux subscription), injects
+cross-shard fan-out), injects
 ``PyThreadState_SetAsyncExc`` and asserts the raise lands within the
 contract budget AND the client is cleanly re-usable afterwards (no
 half-read frames on the wire).
@@ -42,7 +42,6 @@ from tpu_resiliency.store.client import (
     _brownout_grace,
     _poll_quantum,
 )
-from tpu_resiliency.store.mux import MuxStoreClient
 from tpu_resiliency.store.sharding import free_port
 
 # Small quantum so landing-latency assertions are tight; the contract is
@@ -236,20 +235,54 @@ class TestInterruptEveryPoint:
             c.close()
             group.stop()
 
-    def test_mid_mux_long_poll_lands_and_conn_survives(self, server):
-        """Mux client: the caller parks in an Event.wait sliced at the
-        quantum while the WAIT subscription is server-held.  The raise
-        abandons the pending; the SHARED connection stays healthy for other
-        callers."""
-        c = MuxStoreClient("127.0.0.1", server.port, timeout=60.0)
+    def test_mid_long_poll_lands_and_clone_traffic_unharmed(self, server):
+        """A client's ``clone()`` is its own connection: while the first
+        client is parked in a long-poll, while the raise lands in it and
+        after it has re-entered, a second thread's set/get on the clone go
+        on without an error."""
+        c = StoreClient("127.0.0.1", server.port, timeout=60.0)
+        other = c.clone()
+        stop = threading.Event()
+        rounds = [0]
+        errs = []
+
+        def traffic():
+            try:
+                while not stop.is_set():
+                    v = str(rounds[0]).encode()
+                    other.set("clone/k", v)
+                    assert other.get("clone/k", timeout=5.0) == v
+                    rounds[0] += 1
+            except BaseException as exc:  # noqa: BLE001 - surfaced below
+                errs.append(exc)
+
+        def more_rounds_than(n):
+            deadline = time.monotonic() + 10.0
+            while rounds[0] <= n and not errs:
+                assert time.monotonic() < deadline, "clone traffic stalled"
+                time.sleep(0.01)
+            return rounds[0]
+
+        th = threading.Thread(target=traffic, daemon=True)
+        th.start()
         try:
-            dt = _interrupt_parked(lambda: c.get("never/mux", timeout=60.0))
+            before = more_rounds_than(0)
+            dt = _interrupt_parked(lambda: c.get("never/clone", timeout=60.0))
             _assert_landed(dt)
-            # the multiplexed socket did NOT die with the abandoned caller
-            c.set("mux/after", b"ok")
-            assert c.get("mux/after", timeout=5.0) == b"ok"
+            during = rounds[0]
+            assert during > before, "clone made no round while c was parked"
+            more_rounds_than(during)
+            # the interrupted client re-enters cleanly on a new socket
+            assert c._sock is None
+            c.set("clone/after", b"ok")
+            assert c.get("clone/after", timeout=5.0) == b"ok"
         finally:
+            stop.set()
+            th.join(timeout=10.0)
+            other.close()
             c.close()
+        assert not th.is_alive()
+        assert not errs, errs
 
 
 # -- brownout: live listener, wedged event loop -------------------------------

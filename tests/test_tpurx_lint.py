@@ -187,8 +187,8 @@ class TestDeadlineDiscipline:
         """, rule="TPURX005")
 
     def test_recv_sanctioned_in_store_io_core(self, tmp_path):
-        # store/client.py and store/mux.py ARE the interruptible I/O core:
-        # their recv loops are quantum-sliced by construction
+        # store/client.py IS the interruptible I/O core: its recv loops
+        # are quantum-sliced by construction
         assert not lint_snippet(tmp_path, "tpu_resiliency/store/client.py", """
             def f(sock):
                 return sock.recv(4096)

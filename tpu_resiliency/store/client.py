@@ -228,8 +228,7 @@ class StoreClient:
     # -- request plumbing --------------------------------------------------
     # Every socket wait below is a quantum-bounded slice inside a Python
     # loop (the "interruptible I/O core"); tpurx-lint's unbounded-socket
-    # rule sanctions only this module and store/mux.py to touch recv/send
-    # directly.
+    # rule sanctions only this module to touch recv/send directly.
 
     def _read_exact(self, n: int, deadline: float) -> bytes:
         assert self._sock is not None
@@ -835,10 +834,6 @@ def store_from_env(timeout: float = _DEFAULT_TIMEOUT) -> StoreClient:
         return FailoverStoreClient(
             [e.strip() for e in endpoints.split(",") if e.strip()], timeout=timeout
         )
-    host = env.STORE_ADDR.get()
-    port = env.STORE_PORT.get()
-    if env.STORE_MUX.get():
-        from .mux import MuxStoreClient  # local: avoids a cycle
-
-        return MuxStoreClient(host, port, timeout=timeout)
-    return StoreClient(host, port, timeout=timeout)
+    return StoreClient(
+        env.STORE_ADDR.get(), env.STORE_PORT.get(), timeout=timeout
+    )

@@ -65,16 +65,7 @@ class Op(IntEnum):
                         # counts as 0).  The event-driven "wait for the next
                         # arrival" primitive that replaces per-count marker
                         # keys.  -> current value (or TIMEOUT status)
-    MUX = 19            # correlated envelope: args[0] is an ASCII-decimal
-                        # correlation id, args[1] a 1-byte inner opcode,
-                        # args[2:] the inner op's args.  The response is a
-                        # normal response frame whose FIRST arg is the
-                        # correlation id (status = the inner op's status),
-                        # and the server may answer MUX requests OUT OF
-                        # ORDER — long-polls (GET/WAIT/WAIT_GE) become
-                        # server-held subscriptions that never head-of-line
-                        # block the connection's other traffic.  MUX inside
-                        # MUX is an error.
+    # 19 is retired (it was an envelope opcode), never reused
 
 
 # Spliced by the server into ADD_SET's set_value (first occurrence only):
@@ -133,9 +124,9 @@ def render_cpp_op_enum() -> str:
     """The C++ ``enum Op`` block for ``native/store_server.cpp``.
 
     ``OP__LAST`` is the range-guard sentinel: the frame parser accepts
-    ``OP_SET..OP__LAST``, so a new Python-side op is rejected by the native
-    server until this block is regenerated — which the parity test turns
-    into a loud failure instead of a silent connection drop.
+    ``OP_SET..OP__LAST``, so a new Python-side op is refused (``ERROR``) by
+    the native server until this block is regenerated — which the parity
+    test turns into a failure of the suite, not of a job.
     """
     lines = [
         f"{CPP_OP_TABLE_BEGIN} "

@@ -82,8 +82,6 @@ class StoreServer:
         # where a server looks alive at the TCP layer while its serving
         # loop is wedged.  Clients must escape via per-op deadlines.
         self.test_brownout = bool(env.STORE_TEST_BROWNOUT.get())
-        # live MUX subscription tasks per connection (cancelled on close)
-        self._conn_tasks: Dict[asyncio.StreamWriter, Set[asyncio.Task]] = {}
 
     # -- journal -----------------------------------------------------------
     # Record formats (final-state records; replay order reconstructs _data):
@@ -372,7 +370,7 @@ class StoreServer:
                     raise
         return Status.OK
 
-    async def _handle_request(self, op: Op, args: List[bytes]) -> bytes:
+    async def _handle_request(self, op: int, args: List[bytes]) -> bytes:
         data = self._data
         if op == Op.SET:
             self._set(args[0], args[1])
@@ -506,55 +504,13 @@ class StoreServer:
     async def _read_exact(self, reader: asyncio.StreamReader, n: int) -> bytes:
         return await reader.readexactly(n)
 
-    @staticmethod
-    def _with_corr(resp: bytes, corr: bytes) -> bytes:
-        """Splice a MUX correlation id in as the response's FIRST arg
-        without re-encoding the payload args."""
-        (nargs,) = _U32.unpack_from(resp, 1)
-        return (
-            resp[0:1] + _U32.pack(nargs + 1)
-            + _U32.pack(len(corr)) + corr + resp[5:]
-        )
-
-    async def _mux_dispatch(
-        self, writer: asyncio.StreamWriter, corr: bytes,
-        inner: Op, args: List[bytes],
-    ) -> None:
-        """One MUX request as its own task: a long-poll (GET/WAIT/WAIT_GE)
-        becomes a server-held subscription that never head-of-line blocks
-        the connection — replies go out in completion order, each framed
-        with its correlation id.  A whole-frame ``writer.write`` with no
-        await in between keeps concurrent replies from interleaving."""
-        try:
-            resp = await self._handle_request(inner, args)
-        except asyncio.CancelledError:
-            raise
-        except Exception as exc:  # noqa: BLE001 - report to client
-            log.exception("store mux op %s failed", inner)
-            resp = encode_response(Status.ERROR, str(exc).encode())
-        if self.test_brownout:
-            return
-        try:
-            writer.write(self._with_corr(resp, corr))
-            await writer.drain()
-        except (ConnectionResetError, BrokenPipeError, OSError):
-            pass  # subscriber went away; the connection reaper cleans up
-
     async def _handle_conn(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
-        tasks = self._conn_tasks.setdefault(writer, set())
         try:
             while True:
                 header = await reader.read(1)
                 if not header:
-                    break
-                try:
-                    op = Op(header[0])
-                except ValueError:
-                    # Garbage/unknown opcode: the stream is unparseable from
-                    # here on — drop the connection, keep the server.
-                    log.warning("dropping connection: unknown opcode %r", header)
                     break
                 (nargs,) = _U32.unpack(await self._read_exact(reader, 4))
                 if nargs > 1 << 20:  # sanity caps match the native server
@@ -570,37 +526,14 @@ class StoreServer:
                     args.append(await self._read_exact(reader, ln) if ln else b"")
                 if nargs == -1:
                     break
-                if op == Op.MUX:
-                    # correlated envelope: args[0]=corr id, args[1]=one
-                    # inner opcode byte, args[2:]=inner args; handled
-                    # concurrently so this loop goes straight back to
-                    # reading the next pipelined request
-                    bad = len(args) < 2 or len(args[1]) != 1
-                    inner = None
-                    if not bad:
-                        try:
-                            inner = Op(args[1][0])
-                        except ValueError:
-                            bad = True
-                    if bad or inner == Op.MUX:
-                        if not self.test_brownout:
-                            corr = args[0] if args else b""
-                            writer.write(self._with_corr(
-                                encode_response(Status.ERROR, b"bad inner op"),
-                                corr,
-                            ))
-                            await writer.drain()
-                        continue
-                    t = asyncio.ensure_future(
-                        self._mux_dispatch(writer, args[0], inner, args[2:])
-                    )
-                    tasks.add(t)
-                    t.add_done_callback(tasks.discard)
-                    continue
                 try:
-                    resp = await self._handle_request(op, args)
+                    # a well-framed request with an opcode this server does
+                    # not serve (a retired one, a newer client's) falls off
+                    # the dispatch's end: ERROR, connection kept.  Garbage
+                    # trips the caps above.
+                    resp = await self._handle_request(header[0], args)
                 except Exception as exc:  # noqa: BLE001 - report to client
-                    log.exception("store op %s failed", op)
+                    log.exception("store op %s failed", header[0])
                     resp = encode_response(Status.ERROR, str(exc).encode())
                 if self.test_brownout:
                     continue
@@ -609,9 +542,6 @@ class StoreServer:
         except (asyncio.IncompleteReadError, ConnectionResetError, BrokenPipeError):
             pass
         finally:
-            # server-held subscriptions die with their connection
-            for t in list(self._conn_tasks.pop(writer, ())):
-                t.cancel()
             writer.close()
             try:
                 await writer.wait_closed()

@@ -63,22 +63,8 @@ from .client import (
     _interruptible_sleep,
     _poll_quantum,
 )
-from .protocol import Op, Status, itob
 
 log = get_logger("store.sharding")
-
-
-def _shard_client(host, port, timeout, connect_timeout=60.0) -> StoreClient:
-    """Per-shard client constructor: the multiplexed client when
-    ``TPURX_STORE_MUX`` is set (one shared socket per shard per process),
-    the classic one-socket-per-clone client otherwise."""
-    if env.STORE_MUX.get():
-        from .mux import MuxStoreClient  # local: avoids a cycle
-
-        return MuxStoreClient(host, port, timeout=timeout,
-                              connect_timeout=connect_timeout)
-    return StoreClient(host, port, timeout=timeout,
-                       connect_timeout=connect_timeout)
 
 SHARD_MAP_KEY = "store/shard_map"
 
@@ -291,7 +277,6 @@ class ShardedStoreClient:
         failover_policy: RetryPolicy = FAILOVER_POLICY,
         epoch: int = 0,
         spares: Sequence = (),
-        affinity: Optional[bool] = None,
     ):
         self.map = ShardMap(endpoints, vnodes=vnodes, epoch=epoch,
                             spares=spares)
@@ -299,11 +284,9 @@ class ShardedStoreClient:
         self.timeout = timeout
         self._connect_timeout = connect_timeout
         self._failover_policy = failover_policy
-        self._affinity = (
-            env.STORE_AFFINITY.get() if affinity is None else affinity
-        )
         self._clients: List[Optional[StoreClient]] = [
-            _shard_client(h, p, timeout, connect_timeout)
+            StoreClient(h, p, timeout=timeout,
+                        connect_timeout=connect_timeout)
             for h, p in self.endpoints
         ]
         self._shard_ops = [
@@ -331,19 +314,17 @@ class ShardedStoreClient:
 
     def _shard_idx(self, key) -> int:
         k = key.encode() if isinstance(key, str) else bytes(key)
-        if self._affinity:
-            tok = affinity_token(k)
-            if tok is not None:
-                k = tok
+        tok = affinity_token(k)
+        if tok is not None:
+            k = tok
         return self.map.shard_for(k)
 
     def _client(self, idx: int) -> StoreClient:
         c = self._clients[idx]
         if c is None:
             host, port = self.endpoints[idx]
-            c = _shard_client(
-                host, port, self.timeout, self._connect_timeout
-            )
+            c = StoreClient(host, port, timeout=self.timeout,
+                            connect_timeout=self._connect_timeout)
             self._clients[idx] = c
         return c
 
@@ -464,29 +445,6 @@ class ShardedStoreClient:
             groups.setdefault(self._shard_idx(key), []).append((pos, key))
         return groups
 
-    def _mux_batch(self, calls, park_s: float = 0.0):
-        """Batched cross-shard fan-out over multiplexed clients.
-
-        ``calls`` is ``[(idx, op, wire_args), ...]``; when EVERY involved
-        shard client exposes the pipelining hooks, all requests are
-        submitted before any reply is collected — one RTT for the whole
-        round instead of one per shard.  Returns ``[(status, out), ...]``
-        in call order, or ``None`` when any client is non-mux (caller takes
-        its sequential/threaded path).  Shard failures surface as
-        StoreError/StoreBrownout for the caller's fallback to handle.
-        """
-        clients = []
-        for idx, _op, _args in calls:
-            c = self._client(idx)
-            if not hasattr(c, "submit_roundtrip"):
-                return None
-            clients.append(c)
-        pends = [
-            (c, c.submit_roundtrip(op, args))
-            for c, (_idx, op, args) in zip(clients, calls)
-        ]
-        return [c.result_roundtrip(p, park_s) for c, p in pends]
-
     # -- public API (mirrors StoreClient) ----------------------------------
 
     def clone(self) -> "ShardedStoreClient":
@@ -497,7 +455,6 @@ class ShardedStoreClient:
             failover_policy=self._failover_policy,
             epoch=self.map.epoch,
             spares=[f"{h}:{p}" for h, p in self.map.spares],
-            affinity=self._affinity,
         )
 
     def close(self) -> None:
@@ -603,25 +560,6 @@ class ShardedStoreClient:
         deadline = time.monotonic() + t
         groups = list(self._by_shard(keys).items())
 
-        if len(groups) > 1:
-            # Mux fast path: one server-held WAIT subscription per shard,
-            # all submitted before any reply is collected — no thread per
-            # shard, and the fence latency is the max of the shard fences.
-            calls = [
-                (idx, Op.WAIT,
-                 [itob(int(t * 1000))] + [StoreClient._k(k)
-                                          for _p, k in group])
-                for idx, group in groups
-            ]
-            try:
-                results = self._mux_batch(calls, park_s=t)
-            except StoreError:
-                results = None  # shard mid-death: threaded failover below
-            if results is not None:
-                if all(st == Status.OK for st, _ in results):
-                    return
-                raise StoreTimeout(f"wait({list(keys)}) timed out after {t}s")
-
         # Set when the CALLER abandons the fan-out (async raise landing in
         # the sliced join below).  Workers check it between park slices and
         # exit quietly instead of riding out the full wait budget — an
@@ -708,23 +646,9 @@ class ShardedStoreClient:
                 raise exc
 
     def check(self, keys: Sequence) -> bool:
-        groups = list(self._by_shard(keys).items())
-        if len(groups) > 1:
-            calls = [
-                (idx, Op.CHECK, [StoreClient._k(k) for _p, k in g])
-                for idx, g in groups
-            ]
-            try:
-                results = self._mux_batch(calls)
-            except StoreError:
-                results = None
-            if results is not None and all(
-                st == Status.OK for st, _ in results
-            ):
-                return all(out[0] == b"1" for _st, out in results)
         return all(
             self._routed(idx, lambda c, _k=[k for _p, k in g]: c.check(_k))
-            for idx, g in groups
+            for idx, g in self._by_shard(keys).items()
         )
 
     def delete(self, key) -> bool:
@@ -743,49 +667,13 @@ class ShardedStoreClient:
         return out
 
     def multi_set(self, items: dict) -> None:
-        groups = list(self._by_shard(list(items)).items())
-        if len(groups) > 1:
-            calls = []
-            for idx, group in groups:
-                wire: List[bytes] = []
-                for _pos, k in group:
-                    wire += [StoreClient._k(k), StoreClient._v(items[k])]
-                calls.append((idx, Op.MULTI_SET, wire))
-            try:
-                results = self._mux_batch(calls)
-            except StoreError:
-                results = None  # shard mid-death: failover path below
-            if results is not None:
-                if all(st == Status.OK for st, _ in results):
-                    return
-                raise StoreError("multi_set -> shard error")
-        for idx, group in groups:
+        for idx, group in self._by_shard(list(items)).items():
             sub = {k: items[k] for _pos, k in group}
             self._routed(idx, lambda c, _s=sub: c.multi_set(_s))
 
     def multi_get(self, keys: Sequence) -> List[Optional[bytes]]:
         out: List[Optional[bytes]] = [None] * len(keys)
-        groups = list(self._by_shard(keys).items())
-        if len(groups) > 1:
-            calls = [
-                (idx, Op.MULTI_TRY_GET,
-                 [StoreClient._k(k) for _p, k in group])
-                for idx, group in groups
-            ]
-            try:
-                results = self._mux_batch(calls)
-            except StoreError:
-                results = None
-            if results is not None and all(
-                st == Status.OK for st, _ in results
-            ):
-                for (idx, group), (_st, vals) in zip(groups, results):
-                    for i, (pos, _key) in enumerate(group):
-                        out[pos] = (
-                            vals[2 * i + 1] if vals[2 * i] == b"1" else None
-                        )
-                return out
-        for idx, group in groups:
+        for idx, group in self._by_shard(keys).items():
             vals = self._routed(
                 idx, lambda c, _k=[k for _p, k in group]: c.multi_get(_k)
             )
@@ -855,7 +743,7 @@ class AffinityGroup:
 
     Every op verifies its keys (a) carry the group's prefix and (b) route
     to the group's home shard — asserted per call, not assumed, so a
-    mis-grouped key (affinity disabled, or a key outside the round) fails
+    mis-grouped key (one outside the round) fails
     loudly instead of splitting a one-RTT op across shards.  Delegates to
     the owning :class:`ShardedStoreClient`, so failover episodes and
     epoch adoption apply unchanged.
@@ -887,8 +775,7 @@ class AffinityGroup:
             if idx != home:
                 raise StoreError(
                     f"affinity violated: key {k!r} routes to shard {idx}, "
-                    f"group {self._prefix!r} lives on shard {home} (is "
-                    f"TPURX_STORE_AFFINITY disabled?)"
+                    f"group {self._prefix!r} lives on shard {home}"
                 )
 
     def set(self, key, value) -> None:

@@ -219,12 +219,6 @@ STORE_ENDPOINTS = Knob(
     "TPURX_STORE_ENDPOINTS", str, None,
     "Comma-separated host:port shard endpoints, overriding the "
     "shard-map bootstrap read.", group="store")
-STORE_AFFINITY = Knob(
-    "TPURX_STORE_AFFINITY", bool, True,
-    "Key-affinity routing in the sharded store client: keys of one "
-    "protocol round (barrier/{name}/*, rdzv/{n}/*) hash as a unit so "
-    "multi-key one-RTT ops stay single-shard.  Disable to fall back to "
-    "pure per-key routing.", group="store")
 STORE_SPARES = Knob(
     "TPURX_STORE_SPARES", str, None,
     "Comma-separated host:port spare store endpoints a dead shard can be "
@@ -252,13 +246,6 @@ STORE_POLL_S = Knob(
     "blocking op is a Python-level retry loop, so pending async raises "
     "(in-process restarts), monitor aborts and shutdown land between "
     "slices.", group="store")
-STORE_MUX = Knob(
-    "TPURX_STORE_MUX", bool, False,
-    "Use the multiplexed store client: one persistent socket per shard "
-    "shared by every thread in the process, correlation-id framing so "
-    "long-polls become server-held subscriptions (no head-of-line "
-    "blocking), pipelined one-RTT ops and batched cross-shard fan-out.",
-    group="store")
 STORE_TEST_COMPACT_CRASH = Knob(
     "TPURX_STORE_TEST_COMPACT_CRASH", int, None,
     "TEST-ONLY fault hook: crash the store journal compactor after N "

@@ -25,14 +25,13 @@ _SUBPROCESS_FUNCS = {
 # raw byte-wait receivers: a C-level wait no async raise can interrupt
 _RECV_ATTRS = {"recv", "recv_into", "recvfrom", "recvfrom_into", "recvmsg"}
 
-# The sanctioned interruptible I/O core: the ONLY modules allowed to touch
+# The sanctioned interruptible I/O core: the ONLY module allowed to touch
 # raw socket recv/send waits directly.  Every wait there is sliced at the
 # TPURX_STORE_POLL_S quantum inside a Python-level loop, which is the whole
 # point — everyone else must either bound the socket (settimeout/poll in
 # the same function) or go through the store client.
 SANCTIONED_SOCKET_CORE = (
     "tpu_resiliency/store/client.py",
-    "tpu_resiliency/store/mux.py",
 )
 
 
