@@ -30,7 +30,8 @@ METRICS = (
     "restart_main_ms", "restart_collect_ms", "reenter_unattributed_pct")
 STALL_CELLS = [f"{config}.stall-inproc" for config in (
     "cerebras-gpt-1.3b-1chip", "kimi-linear-48b-a3b-1chip",
-    "qwen3-next-80b-a3b-1chip", "keye-vl-2.0-30b-a3b-1chip", "lfm2-8b-a1b-1chip")]
+    "qwen3-next-80b-a3b-1chip", "keye-vl-2.0-30b-a3b-1chip", "lfm2-8b-a1b-1chip",
+    "mellum2-12b-a2.5b-1chip")]
 # what PR 40 added to the program's ring: a program without them is the parent
 NEW_EVENTS = ("inproc.abort", "inproc.raise", "inproc.restart", "flight.dump.")
 

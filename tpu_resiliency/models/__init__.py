@@ -7,12 +7,15 @@ from . import (
     kimi_linear_reference,
     lfm2_moe,
     lfm2_moe_reference,
+    mellum,
+    mellum_reference,
     qwen3_next,
     qwen3_next_reference,
 )
 from .keye_vl2 import KeyeVL2Config
 from .kimi_linear import KimiLinearConfig
 from .lfm2_moe import Lfm2MoeConfig
+from .mellum import MellumConfig
 from .qwen3_next import Qwen3NextConfig
 from .transformer import TransformerConfig, init_params, forward, loss_fn, make_train_step
 
@@ -20,6 +23,7 @@ __all__ = [
     "KeyeVL2Config",
     "KimiLinearConfig",
     "Lfm2MoeConfig",
+    "MellumConfig",
     "Qwen3NextConfig",
     "TransformerConfig",
     "forward",
@@ -32,6 +36,8 @@ __all__ = [
     "lfm2_moe_reference",
     "loss_fn",
     "make_train_step",
+    "mellum",
+    "mellum_reference",
     "qwen3_next",
     "qwen3_next_reference",
 ]
